@@ -2,8 +2,7 @@
 north-star metric) and emit the HLO-category / source-line time tables.
 
 Usage:  python benchmarks/profile_seq2seq.py [--batch 128] [--len 50]
-Outputs: trace under --out (gitignored; only the distilled table is committed
-in PROFILE_r04.md) + markdown tables on stdout.
+Outputs: trace under --out (gitignored) + markdown tables on stdout.
 
 Reference anchor: benchmark/paddle/rnn/rnn.py, benchmark/README.md:115-161.
 """

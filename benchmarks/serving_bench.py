@@ -740,15 +740,12 @@ def run_tp(args):
     """The --tp leg (ISSUE 12): TP=1/2/4 over identical geometry, each in a
     child process with the XLA host device count FORCED to the TP size (the
     shard_update_bench pattern — the device count is fixed at backend
-    init). The persistent compile cache is dropped from the children:
-    executing a cache-DESERIALIZED multi-device program segfaults on this
-    jax build (see tests/test_precision.py). Gates: tokens identical at
+    init). Gates: tokens identical at
     every TP (tensor parallelism is result-invisible), zero decode
     recompiles, and per-chip pool bytes exactly TP× down."""
     legs = []
     for n in [int(x) for x in args.tp.split(",") if x.strip()]:
         env = dict(os.environ, JAX_PLATFORMS="cpu")
-        env.pop("PADDLE_TPU_COMPILE_CACHE", None)
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "").replace(
                 "--xla_force_host_platform_device_count=8", ""

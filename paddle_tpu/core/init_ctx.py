@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 log = logging.getLogger("paddle_tpu")
 
@@ -31,9 +31,6 @@ class GlobalFlags:
     seed: int = 0
     # Dtype policy name ("float32" | "bfloat16").
     dtype_policy: str = "float32"
-    # Persistent XLA compilation-cache directory ("" = PADDLE_TPU_COMPILE_CACHE
-    # env, which itself defaults to off).
-    compile_cache: str = ""
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
@@ -49,76 +46,63 @@ def is_initialized() -> bool:
     return _initialized
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Wire jax's persistent compilation cache to `cache_dir` (or the
-    PADDLE_TPU_COMPILE_CACHE env var). Repeat bench/profiling/test runs then
+# One fixed, git-ignored directory inside the checkout: the path is part of
+# jax's cache key, so a cache that moves between runs never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    The directory is placed from OUTSIDE the program: where
+    JAX_COMPILATION_CACHE_DIR is set jax reads it itself and nothing here
+    touches it; unset, the cache lives at DEFAULT_CACHE_DIR. Repeat runs then
     skip XLA compilation for unchanged programs — tracing still happens, but
-    the compile (the dominant cost) is served from disk. Returns the active
-    directory, or None when disabled.
+    the compile (the dominant cost) is served from disk.
 
     The min-size/min-compile-time thresholds are zeroed so even the small CPU
     oracle programs cache; cache entries are keyed on serialized HLO + backend
     so a stale entry cannot be served for changed code."""
-    cache_dir = cache_dir or os.environ.get("PADDLE_TPU_COMPILE_CACHE")
-    if not cache_dir:
-        return None
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    redirecting = jax.config.jax_compilation_cache_dir != cache_dir
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        if jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR:
+            jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+            # jax latches its cache object (even a None one, if a compile
+            # ran before any dir was configured): a dir change needs an
+            # explicit reset or the setting is a no-op
+            from jax.experimental.compilation_cache import compilation_cache
+
+            compilation_cache.reset_cache()
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    if redirecting:
-        # jax latches its cache object (even a None one, if a compile ran
-        # before any dir was configured); any dir change — including
-        # None → dir — needs an explicit reset or the setting is a no-op
-        from jax.experimental.compilation_cache import compilation_cache
-
-        compilation_cache.reset_cache()
     from paddle_tpu.core import stats
 
-    stats.install_cache_listener()
-    log.info("persistent compilation cache at %s", cache_dir)
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if stats.install_cache_listener():  # once per process, not per caller
+        log.info("persistent compilation cache at %s", cache_dir)
     return cache_dir
 
 
-def detach_compilation_cache(reason: str = "") -> bool:
-    """PERMANENTLY detach the persistent compilation cache from this
-    process (sticky; True when a cache was actually detached).
-
-    Exists for elastic resize: once a process re-shapes its mesh, later
-    small EAGER multi-device programs (cost-sum adds, canonical
-    gather/re-flatten, placement moves) repeat byte-identically across
-    trainer generations and carry no per-trainer cache salt — on jax
-    0.4.37's CPU backend, executing a persistent-cache-DESERIALIZED
-    multi-device program in such a process corrupts memory or segfaults
-    (the same bug the SGDTrainer `_cache_salt` works around for the
-    compiled step; empirically, a region-scoped opt-out around the re-shard
-    alone is NOT sufficient — the poisoned execution can be any later
-    deserialized multi-device program, so the opt-out must be sticky).
-    Mesh step programs never used the persistent cache anyway (the salt),
-    so a resize-performing trainer process loses only the single-device
-    program cache from the first resize onward. No-op when the cache was
-    never enabled. jax_enable_compilation_cache alone does not reliably
-    gate cache READS on jax 0.4.37 — the directory itself is detached and
-    the latched cache object reset."""
+def require_tpu() -> None:
+    """Raise unless jax's default backend is a TPU or the caller asked for
+    the CPU by naming it in JAX_PLATFORMS (the test suite, rehearsals).
+    use_tpu means the TPU: a run that asked for it must not silently train
+    on whatever backend jax fell back to."""
     import jax
 
-    if jax.config.jax_compilation_cache_dir is None:
-        return False
-    from jax.experimental.compilation_cache import compilation_cache
-
-    log.warning(
-        "detaching the persistent compilation cache for the rest of this "
-        "process%s — deserialized multi-device programs are unsafe on this "
-        "backend after a mesh resize (jax 0.4.37 CPU corruption bug; see "
-        "core/init_ctx.detach_compilation_cache)",
-        f" ({reason})" if reason else "",
-    )
-    jax.config.update("jax_compilation_cache_dir", None)
-    compilation_cache.reset_cache()
-    return True
+    if "cpu" in (jax.config.jax_platforms or "").split(","):
+        return
+    platform = jax.default_backend()
+    if platform != "tpu":
+        raise RuntimeError(
+            f"use_tpu is set but jax's default backend is {platform!r}, not "
+            "a TPU; to run on the CPU on purpose set JAX_PLATFORMS=cpu or "
+            "pass --use_tpu=0"
+        )
 
 
 def init(**kwargs: Any) -> GlobalFlags:
@@ -134,8 +118,10 @@ def init(**kwargs: Any) -> GlobalFlags:
             setattr(_flags, key, type(getattr(_flags, key))(value))
         else:
             _flags.extras[key] = value
+    if _flags.use_tpu:
+        require_tpu()
     dtypes.set_policy(dtypes.get(_flags.dtype_policy))
-    enable_compilation_cache(_flags.compile_cache or None)
+    enable_compilation_cache()
     if not logging.getLogger().handlers:
         logging.basicConfig(
             level=logging.INFO,

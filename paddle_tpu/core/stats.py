@@ -290,13 +290,14 @@ RECOMPILES = RecompileStats()
 _cache_listener_installed = False
 
 
-def install_cache_listener() -> None:
+def install_cache_listener() -> bool:
     """Count persistent-compilation-cache hits/misses into RECOMPILES via
     jax.monitoring (events /jax/compilation_cache/cache_hits|cache_misses).
-    Idempotent; importing jax here is fine — callers already run under it."""
+    Idempotent — True only for the call that installed it; importing jax
+    here is fine — callers already run under it."""
     global _cache_listener_installed
     if _cache_listener_installed:
-        return
+        return False
     import jax
 
     def _on_event(event: str, **_kw) -> None:
@@ -307,6 +308,7 @@ def install_cache_listener() -> None:
 
     jax.monitoring.register_event_listener(_on_event)
     _cache_listener_installed = True
+    return True
 
 
 @contextlib.contextmanager

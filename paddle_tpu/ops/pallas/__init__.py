@@ -5,7 +5,11 @@ ragged paged-attention decode, `paged_attention.py`).
 Dispatch policy: `enabled()` is on when running on TPU (or when
 PADDLE_TPU_PALLAS=1/interpret is forced); the lax.scan implementations in
 ops/rnn.py and the jnp gather path in serving/model.py remain the oracles
-and the fallback for exotic activations / peepholes / non-TPU backends."""
+and the path for exotic activations / peepholes / non-TPU backends / shapes
+whose blocks do not fit VMEM. Which path runs is decided from the flag, the
+backend and the shapes BEFORE the call: a backend that fails to start or a
+kernel the compiler refuses raises — it is never caught to take the other
+path."""
 
 from __future__ import annotations
 
@@ -24,17 +28,9 @@ def enabled() -> bool:
         return False
     if f in ("1", "on", "true", "interpret"):
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def interpret_mode() -> bool:
     """Interpret on non-TPU backends so the same kernels are testable on CPU."""
-    if _flag() == "interpret":
-        return True
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return _flag() == "interpret" or jax.default_backend() != "tpu"

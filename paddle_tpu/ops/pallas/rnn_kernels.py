@@ -26,6 +26,32 @@ from paddle_tpu.ops.pallas import interpret_mode
 
 Array = jax.Array
 
+# Scoped-VMEM accounting for the recurrent kernels, in bytes of f32: blocks
+# whose index moves with the grid are double-buffered, resident blocks (the
+# recurrent weights, initial states, dW outputs) and scratch are held once.
+# Checked against Mosaic's own figure by AOT-compiling for v5e (64x1280 LSTM
+# backward: 84.4 MiB here, 82.5 MiB reported). The backward kernel holds
+# three weight-sized buffers (w, dW out, dW accumulator), so it decides.
+# ops/rnn.py routes a shape past VMEM_BUDGET to the lax.scan path BEFORE the
+# call; under it, the kernel asks Mosaic for what it needs instead of the
+# 16 MiB default.
+_VMEM_DEFAULT = 16 << 20
+VMEM_BUDGET = 100 << 20  # of v5e's 128 MiB; the rest stays XLA's
+
+
+def lstm_vmem_bytes(b: int, h: int) -> int:
+    return 4 * (30 * b * h + 12 * h * h + 8 * h + 256 * b)
+
+
+def gru_vmem_bytes(b: int, h: int) -> int:
+    return 4 * (19 * b * h + 9 * h * h + 6 * h + 256 * b)
+
+
+def _vmem_params(need: int) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(VMEM_BUDGET, max(_VMEM_DEFAULT, need * 5 // 4))
+    )
+
 
 def _sig(x):
     return jax.nn.sigmoid(x)
@@ -166,7 +192,9 @@ def _lstm_fwd(proj_tm: Array, mask_tm: Array, w_hh: Array, bias: Array,
             pltpu.VMEM((b, h), f32),
             pltpu.VMEM((b, h), f32),
         ],
+        compiler_params=_vmem_params(lstm_vmem_bytes(b, h)),
         interpret=interpret_mode(),
+        name="lstm_seq_fwd",
     )(*args)
     return hs, gates, ct, cs
 
@@ -238,7 +266,9 @@ def _lstm_vjp_bwd(res, grads):
             pltpu.VMEM((h, 4 * h), f32),
             pltpu.VMEM((4 * h,), f32),
         ],
+        compiler_params=_vmem_params(lstm_vmem_bytes(b, h)),
         interpret=interpret_mode(),
+        name="lstm_seq_bwd",
     )(
         gates, ct, h_prev, c_prev, mask_tm.astype(f32), w_hh.astype(f32),
         dhs, jnp.zeros((b, h), f32), dc_last.astype(f32),
@@ -368,7 +398,9 @@ def _gru_fwd(proj_tm, mask_tm, w_hzr, w_hc, bias, h0):
             jax.ShapeDtypeStruct((t, b, 3 * h), f32),
         ),
         scratch_shapes=[pltpu.VMEM((b, h), f32)],
+        compiler_params=_vmem_params(gru_vmem_bytes(b, h)),
         interpret=interpret_mode(),
+        name="gru_seq_fwd",
     )(proj_tm.astype(f32), mask_tm.astype(f32), w_hzr.astype(f32),
       w_hc.astype(f32), bias.astype(f32), h0.astype(f32))
     return hs, zrc
@@ -430,7 +462,9 @@ def _gru_vjp_bwd(res, grads):
             pltpu.VMEM((h, h), f32),
             pltpu.VMEM((3 * h,), f32),
         ],
+        compiler_params=_vmem_params(gru_vmem_bytes(b, h)),
         interpret=interpret_mode(),
+        name="gru_seq_bwd",
     )(zrc, h_prev, mask_tm.astype(f32), w_hzr.astype(f32), w_hc.astype(f32),
       dhs, jnp.zeros((b, h), f32))
     proj_dt, bias_dt, h0_dt = (a.dtype for a in dtypes)
@@ -493,6 +527,7 @@ def _attn_fwd(scale: float, q, k, v, mask):
         out_specs=pl.BlockSpec((1, tq, dv), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, tq, dv), f32),
         interpret=interpret_mode(),
+        name="attention_seq_fwd",
     )(q.astype(f32), k.astype(f32), v.astype(f32), mask.astype(f32))
     return out.astype(v.dtype)
 

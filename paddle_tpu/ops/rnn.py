@@ -21,7 +21,7 @@ GruCompute.cu. Optional peephole ("check") weights as in the reference."""
 
 from __future__ import annotations
 
-import os
+import logging
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -33,23 +33,34 @@ from paddle_tpu.ops import linalg
 
 Array = jax.Array
 
+log = logging.getLogger("paddle_tpu")
 
-def _use_fused(standard_config: bool, bh: int = 0) -> bool:
+
+def _use_fused(standard_config: bool, kind: str, b: int, h: int) -> bool:
     """Route to the pallas whole-sequence kernel when on TPU (or forced) and
     the layer uses the reference-default activations (no peepholes).
 
-    `bh` = batch*hidden of the carry: the kernel keeps per-step blocks
-    resident in VMEM, and past ~100k carry elements the *backward* kernel's
-    scoped-VMEM stack exceeds the 16 MB limit (measured: 256×512 GRU bwd
-    wants 16.21M) — fall back to the lax.scan path there."""
-    if not standard_config:
-        return False
-    limit = int(os.environ.get("PADDLE_TPU_FUSED_RNN_MAX_BH", "100000"))
-    if bh > limit:
-        return False
+    The kernels keep the recurrent weights and their gradient resident in
+    VMEM; a [b, h] carry whose blocks would not fit the budget takes the
+    lax.scan path — decided here from the shapes, never by catching the
+    compiler's refusal."""
     from paddle_tpu.ops import pallas as pal
+    from paddle_tpu.ops.pallas import rnn_kernels
 
-    return pal.enabled()
+    if not standard_config or not pal.enabled():
+        return False
+    vmem_bytes = {
+        "lstm": rnn_kernels.lstm_vmem_bytes, "gru": rnn_kernels.gru_vmem_bytes
+    }
+    need = vmem_bytes[kind](b, h)
+    if need > rnn_kernels.VMEM_BUDGET:
+        log.info(
+            "fused %s kernel at batch %d hidden %d needs %d MiB of VMEM "
+            "(budget %d MiB): taking the lax.scan path",
+            kind, b, h, need >> 20, rnn_kernels.VMEM_BUDGET >> 20,
+        )
+        return False
+    return True
 
 
 def _run_fused(proj: Array, mask: Array, reverse: bool, fn: Callable) -> Tuple:
@@ -132,7 +143,7 @@ def lstm_scan(
     if not return_cell_seq and _use_fused(
         gate_act == "sigmoid" and cell_act == "tanh" and state_act == "tanh"
         and p.check_i is None and p.check_f is None and p.check_o is None,
-        bh=b * hdim,
+        "lstm", b, hdim,
     ):
         from paddle_tpu.ops.pallas.rnn_kernels import lstm_seq_fused
 
@@ -197,7 +208,7 @@ def gru_scan(
     hdim = h3 // 3
     h0 = h0 if h0 is not None else jnp.zeros((b, hdim), proj.dtype)
 
-    if _use_fused(gate_act == "sigmoid" and cand_act == "tanh", bh=b * hdim):
+    if _use_fused(gate_act == "sigmoid" and cand_act == "tanh", "gru", b, hdim):
         from paddle_tpu.ops.pallas.rnn_kernels import gru_seq_fused
 
         return _run_fused(

@@ -27,7 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 # feature-detects the check_vma/check_rep kwarg rename across jax versions
-from paddle_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 
 Array = jax.Array
 NEG_INF = -1e30

@@ -325,7 +325,14 @@ class ReplicaSpawner:
     registers itself with the router, and appears in the next observed
     snapshot; the controller never blocks on it. `extra_args` carries the
     model/engine flags of the deployment (the controller has no opinion on
-    what a replica serves)."""
+    what a replica serves).
+
+    A replica process OWNS its chip(s): jax opens every chip the process can
+    see, and a chip belongs to one process at a time, so a second replica
+    spawned onto chips a live one already holds fails at backend start-up.
+    The deployment's `env` must give each child its own chips (on a TPU host,
+    TPU_VISIBLE_CHIPS); the child's stderr is inherited so that failure is
+    seen, not discarded."""
 
     def __init__(
         self,
@@ -357,10 +364,7 @@ class ReplicaSpawner:
         env = dict(os.environ)
         if self.env:
             env.update(self.env)
-        proc = subprocess.Popen(
-            cmd, env=env,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-        )
+        proc = subprocess.Popen(cmd, env=env, stdout=subprocess.DEVNULL)
         self._procs.append(proc)
         self.spawned += 1
         log.warning("spawned serving replica (pid %d)", proc.pid)

@@ -1,4 +1,6 @@
-"""Builds csrc/ into libpaddle_tpu_rt.so on first use (cached by mtime).
+"""Builds csrc/ into libpaddle_tpu_rt-<digest>.so on first use, named by a
+digest of the csrc/ sources: a library built from other sources has another
+name and cannot load, whatever mtimes a copy of the tree gave it.
 
 The reference ships its native runtime as CMake targets; here the library is
 small enough that a single g++ invocation at import keeps the source tree the
@@ -7,6 +9,7 @@ are used where they exist)."""
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sys
@@ -15,18 +18,18 @@ from typing import Optional
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CSRC = os.path.join(_REPO, "csrc")
 OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_lib")
-SO_PATH = os.path.join(OUT_DIR, "libpaddle_tpu_rt.so")
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(SO_PATH):
-        return True
-    so_mtime = os.path.getmtime(SO_PATH)
-    for fn in os.listdir(CSRC):
+def _so_path() -> str:
+    digest = hashlib.sha256()
+    for fn in sorted(os.listdir(CSRC)):
         if fn.endswith((".cc", ".h")):
-            if os.path.getmtime(os.path.join(CSRC, fn)) > so_mtime:
-                return True
-    return False
+            digest.update(fn.encode() + b"\0")
+            with open(os.path.join(CSRC, fn), "rb") as f:
+                digest.update(f.read())
+    return os.path.join(
+        OUT_DIR, f"libpaddle_tpu_rt-{digest.hexdigest()[:16]}.so"
+    )
 
 
 def ensure_built(verbose: bool = False) -> Optional[str]:
@@ -35,13 +38,14 @@ def ensure_built(verbose: bool = False) -> Optional[str]:
         return None
     if not os.path.isdir(CSRC):
         return None
-    if not _needs_build():
-        return SO_PATH
+    so_path = _so_path()
+    if os.path.exists(so_path):
+        return so_path
     os.makedirs(OUT_DIR, exist_ok=True)
     sources = sorted(
         os.path.join(CSRC, f) for f in os.listdir(CSRC) if f.endswith(".cc")
     )
-    tmp = SO_PATH + f".tmp.{os.getpid()}"
+    tmp = so_path + f".tmp.{os.getpid()}"
     cmd = [
         "g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
         "-o", tmp, *sources,
@@ -60,5 +64,5 @@ def ensure_built(verbose: bool = False) -> Optional[str]:
         except OSError:
             pass
         return None
-    os.replace(tmp, SO_PATH)
-    return SO_PATH
+    os.replace(tmp, so_path)
+    return so_path

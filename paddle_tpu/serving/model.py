@@ -584,15 +584,16 @@ class ServableLM:
     def _paged_attention_local(
         self,
         q: Array,            # [S, KD_local] — this shard's head slice
-        k_pages_i: Array,    # [NP, PS, KD_local]
-        v_pages_i: Array,
+        k_pages: Array,      # [L, NP, PS, KD_local] — the whole pool
+        v_pages: Array,
         block_table: Array,  # [S, P]
         positions: Array,    # [S]
+        layer: int,
         n_heads: int,
     ) -> Array:
-        """Ragged paged attention over `n_heads` heads (the FULL head count
-        on one chip; the LOCAL slice per shard under TP — heads are
-        batched-independent, so the per-shard math is bitwise the
+        """Ragged paged attention over `n_heads` heads of layer `layer` (the
+        FULL head count on one chip; the LOCAL slice per shard under TP —
+        heads are batched-independent, so the per-shard math is bitwise the
         single-chip math for those heads).
 
         Two numerically-equivalent paths behind one seam: the Pallas kernel
@@ -611,14 +612,14 @@ class ServableLM:
             )
 
             return paged_attention_decode(
-                q, k_pages_i, v_pages_i, block_table, positions,
-                scale=self.scale, n_heads=h_,
+                q, k_pages, v_pages, block_table, positions,
+                layer=layer, scale=self.scale, n_heads=h_,
             ).astype(q.dtype)
-        ps = k_pages_i.shape[1]
+        ps = k_pages.shape[2]
         qh = q.reshape(s, h_, hd)
         # dense gather: [S, P, PS, KD] -> [S, T_ctx, H, hd]
-        k_seq = k_pages_i[block_table].reshape(s, -1, h_, hd)
-        v_seq = v_pages_i[block_table].reshape(s, -1, h_, hd)
+        k_seq = k_pages[layer][block_table].reshape(s, -1, h_, hd)
+        v_seq = v_pages[layer][block_table].reshape(s, -1, h_, hd)
         ctx_idx = jnp.arange(block_table.shape[1] * ps)
         att_mask = ctx_idx[None, :] <= positions[:, None]  # [S, T_ctx]
         sc = jnp.einsum("shd,sthd->sht", qh, k_seq) * self.scale
@@ -629,10 +630,11 @@ class ServableLM:
     def _paged_attention(
         self,
         q: Array,            # [S, KD] — this layer's queries
-        k_pages_i: Array,    # [NP, PS, KD] — this layer's page pools
-        v_pages_i: Array,
+        k_pages: Array,      # [L, NP, PS, KD] — the whole page pools
+        v_pages: Array,
         block_table: Array,  # [S, P]
         positions: Array,    # [S]
+        layer: int,
     ) -> Array:
         """The TP dispatch seam over `_paged_attention_local`.
 
@@ -646,28 +648,26 @@ class ServableLM:
         per shard as on one chip — just fewer heads per page fetch."""
         if self.mesh is None:
             return self._paged_attention_local(
-                q, k_pages_i, v_pages_i, block_table, positions,
-                n_heads=self.cfg.n_heads,
+                q, k_pages, v_pages, block_table, positions,
+                layer=layer, n_heads=self.cfg.n_heads,
             )
-        from paddle_tpu.parallel.shard_map_compat import shard_map
-
         local = functools.partial(
             self._paged_attention_local,
-            n_heads=self.cfg.n_heads // self.tp_size,
+            layer=layer, n_heads=self.cfg.n_heads // self.tp_size,
         )
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=self.mesh,
             in_specs=(
-                P(None, "model"),        # q: head slice
-                P(None, None, "model"),  # k_pages[i]: kv_heads slice
-                P(None, None, "model"),  # v_pages[i]
+                P(None, "model"),              # q: head slice
+                P(None, None, None, "model"),  # k_pages: kv_heads slice
+                P(None, None, None, "model"),  # v_pages
                 P(None, None),           # block table: replicated host state
                 P(None),                 # positions: replicated
             ),
             out_specs=P(None, "model"),
             check_vma=False,
-        )(q, k_pages_i, v_pages_i, block_table, positions)
+        )(q, k_pages, v_pages, block_table, positions)
 
     def decode_step(
         self,
@@ -713,7 +713,7 @@ class ServableLM:
             k_pages = k_pages.at[i, cur_page, offs].set(k_new)
             v_pages = v_pages.at[i, cur_page, offs].set(v_new)
             ctx = self._paged_attention(
-                q, k_pages[i], v_pages[i], block_table, positions
+                q, k_pages, v_pages, block_table, positions, layer=i
             )
             # TP resharding point: row-parallel wo all-reduces here
             x = self._constrain(x + ctx @ params[f"l{i}.wo"])

@@ -1038,6 +1038,25 @@ class ServingSession:
         serving lifetime shared one compiled decode program."""
         return self.recompiles.total_signatures()
 
+    def decode_step_hlo(self) -> str:
+        """Compiled HLO text of the decode executable at this session's
+        fixed shapes — for inspection (chip_smoke.py reads which attention
+        path the step took out of it). Lowers from avals only, so it is
+        safe beside a live engine; it does trace, so the warm-up detector
+        sees one more compile."""
+        import jax
+
+        def aval(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+        s = self.cache.max_slots
+        i32, f32 = np.zeros(s, np.int32), np.zeros(s, np.float32)
+        return self._decode.lower(
+            jax.tree.map(aval, self.params), aval(self.k_pages),
+            aval(self.v_pages), i32, i32, np.zeros(s, bool),
+            self.cache.block_table(), np.zeros(s, np.uint32), i32, f32, i32,
+        ).compile().as_text()
+
     def verify_shape_signatures(self) -> int:
         """Distinct verify_chunk input signatures seen — 1 means every
         speculative round shared one compiled [1, K+1] program (0 when

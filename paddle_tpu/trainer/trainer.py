@@ -238,24 +238,6 @@ class SGDTrainer:
                 f"guard_check_every must be >= 1, got {guard_check_every}"
             )
         self.guard_check_every = guard_check_every
-        # Persistent-compile-cache opt-out for MESH step programs: jax
-        # 0.4.37's CPU backend can SEGFAULT executing a DESERIALIZED
-        # (persistent-cache-hit) donated multi-device program once other
-        # collective-using donated programs have run in the process
-        # (repro: two identical DataParallel trainings in one process with
-        # jax_compilation_cache_dir set — the second dies inside the
-        # deserialized executable; cache-free or donation-free runs are
-        # fine). A per-trainer constant folded into the traced step changes
-        # the cache key, so mesh steps always compile fresh; the in-memory
-        # executable cache still amortizes within the trainer, and
-        # single-device programs keep the full persistent-cache benefit.
-        import os as _os
-
-        self._cache_salt = (
-            (int.from_bytes(_os.urandom(4), "big") & 0x7FFFFFFF) | 1
-            if parallel is not None
-            else 0
-        )
         self.state: Optional[TrainState] = None
         # set by resize_to: gates the per-dispatch stale-plan check on
         # StackedBatch groups — straggler batches sharded for an old mesh
@@ -365,11 +347,6 @@ class SGDTrainer:
                 # cast-ok: int counter arithmetic, not a precision boundary
                 else jnp.sum(mask).astype(jnp.int32)
             )
-            if self._cache_salt:
-                # dead term, folded to 0 by XLA AFTER the compile-cache key
-                # is taken: embeds the per-trainer salt in mesh programs
-                # (see __init__ — persistent-cache opt-out)
-                bs = bs + jnp.asarray(self._cache_salt, jnp.int32) * 0
             # cast-ok: int32 sample counter → f32 schedule input, policy-free
             lr = schedule(state["samples"].astype(jnp.float32)) * state["lr_scale"]
             step_rng = jax.random.fold_in(state["rng"], state["samples"])
@@ -484,8 +461,8 @@ class SGDTrainer:
         On CPU the scan applies bitwise the same updates as K sequential
         single-step dispatches (tests/test_dispatch.py locks this in).
 
-        This amortizes per-dispatch host latency (dominant on remote-tunnel
-        or small-step workloads) and lets XLA overlap the tail of step i with
+        This amortizes per-dispatch host latency (dominant on small-step
+        workloads) and lets XLA overlap the tail of step i with
         the head of step i+1 — the TPU-native analog of the reference's
         compute/comm overlap in ConcurrentRemoteParameterUpdater
         (RemoteParameterUpdater.h:180). `train(steps_per_dispatch=K)` drives
@@ -1250,7 +1227,6 @@ class SGDTrainer:
                 "resize_to needs a DataParallel trainer "
                 "(SGDTrainer(parallel=...)): there is no mesh to re-shape"
             )
-        from paddle_tpu.core.init_ctx import detach_compilation_cache
         from paddle_tpu.parallel import DataParallel
         from paddle_tpu.parallel.mesh import resize_mesh
 
@@ -1260,14 +1236,6 @@ class SGDTrainer:
             new_mesh, batch_axis=old.batch_axis, param_attrs=old.param_attrs,
             rules=old.rules,
         )
-        # A resized process must never again execute a persistent-cache-
-        # DESERIALIZED multi-device program: the re-shard's eager programs
-        # and the train loop's small unsalted helpers (cost-sum adds) repeat
-        # byte-identically across trainer generations, and on jax 0.4.37
-        # CPU a deserialized one corrupts memory or segfaults (see __init__
-        # _cache_salt note). Sticky by design — a scoped opt-out around the
-        # re-shard alone proved insufficient.
-        detach_compilation_cache("elastic resize")
         # canonical layout is the portable waypoint: gather ZeRO-flat
         # slots — and zero3's flat params — back to parameter shapes on
         # the OLD updater...
@@ -1369,8 +1337,7 @@ class SGDTrainer:
         if world == self.parallel.data_axis_size:
             # drain-only epoch (membership churn cancelled out, or the
             # fleet decided the size this trainer already runs): nothing to
-            # re-shard — and no reason to pay the irreversible compile-cache
-            # detach or a recompile for a no-op
+            # re-shard — and no reason to pay a recompile for a no-op
             log.info(
                 "resize epoch %d: already at world %d — drain-only, no "
                 "re-shard", req.epoch, world,

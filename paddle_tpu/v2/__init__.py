@@ -34,10 +34,11 @@ from paddle_tpu.data import image as image  # noqa: F401
 def init(use_gpu: bool = False, trainer_count: int = 1, seed: int = 0, **kwargs):
     """paddle.init analog (python/paddle/v2/__init__.py:65).
 
-    `use_gpu` is accepted for script compatibility and ignored (the backend is
-    whatever jax picks: TPU on TPU hosts, CPU elsewhere). `trainer_count` maps
-    to the data-parallel mesh size; it is recorded and consumed by trainer.SGD.
+    `use_gpu` means "use the accelerator": True requires jax's default backend
+    to be a TPU (core/init_ctx.require_tpu), False — the v2 scripts' default —
+    runs on whatever backend jax picks. `trainer_count` maps to the
+    data-parallel mesh size; it is recorded and consumed by trainer.SGD.
     """
     import paddle_tpu.core.init_ctx as ctx
 
-    ctx.init(trainer_count=trainer_count, seed=seed, **kwargs)
+    ctx.init(use_gpu=use_gpu, trainer_count=trainer_count, seed=seed, **kwargs)

@@ -112,9 +112,12 @@ def test_kernel_matches_oracle_mixed_lengths(seed, ps, pmax):
         pages = [free.pop() for _ in range(n)]
         bt[s_, :n] = pages
         positions[s_] = rng.randint(0, n * ps)
+    # the kernel takes the whole [L, NP, PS, KD] pool and a layer index:
+    # bury the layer under test behind a decoy so a wrong index shows
     got = paged_attention_decode(
-        q, kp, vp, jnp.asarray(bt), jnp.asarray(positions),
-        scale=1.0 / np.sqrt(HD), n_heads=H,
+        q, jnp.stack([vp, kp]), jnp.stack([kp, vp]),
+        jnp.asarray(bt), jnp.asarray(positions),
+        layer=1, scale=1.0 / np.sqrt(HD), n_heads=H,
     )
     want = _oracle_paged_attention(
         q, kp, vp, jnp.asarray(bt), jnp.asarray(positions),

@@ -36,11 +36,16 @@ COLLECTIVES = (
 
 
 def _counts(txt):
-    return {
-        op: len(re.findall(rf"= \S+ {op}\(", txt))
-        + len(re.findall(rf"= \S+ {op}-start\(", txt))
-        for op in COLLECTIVES
-    }
+    """Logical collectives per kind. XLA's combiner may fuse several
+    all-reduces (or all-gathers) into ONE op over a tuple of operands —
+    `%ar = (f32[64], f32[64,16], ...) all-reduce(%a, %b, ...)` — so an op
+    counts once per operand: combined or not, seven gradients reduced are
+    seven."""
+    counts = dict.fromkeys(COLLECTIVES, 0)
+    for op in COLLECTIVES:
+        for m in re.finditer(rf" {op}(?:-start)?\(([^)]*)\)", txt):
+            counts[op] += max(1, m.group(1).count("%"))
+    return counts
 
 
 def _built_trainer(shard, compression=None, extra_layer=False):
@@ -83,14 +88,16 @@ def _compiled_multi_hlo(shard, k=4):
     return tr.make_multi_step().lower(tr.state, batches).compile().as_text()
 
 
-# measured on the container's jax 0.4.37 CPU partitioner; a changed count
-# means the step's collective structure changed — review and re-pin
+# measured on the container's CPU partitioner; a changed count means the
+# step's collective structure changed — review and re-pin
 PINNED = {
     "replicated": {"all-reduce": 7, "reduce-scatter": 0, "all-gather": 0,
                    "collective-permute": 0, "all-to-all": 0},
-    "sharded": {"all-reduce": 7, "reduce-scatter": 0, "all-gather": 6,
+    # the sharded update concatenates its per-parameter payloads, and the
+    # partitioner gathers the concatenation in ONE all-gather
+    "sharded": {"all-reduce": 7, "reduce-scatter": 0, "all-gather": 1,
                 "collective-permute": 0, "all-to-all": 0},
-    "sharded_bf16": {"all-reduce": 7, "reduce-scatter": 0, "all-gather": 6,
+    "sharded_bf16": {"all-reduce": 7, "reduce-scatter": 0, "all-gather": 1,
                      "collective-permute": 0, "all-to-all": 0},
 }
 
@@ -177,7 +184,7 @@ def test_zero1_k_dispatch_keeps_per_step_collectives():
 # 7 all-reduces as the replicated/zero1 step — the grad scatter rides the
 # baseline grad reductions (all-reduce + shard slice on CPU; a true
 # reduce-scatter under the TPU weight-update-sharding pass), so sharding
-# the PARAMS adds zero reduce ops. Measured on the container's jax 0.4.37
+# the PARAMS adds zero reduce ops. Measured on the container's
 # CPU partitioner.
 ZERO3_PINNED = {
     "all-reduce": 7, "reduce-scatter": 0, "all-gather": 6,
@@ -273,7 +280,7 @@ def _compiled_tp_decode_hlo(tp: int, max_slots: int = 4,
 
 
 # 1 embed all-reduce + 2 row-parallel all-reduces per layer; 1 logits
-# all-gather. Measured on the container's jax 0.4.37 CPU partitioner.
+# all-gather. Measured on the container's CPU partitioner.
 TP_DECODE_PINNED = {
     "all-reduce": 1 + 2 * N_LAYERS_TP,
     "reduce-scatter": 0,

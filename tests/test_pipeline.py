@@ -257,36 +257,62 @@ def test_recompile_stats_pass_reset_and_warning(caplog):
 # ---------------------------------------------------------------------------
 
 
-def test_compilation_cache_round_trip(tmp_path):
+def test_compilation_cache_round_trip():
+    """The suite's own cache (conftest → enable_compilation_cache) misses on
+    a new program, persists it, and serves a fresh jit of the same program
+    from disk. The directory follows the one placement rule."""
     import jax
     import jax.numpy as jnp
 
-    from paddle_tpu.core.init_ctx import enable_compilation_cache
+    from paddle_tpu.core.init_ctx import (
+        DEFAULT_CACHE_DIR,
+        enable_compilation_cache,
+    )
 
-    old_dir = jax.config.jax_compilation_cache_dir
-    try:
-        cache_dir = enable_compilation_cache(str(tmp_path / "xla_cache"))
-        assert cache_dir is not None
-        misses0 = stats.RECOMPILES.cache_misses
-        # a program shape unique to this test → must MISS then persist
-        f = jax.jit(lambda x: x * 3.5 + x[::-1])
-        f(jnp.arange(193, dtype=jnp.float32)).block_until_ready()
-        assert stats.RECOMPILES.cache_misses > misses0
-        assert os.listdir(cache_dir)  # entries persisted
-        # identical program from a fresh jit wrapper → served from the cache
-        hits0 = stats.RECOMPILES.cache_hits
-        g = jax.jit(lambda x: x * 3.5 + x[::-1])
-        g(jnp.arange(193, dtype=jnp.float32)).block_until_ready()
-        assert stats.RECOMPILES.cache_hits > hits0
-    finally:
-        if old_dir:  # re-point the session cache (conftest) where it was
-            enable_compilation_cache(old_dir)
-        else:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
+    cache_dir = enable_compilation_cache()
+    assert cache_dir == (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+    )
+    assert jax.config.jax_compilation_cache_dir == cache_dir
+    # the directory outlives the run, so the program must be new EVERY run
+    c = float(np.random.RandomState().rand())
+    misses0 = stats.RECOMPILES.cache_misses
+    f = jax.jit(lambda x: x * c + x[::-1])
+    f(jnp.arange(193, dtype=jnp.float32)).block_until_ready()
+    assert stats.RECOMPILES.cache_misses > misses0
+    assert os.listdir(cache_dir)  # entries persisted
+    # identical program from a fresh jit wrapper → served from the cache
+    hits0 = stats.RECOMPILES.cache_hits
+    g = jax.jit(lambda x: x * c + x[::-1])
+    g(jnp.arange(193, dtype=jnp.float32)).block_until_ready()
+    assert stats.RECOMPILES.cache_hits > hits0
 
 
-def test_compilation_cache_disabled_without_dir(monkeypatch):
-    from paddle_tpu.core.init_ctx import enable_compilation_cache
+def test_compilation_cache_dir_placed_from_outside(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set jax reads it itself: the program
+    redirects nothing, and entries land there (fresh interpreter — jax reads
+    the variable at import)."""
+    import subprocess
+    import sys
 
-    monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE", raising=False)
-    assert enable_compilation_cache(None) is None
+    outside = tmp_path / "outside_cache"
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from paddle_tpu.core.init_ctx import enable_compilation_cache\n"
+        "d = enable_compilation_cache()\n"
+        "jax.jit(lambda x: x * 2.5 + 1)(jnp.ones(7)).block_until_ready()\n"
+        "print(d)\n"
+    )
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(outside),
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+         env.get("PYTHONPATH", "")]
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=str(tmp_path),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(outside)
+    assert os.listdir(outside)

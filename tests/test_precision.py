@@ -38,33 +38,6 @@ from paddle_tpu.trainer.events import EndIteration, EndPass
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
-    """Detach the suite's persistent compile cache for this module.
-
-    This file interleaves collective-donated mesh programs with REPEATED
-    identical single-device donated step programs (same tiny FC model across
-    many tests). That is exactly the jax-0.4.37 CPU pattern where executing
-    a persistent-cache-DESERIALIZED donated program corrupts memory/segfaults
-    once collective donated programs have run in the process — the PR-5
-    `_cache_salt` / PR-8 `detach_compilation_cache` gotcha, which salts MESH
-    step programs but deliberately leaves single-device programs cacheable.
-    Reproducer: `pytest tests/test_parallel.py tests/test_precision.py`
-    segfaults inside test_cross_precision_checkpoint_masters_bitwise's step
-    dispatch without this fixture. Compiling fresh here costs ~10 s and
-    removes the deserialized-execution hazard; the cache is restored for the
-    rest of the suite."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_compilation_cache_dir", prev)
-    compilation_cache.reset_cache()
-
-
 @pytest.fixture(autouse=True)
 def _fresh_names():
     reset_name_scope()

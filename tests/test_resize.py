@@ -193,7 +193,7 @@ def test_same_size_resize_roundtrip_is_bitwise():
     SAME world size (full canonical round trip + re-placement + recompile)
     changes nothing bitwise — and a drained epoch targeting the size the
     trainer already runs is a cheap drain-only epoch (no re-shard, no
-    compile-cache detach) that leaves training bitwise-identical too."""
+    recompile) that leaves training bitwise-identical too."""
     tr_fixed, _ = _run(2)
     before = _params(tr_fixed)
     tr_fixed.resize_to(2)  # the full seam, exercised directly

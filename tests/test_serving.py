@@ -56,12 +56,19 @@ def make_session(model_and_params, **kw):
 
 def greedy_reference(model, params, prompt, max_new):
     """Naive sequential decode: full-context forward per token — the
-    semantics `run_generation`-style serving gives one request at a time."""
+    semantics `run_generation`-style serving gives one request at a time.
+    The context is zero-padded to one fixed length so the forward compiles
+    once, not once per op per token (causal masking: padding cannot leak
+    into a valid position — see ServableLM.forward_logits)."""
+    import jax
     import jax.numpy as jnp
 
+    width = -(-(len(prompt) + max_new) // 16) * 16
+    forward = jax.jit(model.forward_logits)
     toks, out = list(prompt), []
     for _ in range(max_new):
-        logits = model.forward_logits(params, jnp.asarray([toks], jnp.int32))
+        padded = toks + [0] * (width - len(toks))
+        logits = forward(params, jnp.asarray([padded], jnp.int32))
         nxt = int(jnp.argmax(logits[0, len(toks) - 1]))
         out.append(nxt)
         toks.append(nxt)

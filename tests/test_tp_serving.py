@@ -47,23 +47,6 @@ from paddle_tpu.serving.workload import (
 )
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _no_persistent_cache():
-    """Detach the suite's persistent compile cache for this module: it
-    EXECUTES multi-device (TP mesh) programs, and on jax 0.4.37 CPU running
-    a persistent-cache-DESERIALIZED multi-device program corrupts memory or
-    segfaults (the PR-5/PR-8 gotcha test_precision.py documents). Compiling
-    fresh here costs a few seconds; the cache is restored afterwards."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    prev = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_compilation_cache_dir", prev)
-    compilation_cache.reset_cache()
-
-
 # ---------------------------------------------------------------------------
 # rules table
 # ---------------------------------------------------------------------------

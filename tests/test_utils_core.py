@@ -1,6 +1,8 @@
 """Cross-cutting utils: Stat timers (utils/Stat.h parity), layer-name crash
 context (CustomStackTrace parity), flags."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -146,3 +148,65 @@ def test_seq_text_printer_rejects_missing_payload(tmp_path):
             printer.update(beam=None, output=None)
     finally:
         printer.finish()
+
+
+# -- the chip is required where it was asked for -------------------------------
+
+
+def test_init_use_tpu_raises_on_a_backend_nobody_asked_for():
+    """use_tpu means the TPU: with jax's default backend 'cpu' and
+    JAX_PLATFORMS not naming it, init() raises and names the platform it
+    found; with the CPU asked for (this suite), the same call runs."""
+    import jax
+
+    from paddle_tpu.core import init_ctx
+
+    asked = jax.config.jax_platforms
+    assert "cpu" in asked
+    init_ctx.init(use_tpu=True)
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(RuntimeError, match="default backend is 'cpu'"):
+            init_ctx.init(use_tpu=True)
+        init_ctx.init(use_tpu=False)
+    finally:
+        jax.config.update("jax_platforms", asked)
+        init_ctx.init(use_tpu=True)
+
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _chip_smoke(*argv, **env):
+    import subprocess
+    import sys
+
+    return subprocess.run(
+        [sys.executable, os.path.join(_REPO, "chip_smoke.py"), *argv],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", **env), cwd=_REPO,
+        capture_output=True, text=True, timeout=1500,
+    )
+
+
+def test_chip_smoke_is_not_a_pass_on_the_cpu():
+    out = _chip_smoke()
+    assert out.returncode != 0
+    assert "platform: cpu" in out.stdout and "not a TPU" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+@pytest.mark.slow
+def test_chip_smoke_rehearsal_runs_every_phase():
+    """The explicit CPU rehearsal drives all three phases at tiny sizes —
+    on four virtual devices, so the four-chip legs (data mesh of 4, replica
+    check, --tp=4) run too — and still is not a pass: exit code 3, no
+    result line."""
+    out = _chip_smoke(
+        "--rehearse", XLA_FLAGS="--xla_force_host_platform_device_count=4"
+    )
+    assert out.returncode == 3, out.stdout[-2000:] + out.stderr[-2000:]
+    for phase in ("kernels", "serve", "train"):
+        assert f"[{phase}] ok" in out.stdout
+    assert "--tp=4: tokens equal" in out.stdout
+    assert "bitwise equal on 4 chips" in out.stdout
+    assert '"ok"' not in out.stdout
