@@ -1,0 +1,46 @@
+"""Metric arithmetic on plain numbers: percentiles, rates, spreads. No jax."""
+
+from __future__ import annotations
+
+import math
+import statistics
+from typing import Iterable, List, Optional, Sequence
+
+
+def percentile(values: Sequence[float], q: float) -> float:
+    """Nearest-rank percentile (q in 0..100) of a non-empty sample: the
+    smallest value with at least q% of the sample at or below it. No
+    interpolation, so a reported tail is a latency some request really had."""
+    if not values:
+        raise ValueError("percentile of an empty sample")
+    ordered = sorted(values)
+    rank = max(1, math.ceil(q / 100.0 * len(ordered)))
+    return float(ordered[rank - 1])
+
+
+def rate(items: float, seconds: float, chips: int = 1) -> float:
+    """Items of ALL work completed in the window over the WHOLE window."""
+    if seconds <= 0:
+        raise ValueError("a rate needs a window longer than 0 s")
+    return items / seconds / chips
+
+
+def token_gaps(stamps: Sequence[float]) -> List[float]:
+    """Gaps between consecutive token times of one request."""
+    return [b - a for a, b in zip(stamps, stamps[1:])]
+
+
+def ttft_samples(
+    due: Sequence[float], first: Sequence[Optional[float]], worst: float
+) -> List[float]:
+    """First-token time minus DUE time per request; a request with no first
+    token (failed, shed, unfinished) counts as `worst`."""
+    return [worst if f is None else f - d for d, f in zip(due, first)]
+
+
+def iqr_share(values: Iterable[float]) -> float:
+    """The contract's spread: distance between the first and third quartile
+    (statistics.quantiles, n=4) as a share of the median."""
+    vals = list(values)
+    q1, _, q3 = statistics.quantiles(vals, n=4)
+    return (q3 - q1) / statistics.median(vals)
