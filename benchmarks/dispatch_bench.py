@@ -18,10 +18,10 @@ charged for its durability barrier. The headline `value` is the speedup of
 (guard_check_every=16, K=16, async) over yesterday's defaults
 (guard every step, K=1, sync) — the ISSUE 4 acceptance gate is >= 1.3x.
 
-A second, separately-reported pass runs with PADDLE_TPU_TIMER enabled to
-split host time across hostFeed / forwardBackward / ckptFetch / ckptWrite.
-Enabling timers forces a device sync per dispatch, so that pass measures the
-SPLIT, never the throughput.
+The headline config's host time is split by span name (`span_split`), read
+from the train loop's always-recorded spans (obs/trace.py's flight recorder:
+train.input_wait / train.dispatch / train.handler / train.guard_poll /
+train.checkpoint under train.pass) — no second instrumented run, no sync.
 
 ISSUE 9 adds a precision × remat grid leg (`precision_remat` in the JSON):
 f32/bf16 × none/dots (plus "full" with --full), each entry platform-tagged,
@@ -135,22 +135,22 @@ def run_config(args, batches, guard: str, k: int, async_ckpt: bool,
     return out
 
 
-def run_timer_split(args, batches) -> dict:
-    """One instrumented run of the fully-async config: where host time goes.
-    Timers sync per dispatch, so this is diagnostic, not a throughput run."""
-    from paddle_tpu.core.stats import GLOBAL_STATS, enable_timers
+def run_span_split(args, batches) -> dict:
+    """Where the fully-async config's host time goes, by span name, from the
+    ring the train loop always writes (spans of this one run only)."""
+    from paddle_tpu.obs import trace as obs_trace
 
-    GLOBAL_STATS.reset()
-    enable_timers(True)
-    try:
-        run_config(args, batches, guard="16", k=16, async_ckpt=True)
-        return {
-            name: {"total_ms": round(d["total_ms"], 2), "count": d["count"]}
-            for name, d in GLOBAL_STATS.as_dict().items()
-        }
-    finally:
-        enable_timers(False)
-        GLOBAL_STATS.reset()
+    obs_trace.reset()
+    run_config(args, batches, guard="16", k=16, async_ckpt=True)
+    split: dict = {}
+    for name, _t0, dur_ns, *_ in obs_trace.TRACER.snapshot():
+        entry = split.setdefault(name, {"total_ms": 0.0, "count": 0})
+        entry["total_ms"] += dur_ns * 1e-6
+        entry["count"] += 1
+    for entry in split.values():
+        entry["total_ms"] = round(entry["total_ms"], 2)
+    split["dropped_spans"] = obs_trace.TRACER.dropped
+    return split
 
 
 def run_precision_grid(args, batches, full: bool) -> dict:
@@ -242,26 +242,6 @@ def main():
     baseline = sps("1", 1, False)
     best = sps("16", 16, True)
 
-    # observability cost check (ISSUE 7 acceptance: disabled tracing must
-    # not move steps/sec): re-run the headline config with span recording ON
-    # — per-dispatch ring-buffer spans — and report the throughput delta
-    from paddle_tpu.obs import trace as obs_trace
-
-    spans0 = obs_trace.TRACER.recorded
-    obs_trace.enable_tracing(True)
-    try:
-        traced = run_config(args, batches, guard="16", k=16, async_ckpt=True)
-    finally:
-        obs_trace.enable_tracing(False)
-    tracing = {
-        "config": "guard_check_every=16, K=16, async ckpt, PADDLE_TPU_TRACE=1",
-        "steps_per_sec": traced["steps_per_sec"],
-        "vs_disabled": (
-            round(traced["steps_per_sec"] / best, 4) if best else 0.0
-        ),
-        "spans_recorded": obs_trace.TRACER.recorded - spans0,
-    }
-
     out = {
         "metric": "dispatch_runtime_speedup",
         "value": round(best / baseline, 3) if baseline and best else 0.0,
@@ -276,8 +256,7 @@ def main():
         },
         "grid": results,
         "precision_remat": run_precision_grid(args, batches, args.full),
-        "tracing_enabled": tracing,
-        "timer_split_instrumented": run_timer_split(args, batches),
+        "span_split": run_span_split(args, batches),
         "batches_per_pass": args.batches,
         "timed_passes": args.passes,
         "batch_size": args.batch_size,

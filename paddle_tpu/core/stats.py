@@ -1,96 +1,18 @@
-"""Timers + profiler hooks (SURVEY §5 tracing/profiling).
+"""Event counters, recompile/compile telemetry, memory accounting and the
+device-profiler hooks (SURVEY §5 tracing/profiling).
 
-Parity: utils/Stat.h:63 StatSet / :114 Stat / :189 TimerOnce and the
-REGISTER_TIMER* macros (:215-224) that the hot loop stamps
-(TrainerInternal.cpp:94-152, per-layer timers NeuralNetwork.cpp:258/298);
-hl_profiler_start/end (hl_cuda.h:338) maps to jax.profiler traces.
-
-Gating: the reference compiles timers out unless WITH_TIMER=ON; here the
-equivalent is the PADDLE_TPU_TIMER env var / enable_timers() — disabled
-timers cost one dict lookup and a truth test."""
+Parity: hl_profiler_start/end (hl_cuda.h:338) maps to jax.profiler traces.
+The reference's REGISTER_TIMER* macros (utils/Stat.h) have no analog here:
+the intervals they stamped are spans in obs/trace.py's ring (`train.*`,
+`pipeline.*`, `compile.*`), recorded without a switch and without a device
+sync, and counters at the same boundaries live in obs/metrics.py."""
 
 from __future__ import annotations
 
 import contextlib
 import os
 import threading
-import time
 from typing import Dict, Iterator, Optional
-
-
-class Stat:
-    """Accumulates wall time + call count for one named timer (Stat.h:114)."""
-
-    __slots__ = ("name", "total", "count", "max")
-
-    def __init__(self, name: str):
-        self.name = name
-        self.total = 0.0
-        self.count = 0
-        self.max = 0.0
-
-    def add(self, seconds: float) -> None:
-        self.total += seconds
-        self.count += 1
-        if seconds > self.max:
-            self.max = seconds
-
-    def __repr__(self):
-        avg = self.total / max(self.count, 1)
-        return (
-            f"{self.name}: total={self.total * 1e3:.2f}ms count={self.count} "
-            f"avg={avg * 1e3:.3f}ms max={self.max * 1e3:.3f}ms"
-        )
-
-
-class StatSet:
-    """Global registry of Stats (Stat.h:63 StatSet + BarrierStatSet)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._stats: Dict[str, Stat] = {}
-        self.enabled = os.environ.get("PADDLE_TPU_TIMER", "").lower() not in (
-            "", "0", "false", "off",
-        )
-
-    def get(self, name: str) -> Stat:
-        with self._lock:
-            s = self._stats.get(name)
-            if s is None:
-                s = self._stats[name] = Stat(name)
-            return s
-
-    def reset(self) -> None:
-        with self._lock:
-            self._stats.clear()
-
-    def report(self) -> str:
-        # deterministic order (total desc, then name) and a percent-of-total
-        # column, so timer splits are diffable across bench runs — equal
-        # totals no longer land in dict-insertion order
-        with self._lock:
-            stats = sorted(self._stats.values(), key=lambda s: (-s.total, s.name))
-        grand = sum(s.total for s in stats)
-        lines = ["======= StatSet: [GlobalStatInfo] status ======"]
-        lines += [
-            f"  {s!r} ({100.0 * s.total / grand if grand else 0.0:5.1f}%)"
-            for s in stats
-        ]
-        return "\n".join(lines)
-
-    def as_dict(self) -> Dict[str, Dict[str, float]]:
-        with self._lock:
-            return {
-                n: {"total_ms": s.total * 1e3, "count": s.count, "max_ms": s.max * 1e3}
-                for n, s in self._stats.items()
-            }
-
-
-GLOBAL_STATS = StatSet()
-
-
-def enable_timers(on: bool = True) -> None:
-    GLOBAL_STATS.enabled = on
 
 
 # every NAMED EventCounter registers here so the observability plane
@@ -102,8 +24,8 @@ EVENT_COUNTERS: Dict[str, "EventCounter"] = {}
 class EventCounter:
     """Thread-safe named counters for rare-but-load-bearing runtime events
     (divergence guard trips, feeder retries, pipeline stalls, master
-    reconnects). Unlike Stat these are unconditional — failure telemetry must
-    not hide behind PADDLE_TPU_TIMER.
+    reconnects). Unconditional — failure telemetry must not hide behind a
+    switch.
 
     A `name` registers the counter group in EVENT_COUNTERS for the metrics
     exporter; anonymous counters stay private."""
@@ -188,12 +110,6 @@ def device_memory_stats() -> Dict[str, int]:
     if not stats:
         return {}
     return {k: int(v) for k, v in stats.items() if isinstance(v, (int, float))}
-
-# Timer names stamped by the async execution runtime (PADDLE_TPU_TIMER):
-#   hostFeed / h2d        input-pipeline legs (trainer or prefetcher worker)
-#   forwardBackward       the device-step segment (syncs only when timing on)
-#   ckptFetch             non-blocking device→host snapshot copy (train thread)
-#   ckptWrite             npz/CRC/v1/retention on the async writer thread
 
 
 # -- recompile / input-pipeline telemetry ------------------------------------
@@ -289,16 +205,48 @@ RECOMPILES = RecompileStats()
 
 _cache_listener_installed = False
 
+# jax.monitoring's events for the three phases of one compile: a scalar event
+# when a phase begins, a time-span event with wall-clock start and end when
+# it ends, each with the function's name
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_COMPILE_PHASES = {
+    _TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
 
 def install_cache_listener() -> bool:
-    """Count persistent-compilation-cache hits/misses into RECOMPILES via
-    jax.monitoring (events /jax/compilation_cache/cache_hits|cache_misses).
-    Idempotent — True only for the call that installed it; importing jax
+    """jax.monitoring listeners: persistent-compilation-cache hits and
+    misses counted into RECOMPILES (events /jax/compilation_cache/
+    cache_hits|cache_misses), and every compile's phases recorded as
+    `compile.trace` / `compile.lower` / `compile.backend` flight-recorder
+    spans (attrs: `fun_name`) with `paddle_tpu_compile_seconds_total{phase}`
+    beside them: WHICH function was traced, lowered and compiled (or loaded
+    from the persistent cache: `backend` covers both) when, and for how
+    long. Only a thread's OUTERMOST compile event is kept: whatever jax
+    reports while a trace is open on the same thread — a jit traced inside
+    another's trace (every jnp.multiply in a model is one: 4,185 trace
+    events for one ResNet-50 step, 3,350 of them under 10 us), or an op run
+    eagerly on a concrete value there, with its own lower and backend —
+    leaves no span and no seconds of its own, because the outer
+    `compile.trace` span covers it. So one thread's spans never overlap and
+    the three phases' seconds add up to at most that thread's wall time.
+    Idempotent — True only for the call that installed them; importing jax
     here is fine — callers already run under it."""
     global _cache_listener_installed
     if _cache_listener_installed:
         return False
     import jax
+
+    # core reaches UP into obs here, and obs.metrics imports this module: the
+    # compile listener lives beside the cache listener (ISSUE 26) and writes
+    # to the one span ring and the one registry, so the imports stay local
+    # to the install call
+    from paddle_tpu.obs import metrics as obs_metrics
+    from paddle_tpu.obs import trace
+
+    tracing = threading.local()  # .depth: traces open on this thread
 
     def _on_event(event: str, **_kw) -> None:
         if event.endswith("/cache_hits"):
@@ -306,39 +254,29 @@ def install_cache_listener() -> bool:
         elif event.endswith("/cache_misses"):
             RECOMPILES.cache_misses += 1
 
+    def _on_begin(event: str, _value, **_kw) -> None:
+        if event == _TRACE_EVENT:
+            tracing.depth = getattr(tracing, "depth", 0) + 1
+
+    def _on_time_span(event: str, start_time: float, end_time: float, **kw) -> None:
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        if event == _TRACE_EVENT:
+            tracing.depth = max(0, getattr(tracing, "depth", 0) - 1)
+        if getattr(tracing, "depth", 0):
+            return  # inside an outer trace on this thread, which covers it
+        t0, t1 = int(start_time * 1e9), int(end_time * 1e9)
+        trace.record_flight(
+            "compile." + phase, t0, t1, attrs={"fun_name": kw.get("fun_name")}
+        )
+        obs_metrics.observe_compile(phase, (t1 - t0) * 1e-9)
+
     jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_scalar_listener(_on_begin)
+    jax.monitoring.register_event_time_span_listener(_on_time_span)
     _cache_listener_installed = True
     return True
-
-
-@contextlib.contextmanager
-def timer(name: str) -> Iterator[None]:
-    """REGISTER_TIMER_INFO analog: `with timer("forwardBackward"): ...`."""
-    if not GLOBAL_STATS.enabled:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        GLOBAL_STATS.get(name).add(time.perf_counter() - t0)
-
-
-class TimerOnce:
-    """Stat.h:189 TimerOnce: manual start/stop object form."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self._t0: Optional[float] = None
-
-    def start(self) -> "TimerOnce":
-        self._t0 = time.perf_counter()
-        return self
-
-    def stop(self) -> None:
-        if self._t0 is not None and GLOBAL_STATS.enabled:
-            GLOBAL_STATS.get(self.name).add(time.perf_counter() - self._t0)
-        self._t0 = None
 
 
 # -- device profiler (hl_profiler_start/end → jax.profiler) -----------------
@@ -350,9 +288,28 @@ class TimerOnce:
 _profiler_active = False
 
 
+def profiler_options():
+    """The device planes and user annotations are what a trace of a pass is
+    read for. With jax's defaults (Python tracer on, host tracer at every
+    level, each program's HLO copied into the trace) starting the profiler
+    stalled the host for 2.3-4.1 s under ResNet-50's step and the traced
+    pass read 27-65% device idle where the program runs at 0.006% (PERF.md
+    section 6, PR 25; perfbench/harness.py::trace_options has the same
+    finding). So: Python tracer off, host tracer at level 1 (user
+    TraceAnnotations only, so profile_region() still shows), no HLO copies."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    options.enable_hlo_proto = False
+    return options
+
+
 def profiler_start(logdir: str = "/tmp/paddle_tpu_profile") -> None:
-    """Start a jax.profiler trace. A second start while one is active warns
-    and no-ops instead of propagating jax's "already started" RuntimeError."""
+    """Start a jax.profiler trace with profiler_options(). A second start
+    while one is active warns and no-ops instead of propagating jax's
+    "already started" RuntimeError."""
     global _profiler_active
     import logging
 
@@ -365,7 +322,7 @@ def profiler_start(logdir: str = "/tmp/paddle_tpu_profile") -> None:
         )
         return
     try:
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=profiler_options())
     except RuntimeError as e:
         # started outside our bookkeeping (e.g. by user code calling jax
         # directly); adopt it so profiler_stop() still works

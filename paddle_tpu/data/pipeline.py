@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from paddle_tpu.core import faults, stats
+from paddle_tpu.obs import metrics as obs_metrics
 from paddle_tpu.obs import trace
 
 log = logging.getLogger("paddle_tpu.pipeline")
@@ -48,7 +49,10 @@ class StackedBatch(dict):
 
 
 class _Group(list):
-    """Marker: a stack_k-sized run of raw reader items (worker-side only)."""
+    """Marker: a stack_k-sized run of raw reader items (worker-side only);
+    `first` is the index in reader order of its first item."""
+
+    first: int = 0
 
 
 class _Singles(list):
@@ -81,7 +85,17 @@ def iter_async(
     stall_warn_s (default $PADDLE_TPU_STALL_WARN_S or 30; <= 0 disables):
     the consumer logs a warning whenever it has been starved that long
     waiting on the producer — the watchdog that distinguishes "feeder
-    wedged" from "training slow"."""
+    wedged" from "training slow".
+
+    Spans: the worker adopts the span context of the thread that CALLED
+    iter_async (a train pass, when the trainer called its reader), so
+    whatever `prepare` records joins that trace; each blocking put is a
+    `pipeline.queue_full` span (attrs: `item`, the queue item's index in
+    reader order) — long when the feed runs ahead of its consumer, as
+    `train.input_wait` is long when it runs behind."""
+    # taken now, on the caller's thread: consume() below is a generator, and
+    # its body runs only at the consumer's first next()
+    ctx = trace.current_context()
     if stall_warn_s is None:
         stall_warn_s = float(os.environ.get("PADDLE_TPU_STALL_WARN_S", "30"))
     if stall_warn_s <= 0:  # disabled: plain blocking get, no watchdog
@@ -90,15 +104,17 @@ def iter_async(
     err: List[BaseException] = []
     stop = threading.Event()
 
-    def put(item) -> bool:
+    def put(item, n: int) -> bool:
         # bounded put that notices consumer abandonment
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
+        # span-ok: one ring write per queue item, constant name, int attr
+        with trace.flight("pipeline.queue_full", item=n):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
     def prepare_with_retry(raw):
         for attempt in range(retries + 1):
@@ -116,43 +132,48 @@ def iter_async(
                 time.sleep(min(0.05 * 2 ** attempt, 1.0))
 
     def work():
-        try:
-            for raw in reader():
-                item = prepare_with_retry(raw)
-                if item is SKIP:
-                    continue
-                if not put(item):
-                    return
-        except BaseException as e:  # surface worker errors to the consumer
-            err.append(e)
-        finally:
-            put(_STOP)
-
-    t = threading.Thread(target=work, daemon=True, name=name)
-    t.start()
-    try:
-        while True:
+        n = -1
+        with trace.activate(ctx):
             try:
-                item = q.get(timeout=stall_warn_s)
-            except queue.Empty:  # starved, not done: watchdog, then keep waiting
-                stats.FT_EVENTS.incr("pipeline_stall")
-                log.warning(
-                    "%s: consumer starved for > %.1fs waiting on the producer "
-                    "thread (feeder wedged or reader stalled?)",
-                    name, stall_warn_s,
-                )
-                continue
-            if item is _STOP:
-                break
-            yield item
-        t.join()
-        if err:
-            # the exception object still carries the worker's traceback, so
-            # the failing feeder frame surfaces here, not just this loop
-            # (locked in by test_worker_traceback_reaches_consumer)
-            raise err[0]
-    finally:
-        stop.set()  # unblock and retire the producer on early exit
+                for n, raw in enumerate(reader()):
+                    item = prepare_with_retry(raw)
+                    if item is SKIP:
+                        continue
+                    if not put(item, n):
+                        return
+            except BaseException as e:  # surface worker errors to the consumer
+                err.append(e)
+            finally:
+                put(_STOP, n + 1)
+
+    def consume():
+        t = threading.Thread(target=work, daemon=True, name=name)
+        t.start()
+        try:
+            while True:
+                try:
+                    item = q.get(timeout=stall_warn_s)
+                except queue.Empty:  # starved, not done: watchdog, then keep waiting
+                    stats.FT_EVENTS.incr("pipeline_stall")
+                    log.warning(
+                        "%s: consumer starved for > %.1fs waiting on the producer "
+                        "thread (feeder wedged or reader stalled?)",
+                        name, stall_warn_s,
+                    )
+                    continue
+                if item is _STOP:
+                    break
+                yield item
+            t.join()
+            if err:
+                # the exception object still carries the worker's traceback, so
+                # the failing feeder frame surfaces here, not just this loop
+                # (locked in by test_worker_traceback_reaches_consumer)
+                raise err[0]
+        finally:
+            stop.set()  # unblock and retire the producer on early exit
+
+    return consume()
 
 
 def is_device_batch(batch: Any) -> bool:
@@ -216,9 +237,14 @@ class DevicePrefetcher:
     One iteration = one pass. Worker exceptions surface in the consumer;
     abandoning the iterator (break / GeneratorExit) retires the worker.
 
-    Timers (PADDLE_TPU_TIMER): worker time lands in `hostFeed` (feeder +
-    coerce) and `h2d` (device_put dispatch), the same names the synchronous
-    trainer path stamps — the report shows where input time went either way.
+    Spans (always recorded, on the worker's thread row, in the trace of the
+    pass whose trainer called this reader): `pipeline.hostFeed` (feeder +
+    coerce, one per batch, counted in `paddle_tpu_pipeline_batches_total`),
+    `pipeline.stack` (the np.stack of a stack_k group), `pipeline.h2d`
+    (device_put dispatch), `pipeline.queue_full` (iter_async). Each carries
+    `batch`, the index in reader order of the (first) batch it handled —
+    the trainer's `train.input_wait` and `train.dispatch` carry the same
+    index, unless the divisibility filter dropped a batch in between.
     """
 
     def __init__(
@@ -257,20 +283,20 @@ class DevicePrefetcher:
         rest of the run lands directly on the new mesh."""
         self.parallel = parallel
 
-    def _feed(self, raw: Any) -> Dict[str, Any]:
-        """Raw reader item → feed-ready host batch (the hostFeed leg).
-        Span + timer stamp the same interval: the timer aggregates, the span
-        shows THIS batch's feed on the worker-thread row of the trace."""
-        with trace.span("pipeline.hostFeed"):
-            with stats.timer("hostFeed"):
-                return (
-                    self.feeder(raw)
-                    if self.feeder is not None and not isinstance(raw, dict)
-                    else coerce_batch(raw)
-                )
+    def _feed(self, raw: Any, index: int) -> Dict[str, Any]:
+        """Raw reader item → feed-ready host batch (the hostFeed leg)."""
+        obs_metrics.observe_pipeline_batch()
+        # span-ok: one ring write per batch, constant name, int attr
+        with trace.flight("pipeline.hostFeed", batch=index):
+            return (
+                self.feeder(raw)
+                if self.feeder is not None and not isinstance(raw, dict)
+                else coerce_batch(raw)
+            )
 
     def _device_put(
-        self, batch: Dict[str, Any], par: Optional[Any], stacked: bool = False
+        self, batch: Dict[str, Any], par: Optional[Any], index: int,
+        stacked: bool = False,
     ) -> Any:
         """Feed-ready batch → device-resident batch (the h2d leg) under the
         plan `par` the caller captured at preparation start (rebind_parallel
@@ -278,7 +304,8 @@ class DevicePrefetcher:
         group with the scan-axis sharding; the chaos sleep fires once per
         call either way = once per dispatch."""
         faults.get().sleep("h2d_delay")  # chaos hook: slow transfer leg
-        with trace.span("pipeline.h2d", stacked=stacked):
+        # span-ok: one ring write per put, constant name, int/bool attrs
+        with trace.flight("pipeline.h2d", batch=index, stacked=stacked):
             if par is not None:
                 put = par.shard_batches if stacked else par.shard_batch
                 return put(batch)
@@ -286,38 +313,40 @@ class DevicePrefetcher:
                 return {k: jax.device_put(v, self.device) for k, v in batch.items()}
             return {k: jax.device_put(v) for k, v in batch.items()}
 
-    def _prepare(self, raw: Any) -> Any:
-        """Raw reader item → device-resident batch (SKIP = drop)."""
+    def _prepare(self, indexed: Any) -> Any:
+        """(index, raw reader item) → device-resident batch (SKIP = drop)."""
+        index, raw = indexed
         par = self.parallel  # one capture: pad and shard under ONE plan
-        batch = self._feed(raw)
-        with stats.timer("h2d"):
-            if par is not None:
-                # pad to the shard multiple with a row mask instead of
-                # dropping (cost layers zero pad rows; see
-                # DataParallel.pad_batch) — the sample stream now matches
-                # the unsharded reader exactly; only unpaddable ragged
-                # batches drop
-                batch = par.maybe_pad_batch(batch, where="prefetcher")
-                if batch is None:
-                    return SKIP
-            return self._device_put(batch, par)
+        batch = self._feed(raw, index)
+        if par is not None:
+            # pad to the shard multiple with a row mask instead of
+            # dropping (cost layers zero pad rows; see
+            # DataParallel.pad_batch) — the sample stream now matches
+            # the unsharded reader exactly; only unpaddable ragged
+            # batches drop
+            batch = par.maybe_pad_batch(batch, where="prefetcher")
+            if batch is None:
+                return SKIP
+        return self._device_put(batch, par, index)
 
     def _grouped_reader(self):
-        buf: List[Any] = []
-        for raw in self.reader():
-            buf.append(raw)
-            if len(buf) == self.stack_k:
-                yield _Group(buf)
-                buf = []
-        if buf:
-            yield _Group(buf)  # trailing remainder; degrades to singles
+        group = _Group()
+        for i, raw in enumerate(self.reader()):
+            if not group:
+                group.first = i
+            group.append(raw)
+            if len(group) == self.stack_k:
+                yield group
+                group = _Group()
+        if group:
+            yield group  # trailing remainder; degrades to singles
 
     def _prepare_group(self, group: "_Group") -> Any:
         """A run of stack_k raw items → one StackedBatch (the fast path: one
         np.stack + one device put covering K steps), or _Singles/SKIP when
         the group cannot stack as a whole."""
         par = self.parallel  # one capture: the whole group under ONE plan
-        batches = [self._feed(raw) for raw in group]
+        batches = [self._feed(raw, group.first + j) for j, raw in enumerate(group)]
         if par is not None:
             # a padded batch gains a mask slot → its signature differs →
             # the group degrades to singles below
@@ -335,31 +364,39 @@ class DevicePrefetcher:
             len(batches) == self.stack_k
             and len({stats.batch_signature(b) for b in batches}) == 1
         )
-        with stats.timer("h2d"):
-            if not stackable:
-                return _Singles(self._device_put(b, par) for b in batches)
+        if not stackable:
+            return _Singles(
+                self._device_put(b, par, group.first + j)
+                for j, b in enumerate(batches)
+            )
+        # span-ok: one ring write per group, constant name, int attrs
+        with trace.flight("pipeline.stack", batch=group.first, k=self.stack_k):
             stacked = {
                 k: np.stack([np.asarray(b[k]) for b in batches])
                 for k in batches[0]
             }
-            out = self._device_put(stacked, par, stacked=True)
+        out = self._device_put(stacked, par, group.first, stacked=True)
         sb = StackedBatch(out)
         sb.k = self.stack_k
         return sb
 
     def __iter__(self):
+        # iter_async is called HERE, on the consumer's thread, so that the
+        # worker's spans join the consumer's trace (a train pass)
         if self.stack_k <= 1:
             return iter_async(
-                self.reader, self._prepare, self.prefetch_depth,
+                lambda: enumerate(self.reader()), self._prepare,
+                self.prefetch_depth,
                 name="paddle-tpu-device-prefetch", retries=self.feed_retries,
             )
-        return self._iter_stacked()
-
-    def _iter_stacked(self):
-        for item in iter_async(
+        return self._unpack_singles(iter_async(
             self._grouped_reader, self._prepare_group, self.prefetch_depth,
             name="paddle-tpu-device-prefetch", retries=self.feed_retries,
-        ):
+        ))
+
+    @staticmethod
+    def _unpack_singles(items):
+        for item in items:
             if isinstance(item, _Singles):
                 # degraded group: hand the batches over one by one — the
                 # trainer re-buffers or single-steps them as appropriate

@@ -3,13 +3,14 @@
 Three pillars over every subsystem (trainer, data pipeline, master RPC
 plane, serving):
 
-  * ``obs.trace``   — structured spans in a bounded per-process ring buffer,
-                      near-zero cost when disabled (PADDLE_TPU_TRACE gate,
-                      same discipline as PADDLE_TPU_TIMER), trace context
-                      piggybacked on the line-JSON RPC frames, exported as
-                      Perfetto-loadable Chrome trace-event JSON.
+  * ``obs.trace``   — structured spans in a bounded per-process ring buffer:
+                      the train loop, the prefetch worker and every compile
+                      always record (a flight recorder); RPC, serving and
+                      router spans are near-zero cost unless PADDLE_TPU_TRACE
+                      is set; trace context piggybacked on the RPC frames,
+                      exported as Perfetto-loadable Chrome trace-event JSON.
   * ``obs.metrics`` — counter/gauge/histogram registry absorbing the
-                      existing StatSet/EventCounter telemetry; trainer
+                      existing EventCounter telemetry; trainer
                       snapshots ride on master heartbeats into a fleet-wide
                       aggregate; Prometheus text via the `metrics` RPC and
                       ``python -m paddle_tpu.obs export``.
@@ -25,6 +26,7 @@ from paddle_tpu.obs.trace import (  # noqa: F401
     TRACER,
     enable_tracing,
     export_chrome,
+    flight,
     record_span,
     span,
 )
@@ -34,6 +36,7 @@ __all__ = [
     "TRACER",
     "enable_tracing",
     "export_chrome",
+    "flight",
     "metrics",
     "record_span",
     "span",

@@ -1,6 +1,6 @@
 """Fleet metrics: one registry over every counter the runtime already keeps.
 
-The repo grew its telemetry organically — `StatSet` timers, the
+The repo grew its telemetry organically — the
 `FT_EVENTS`/`DATA_EVENTS`/`SERVING_EVENTS` EventCounters, `RecompileStats`,
 ad-hoc `stats()` dicts on the master/allocator/serving server. This module
 puts ONE read path over all of them:
@@ -33,13 +33,17 @@ __all__ = [
     "REGISTRY",
     "Sample",
     "aggregate_snapshots",
+    "observe_compile",
     "observe_deadline_miss",
     "observe_engine_restart",
+    "observe_input_wait",
     "observe_pages_recycled",
+    "observe_pipeline_batch",
     "observe_prefix_cow",
     "observe_prefix_evictions",
     "observe_prefix_hit",
     "observe_shed",
+    "observe_train_dispatch",
     "snapshot",
     "to_prometheus_text",
 ]
@@ -182,8 +186,8 @@ class MetricsRegistry:
 
 
 def _stats_collector() -> Iterable[Sample]:
-    """Absorb core/stats.py state: every registered EventCounter group, the
-    StatSet timers, and the recompile/compile-cache telemetry."""
+    """Absorb core/stats.py state: every registered EventCounter group and
+    the recompile/compile-cache telemetry."""
     from paddle_tpu.core import stats
 
     for group, ec in stats.EVENT_COUNTERS.items():
@@ -192,15 +196,6 @@ def _stats_collector() -> Iterable[Sample]:
                 "paddle_tpu_events_total", "counter", float(n),
                 (("event", event), ("group", group)),
             )
-    for name, d in sorted(stats.GLOBAL_STATS.as_dict().items()):
-        yield Sample(
-            "paddle_tpu_timer_ms_total", "counter", float(d["total_ms"]),
-            (("name", name),),
-        )
-        yield Sample(
-            "paddle_tpu_timer_calls_total", "counter", float(d["count"]),
-            (("name", name),),
-        )
     rc = stats.RECOMPILES
     yield Sample(
         "paddle_tpu_shape_signatures", "gauge", float(rc.total_signatures())
@@ -250,6 +245,50 @@ def observe_resize(phase_seconds: Mapping[str, float]) -> None:
     )
     for phase, s in phase_seconds.items():
         lat.inc(float(s), phase=phase)
+
+
+# -- the train loop's and set-up's own time (ISSUE 26) ------------------------
+#
+# Counters at the boundaries where obs/trace.py's flight-recorder spans are
+# stamped (train.input_wait, train.dispatch, pipeline.hostFeed, compile.*),
+# fed the spans' own durations: over any interval, input-wait share is a ratio
+# of two deltas on a dashboard, without a trace. Counters, so that heartbeat
+# snapshots sum across a fleet.
+
+
+def observe_input_wait(seconds: float) -> None:
+    """The train thread waited this long for its next item (one
+    `train.input_wait` span)."""
+    REGISTRY.counter(
+        "paddle_tpu_train_input_wait_seconds_total",
+        "seconds the train loop waited for its reader's next item",
+    ).inc(seconds)
+
+
+def observe_train_dispatch() -> None:
+    """One device dispatch enqueued (one `train.dispatch` span)."""
+    REGISTRY.counter(
+        "paddle_tpu_train_dispatches_total",
+        "device dispatches enqueued by the train loop",
+    ).inc()
+
+
+def observe_pipeline_batch() -> None:
+    """The prefetch worker fed one batch (one `pipeline.hostFeed` span)."""
+    REGISTRY.counter(
+        "paddle_tpu_pipeline_batches_total",
+        "batches fed by the prefetch worker",
+    ).inc()
+
+
+def observe_compile(phase: str, seconds: float) -> None:
+    """One phase of one compile finished (one `compile.<phase>` span);
+    phase is 'trace', 'lower' or 'backend' (XLA, or the persistent cache
+    handing the executable back)."""
+    REGISTRY.counter(
+        "paddle_tpu_compile_seconds_total",
+        "seconds spent tracing, lowering and backend-compiling, by phase",
+    ).inc(seconds, phase=phase)
 
 
 # -- serving resilience (ISSUE 10) -------------------------------------------

@@ -2,19 +2,33 @@
 
 One request or one training step crosses several threads (RPC handler,
 serving engine, prefetch worker) and several PROCESSES (trainer → master →
-standby; serving client → server); the per-subsystem timers in core/stats.py
-cannot say "this 40 ms belonged to THAT request". A span fixes that: a named
-interval carrying (trace_id, span_id, parent_id, wall-clock, attrs), recorded
-into a fixed-size ring so a long-lived server never grows, and exported as
-Chrome trace-event JSON loadable in Perfetto (chrome://tracing).
+standby; serving client → server). A span is a named interval carrying
+(trace_id, span_id, parent_id, wall-clock, attrs), recorded into a
+fixed-size ring so a long-lived server never grows, and exported as Chrome
+trace-event JSON loadable in Perfetto (chrome://tracing).
 
-Gating discipline matches PADDLE_TPU_TIMER (core/stats.py): tracing is off
-unless PADDLE_TPU_TRACE is set / enable_tracing() is called, and a disabled
-`span()` costs one attribute lookup + a truth test — it returns a shared
-no-op context manager, builds no strings, and takes no locks. Hot loops
-(train dispatch, serving decode) therefore stamp spans unconditionally; the
-lint in tests/test_lint_hotloop.py pins those sites and bans file I/O and
-unconditional string formatting inside them.
+Two kinds of site, one ring:
+
+  * flight-recorder spans (`flight()`, `record_flight()`): ALWAYS recorded.
+    The train loop (`train.*`), the prefetch worker (`pipeline.*`) and the
+    compile listener (`compile.*`, core/stats.py) use them: a fixed number
+    per dispatch and per compile, a few microseconds each, bounded by the
+    ring. They are what the benchmark's per-layer readers read
+    (perfbench/spans.py), and nobody has to switch them on.
+  * gated spans (`span()`, `record_span()`, `span_from_monotonic()`,
+    `server_span()`, the wire context): off unless PADDLE_TPU_TRACE is set /
+    enable_tracing() is called; a disabled site costs one attribute lookup +
+    a truth test, builds no strings and takes no locks. RPC, serving and
+    router sites are per request or per decode step, so they stay gated.
+
+The lint in tests/test_lint_hotloop.py pins both kinds of site in the hot
+loops and bans file I/O and string formatting inside them.
+
+Clock: `time.time_ns()`, whole nanoseconds of the wall clock. A device
+trace's events are `profile_start_time + start_ns` on the same clock (the
+xplane's `Task Environment` plane holds `profile_start_time` in unix ns), so
+program spans and device ops line up without the profiler's host tracer.
+The Chrome export divides by 1000 (its `ts`/`dur` unit is microseconds).
 
 Cross-process correlation: `wire_context()` serializes the current span as a
 tiny {"t": trace_id, "s": span_id} dict that rides on the line-JSON RPC
@@ -39,16 +53,19 @@ __all__ = [
     "current_context",
     "enable_tracing",
     "export_chrome",
+    "flight",
     "merge_chrome",
+    "record_flight",
     "record_span",
     "reset",
     "span",
     "wire_context",
 ]
 
-# wall-clock microseconds: Chrome trace `ts` unit, and shared across processes
-# so client/server spans of one RPC line up on a common axis
-_now_us = lambda: time.time_ns() // 1000  # noqa: E731
+# wall-clock nanoseconds: shared across processes, so client/server spans of
+# one RPC line up on a common axis, and with a device trace's
+# profile_start_time + start_ns
+_now_ns = time.time_ns
 
 _REQUIRED_EVENT_KEYS = ("ph", "ts", "pid", "tid", "name")  # golden-format keys
 
@@ -60,8 +77,11 @@ class Tracer:
         self.enabled = os.environ.get("PADDLE_TPU_TRACE", "").lower() not in (
             "", "0", "false", "off",
         )
+        # the default holds a ResNet-50 training process whole, several
+        # times over: set-up and a 50 s window are a few thousand spans each
+        # (counts in PERF.md section 6, PR 26)
         self.capacity = capacity or int(
-            os.environ.get("PADDLE_TPU_TRACE_BUF", "8192")
+            os.environ.get("PADDLE_TPU_TRACE_BUF", "32768")
         )
         self._lock = threading.Lock()
         self._ring: List[Optional[tuple]] = [None] * self.capacity
@@ -95,15 +115,15 @@ class Tracer:
     def record(
         self,
         name: str,
-        t0_us: int,
-        dur_us: int,
+        t0_ns: int,
+        dur_ns: int,
         trace_id: str,
         span_id: str,
         parent_id: Optional[str],
         attrs: Optional[Dict[str, Any]],
     ) -> None:
         row = (
-            name, int(t0_us), int(dur_us), trace_id, span_id, parent_id,
+            name, int(t0_ns), int(dur_ns), trace_id, span_id, parent_id,
             attrs, threading.get_ident(),
         )
         with self._lock:
@@ -168,7 +188,13 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id", "_t0")
+    """An open span. `attrs` may be set until the span closes (the train
+    loop learns a batch's index only once the pull returned); `dur_ns` is
+    there after it closed, for the counter kept at the same boundary."""
+
+    __slots__ = (
+        "name", "attrs", "trace_id", "span_id", "parent_id", "_t0", "dur_ns",
+    )
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
         self.name = name
@@ -182,11 +208,11 @@ class _LiveSpan:
             self.trace_id, self.parent_id = parent[0], parent[1]
         self.span_id = TRACER.new_span_id()
         TRACER._stack().append((self.trace_id, self.span_id))
-        self._t0 = _now_us()
+        self._t0 = _now_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
-        t1 = _now_us()
+        self.dur_ns = _now_ns() - self._t0
         st = TRACER._stack()
         # unwind to our own entry: a span leaked open by an exception below
         # us must not poison this thread's context stack forever
@@ -195,7 +221,7 @@ class _LiveSpan:
             if st.pop() == want:
                 break
         TRACER.record(
-            self.name, self._t0, t1 - self._t0, self.trace_id, self.span_id,
+            self.name, self._t0, self.dur_ns, self.trace_id, self.span_id,
             self.parent_id, self.attrs,
         )
         return False
@@ -212,19 +238,24 @@ def span(name: str, **attrs: Any):
     return _LiveSpan(name, attrs or None)
 
 
-def record_span(
+def flight(name: str, **attrs: Any) -> _LiveSpan:
+    """`with flight("train.dispatch", k=8): ...` — a span recorded whether
+    or not tracing is enabled (the flight recorder; see the module
+    docstring for which sites may use it)."""
+    return _LiveSpan(name, attrs or None)
+
+
+def record_flight(
     name: str,
-    t0_us: int,
-    t1_us: int,
+    t0_ns: int,
+    t1_ns: int,
     trace_id: Optional[str] = None,
     parent_id: Optional[str] = None,
     attrs: Optional[Dict[str, Any]] = None,
 ) -> None:
-    """Record a span whose interval was measured externally (queue waits,
-    time-to-first-token, pass durations). Inherits the thread's current
-    context when trace_id is not given. No-op when disabled."""
-    if not TRACER.enabled:
-        return
+    """Always record a span whose interval was measured externally (jax's
+    compile phases arrive as wall-clock start and end). Inherits the
+    thread's current context when trace_id is not given."""
     if trace_id is None:
         cur = TRACER.current()
         if cur is not None:
@@ -232,9 +263,23 @@ def record_span(
         else:
             trace_id = TRACER.new_trace_id()
     TRACER.record(
-        name, t0_us, max(0, int(t1_us) - int(t0_us)), trace_id,
+        name, t0_ns, max(0, int(t1_ns) - int(t0_ns)), trace_id,
         TRACER.new_span_id(), parent_id, attrs,
     )
+
+
+def record_span(
+    name: str,
+    t0_ns: int,
+    t1_ns: int,
+    trace_id: Optional[str] = None,
+    parent_id: Optional[str] = None,
+    attrs: Optional[Dict[str, Any]] = None,
+) -> None:
+    """record_flight() behind the PADDLE_TPU_TRACE gate (queue waits,
+    time-to-first-token). No-op when disabled."""
+    if TRACER.enabled:
+        record_flight(name, t0_ns, t1_ns, trace_id, parent_id, attrs)
 
 
 def span_from_monotonic(
@@ -248,9 +293,9 @@ def span_from_monotonic(
     scheduler's clock) as a wall-clock span ending now."""
     if not TRACER.enabled:
         return
-    t1 = _now_us()
-    dur_us = int((time.monotonic() - started_monotonic) * 1e6)
-    record_span(name, t1 - max(0, dur_us), t1, trace_id, parent_id, attrs)
+    t1 = _now_ns()
+    dur_ns = int((time.monotonic() - started_monotonic) * 1e9)
+    record_span(name, t1 - max(0, dur_ns), t1, trace_id, parent_id, attrs)
 
 
 # -- cross-process context ---------------------------------------------------
@@ -296,13 +341,17 @@ class _Activation:
 def activate(ctx) -> _Activation:
     """Re-enter a foreign span context so spans opened inside join its trace.
 
-    `ctx` is a wire dict ({"t": ..., "s": ...}), a (trace_id, span_id)
-    tuple, or None (no-op). Disabled tracing is also a no-op."""
-    if not TRACER.enabled or ctx is None:
+    `ctx` is a wire dict ({"t": ..., "s": ...}) that arrived on an RPC
+    frame: a no-op when tracing is disabled, as every wire site is; or a
+    (trace_id, span_id) tuple handed from one thread of this process to
+    another (current_context() on the train thread, adopted by the prefetch
+    worker): always entered, since flight-recorder spans record either way;
+    or None (no-op)."""
+    if ctx is None:
         return _Activation(None)
     if isinstance(ctx, dict):
         t, s = ctx.get("t"), ctx.get("s")
-        if not t:
+        if not TRACER.enabled or not t:
             return _Activation(None)
         return _Activation((str(t), str(s or "")))
     return _Activation((ctx[0], ctx[1]))
@@ -349,8 +398,8 @@ def _to_event(row: tuple, pid: int) -> Dict[str, Any]:
         "ph": "X",
         "cat": "paddle_tpu",
         "name": name,
-        "ts": t0,
-        "dur": max(0, dur),
+        "ts": t0 / 1000,  # the ring keeps nanoseconds, Chrome wants us
+        "dur": max(0, dur) / 1000,
         "pid": pid,
         "tid": tid,
         "args": args,

@@ -30,7 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 import jax
 import numpy as np
 
-from paddle_tpu.core import faults, stats
+from paddle_tpu.core import faults
 
 log = logging.getLogger("paddle_tpu.checkpoint")
 
@@ -234,8 +234,7 @@ class AsyncCheckpointer:
                 fn, desc = self._job
             err: Optional[BaseException] = None
             try:
-                with stats.timer("ckptWrite"):
-                    fn()
+                fn()
             except BaseException as e:  # surfaces at the next submit()/wait()
                 err = e
                 log.error("async checkpoint write (%s) failed: %s", desc, e)

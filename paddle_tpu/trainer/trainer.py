@@ -42,6 +42,8 @@ TrainState = Dict[str, Any]  # params / opt / states / avg / samples / rng
 
 DIVERGENCE_POLICIES = ("skip_batch", "rollback", "raise")
 
+_END_OF_PASS = object()  # next()'s default: the reader has no further item
+
 # rematerialization policies for the compiled step's backward pass (see
 # _build_step): "dots" keeps matmul/conv outputs and recomputes everything
 # elementwise; "conv_only" keeps only the tagged conv/matmul outputs
@@ -677,12 +679,16 @@ class SGDTrainer:
                     or (pass_id == resume_pass and not resume_mid)
                 ):
                     continue  # completed by the run we are resuming
-                resume_pending = self._train_one_pass(
-                    reader, pass_id, event_handler, feeder, test_reader,
-                    save_dir, log_period, keep_last_n, steps_per_dispatch,
-                    async_checkpoint, resume_pass, resume_mid, resume_skip,
-                    resume_pending, resize_barrier,
-                )
+                # span-ok: the pass is a real span, open while the pass runs,
+                # so that every span of the pass (the prefetch worker's
+                # too) has its trace id and a parent
+                with trace.flight("train.pass", pass_id=pass_id) as pass_span:
+                    resume_pending = self._train_one_pass(
+                        reader, pass_id, event_handler, feeder, test_reader,
+                        save_dir, log_period, keep_last_n, steps_per_dispatch,
+                        async_checkpoint, resume_pass, resume_mid, resume_skip,
+                        resume_pending, resize_barrier, pass_span,
+                    )
             if resume_pending:
                 # every requested pass was already checkpointed — nothing ran,
                 # so state was never initialized; pull one batch just for
@@ -740,10 +746,20 @@ class SGDTrainer:
         resume_mid: bool,
         resume_skip: int,
         resume_pending: bool,
-        resize_barrier: Optional[Callable] = None,
+        resize_barrier: Optional[Callable],
+        pass_span: Any,
     ) -> bool:
         """One training pass of the async execution runtime. Returns the
         (possibly cleared) resume_pending flag.
+
+        The pass accounts for its own time in obs/trace.py's ring, recorded
+        without a switch: under `pass_span` (train()'s `train.pass`) every
+        pull from the reader is a `train.input_wait` span, every call of the
+        caller's event handler a `train.handler` span, every dispatch a
+        `train.dispatch` span, beside `train.guard_poll`, `train.checkpoint`
+        and the pass-end `train.cost_fetch`; what of the pass's duration
+        none of them covers is the loop's own host work. A fixed number of
+        ring writes per dispatch, no device sync, no formatting.
 
         Hot-loop discipline (enforced by tests/test_lint_hotloop.py): nothing
         in this body fetches a device value per step — cost accumulation is
@@ -753,7 +769,14 @@ class SGDTrainer:
         syncs once at pass end. Lines that DO fetch carry a `sync-ok` tag."""
         inj = faults.get()
         guard_on = self.divergence_policy is not None
-        event_handler(BeginPass(pass_id))
+
+        def emit(event) -> None:
+            # span-ok: the caller's time (a handler may block on a device
+            # value), kept apart from the loop's own
+            with trace.flight("train.handler"):
+                event_handler(event)
+
+        emit(BeginPass(pass_id))
         self.updater.start_pass()
         stats.RECOMPILES.start_pass()
         t0 = time.time()
@@ -811,26 +834,20 @@ class SGDTrainer:
             # [K, B, ...] signature is its own program); churn past the
             # threshold warns (misconfigured seq_buckets)
             stats.RECOMPILES.record(stats.batch_signature(batch))
-            event_handler(BeginIteration(pass_id, idx_first))
-            # REGISTER_TIMER_INFO("forwardBackward") parity
-            # (TrainerInternal.cpp:94-152); enable via PADDLE_TPU_TIMER.
-            # Timing is opt-in, so when enabled we sync the device inside
-            # the timer — otherwise it would measure only async dispatch.
+            emit(BeginIteration(pass_id, idx_first))
             # span-ok: one ring-buffer span per DISPATCH (constant name, int
-            # attrs, no formatting) — a no-op truth test when tracing is off;
-            # note it measures dispatch latency, not device time (no sync)
-            with trace.span("train.dispatch", first=idx_first, k=k):
-                with stats.timer("forwardBackward"):
-                    if k == 1:
-                        self.state, cost, extras = self._step_fn(self.state, batch)
-                        costs = None
-                    else:
-                        if self._multi_fn is None:
-                            self._multi_fn = self.make_multi_step()
-                        self.state, costs = self._multi_fn(self.state, batch)
-                        cost, extras = costs[-1], {}
-                    if stats.GLOBAL_STATS.enabled:
-                        jax.block_until_ready(cost)  # sync-ok: opt-in timing only
+            # attrs, no formatting); it measures the host's time to enqueue
+            # the dispatch, not device time (no sync)
+            with trace.flight("train.dispatch", first=idx_first, k=k):
+                if k == 1:
+                    self.state, cost, extras = self._step_fn(self.state, batch)
+                    costs = None
+                else:
+                    if self._multi_fn is None:
+                        self._multi_fn = self.make_multi_step()
+                    self.state, costs = self._multi_fn(self.state, batch)
+                    cost, extras = costs[-1], {}
+            obs_metrics.observe_train_dispatch()
             if self._resize_mark is not None:
                 # first dispatch on the post-resize mesh returned (compile
                 # included): close the resume leg of the resize latency split
@@ -859,7 +876,7 @@ class SGDTrainer:
                 suppress = bool(new) and k == 1 and self.guard_check_every == 1
             if suppress:
                 return
-            event_handler(EndIteration(pass_id, idx_last, cost, extras))
+            emit(EndIteration(pass_id, idx_last, cost, extras))
             if idx_last % log_period < k:  # window crossed a log_period mark
                 flush_log()
                 cost.copy_to_host_async()  # start D2H without blocking
@@ -876,7 +893,16 @@ class SGDTrainer:
                 del pending[:]
             pending_sig = None
 
-        for raw in reader():
+        items = iter(reader())
+        while True:
+            # span-ok: one ring write per item pulled: the time the loop
+            # waited for data (the reader's own work, or the prefetch queue)
+            with trace.flight("train.input_wait") as wait:
+                raw = next(items, _END_OF_PASS)
+                wait.attrs = {"batch": logical}
+            obs_metrics.observe_input_wait(wait.dur_ns * 1e-9)
+            if raw is _END_OF_PASS:
+                break
             k_item = raw.k if isinstance(raw, StackedBatch) else 1
             idx0 = logical
             logical += k_item
@@ -985,14 +1011,13 @@ class SGDTrainer:
                 self.parallel is None or self.parallel.is_sharded_batch(raw)
             )
             if on_device:
-                batch = raw  # hostFeed/h2d were stamped by the prefetcher
+                batch = raw  # fed and put by the prefetcher's worker
             else:
-                with stats.timer("hostFeed"):
-                    batch = (
-                        feeder(raw)
-                        if feeder is not None and not isinstance(raw, dict)
-                        else _coerce_batch(raw)
-                    )
+                batch = (
+                    feeder(raw)
+                    if feeder is not None and not isinstance(raw, dict)
+                    else _coerce_batch(raw)
+                )
             if self.parallel is not None and not on_device:
                 # trailing partial batch not divisible by the mesh data axis
                 # pads to the next shard multiple with a 0/1 row mask (cost
@@ -1007,8 +1032,7 @@ class SGDTrainer:
                     if not pending:
                         boundary = logical
                     continue
-                with stats.timer("h2d"):
-                    batch = self.parallel.shard_batch(batch)
+                batch = self.parallel.shard_batch(batch)
             if self.state is None:
                 self.init_state(batch)
                 if resume_pending:  # deferred auto-resume load
@@ -1053,13 +1077,17 @@ class SGDTrainer:
         n_batches = stepped - n_diverged
         if guard_on and self.state is not None:
             cost_sum_dev = self.state["cost_acc"]  # step-accumulated, masked
-        metrics: Dict[str, Any] = {
-            "avg_cost": (
+        # span-ok: once a pass; the fetch waits for the last dispatch to run,
+        # which is the device's time and not the loop's
+        with trace.flight("train.cost_fetch"):
+            avg_cost = (
                 # sync-ok: the single pass-end fetch of the on-device sum
                 float(cost_sum_dev) / n_batches
                 if n_batches and cost_sum_dev is not None
                 else 0.0
-            ),
+            )
+        metrics: Dict[str, Any] = {
+            "avg_cost": avg_cost,
             "batches": n_batches,
             "pass_seconds": time.time() - t0,
             "shape_signatures": stats.RECOMPILES.pass_signatures(),
@@ -1099,14 +1127,7 @@ class SGDTrainer:
             hbm = stats.device_memory_stats()
             if hbm.get("peak_bytes_in_use"):
                 metrics["peak_hbm_bytes"] = hbm["peak_bytes_in_use"]
-        if stats.GLOBAL_STATS.enabled:
-            log.info("pass %d %s", pass_id, stats.RECOMPILES.report())
-        # span-ok: whole-pass span recorded once at pass end (ring buffer
-        # write from already-measured wall-clock; no per-step work)
-        trace.record_span(
-            "train.pass", int(t0 * 1e6), time.time_ns() // 1000,
-            attrs={"pass": pass_id, "batches": n_batches},
-        )
+        pass_span.attrs["batches"] = n_batches
         self.updater.finish_pass()
         if test_reader is not None:
             metrics["test_cost"] = self.test(test_reader, feeder)["cost"]
@@ -1116,7 +1137,7 @@ class SGDTrainer:
                 async_=async_checkpoint,
             )
             self._known_good_pass = (save_dir, pass_id)
-        event_handler(EndPass(pass_id, metrics))
+        emit(EndPass(pass_id, metrics))
         return resume_pending
 
     def _poll_guard(
@@ -1132,7 +1153,7 @@ class SGDTrainer:
         poisoned update on device, so by the time the host learns about a
         window's divergences the state is clean — the reaction here is
         policy, not protection. Returns the number of new events."""
-        with trace.span("train.guard_poll", batch=batch_id):
+        with trace.flight("train.guard_poll", batch=batch_id):
             d = int(self.state["diverged"])  # sync-ok: the guard-poll site
         new = d - self._diverged_seen
         self._diverged_seen = d
@@ -1526,7 +1547,7 @@ class SGDTrainer:
         assert self.state is not None
         # the checkpoint span covers what the TRAINING THREAD pays: the full
         # write when synchronous, only the D2H fetch + enqueue when async
-        with trace.span("train.checkpoint", pass_id=pass_id, is_async=async_):
+        with trace.flight("train.checkpoint", pass_id=pass_id, is_async=async_):
             # checkpoints always store the CANONICAL per-param layout: a
             # ShardedUpdater gathers its flat [n, chunk] slot/EF shards back
             # to parameter shapes here — and the Zero3Updater its flat
@@ -1570,10 +1591,9 @@ class SGDTrainer:
                 )
             if self._ckpt_writer is None:
                 self._ckpt_writer = ckpt_mod.AsyncCheckpointer()
-            with stats.timer("ckptFetch"):
-                params_np = _fetch_host_tree(params_store)
-                states_np = _fetch_host_tree(self.state["states"])
-                opt_np = _fetch_host_tree(opt_tree)
+            params_np = _fetch_host_tree(params_store)
+            states_np = _fetch_host_tree(self.state["states"])
+            opt_np = _fetch_host_tree(opt_tree)
             return ckpt_mod.save_pass_async(
                 self._ckpt_writer,
                 save_dir,

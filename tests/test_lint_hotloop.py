@@ -13,8 +13,7 @@ naming its justification:
   * the guard poll (_poll_guard, every guard_check_every steps),
   * the single pass-end fetch of the on-device cost sum,
   * the deferred log line (value copied to host asynchronously a dispatch
-    earlier),
-  * the opt-in PADDLE_TPU_TIMER block_until_ready.
+    earlier).
 
   serving (ServingSession._decode_once / step):
   * the sampled-token fetch after the decode dispatch.
@@ -64,7 +63,7 @@ SERVING_SYNC_CALL = re.compile(
 # K+1 sampled tokens (per ROUND per slot — acceptance runs on host), so the
 # verify loop obeys the same budget discipline as the decode loop.
 HOT_LOOPS = [
-    (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), SYNC_CALL, 4),
+    (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), SYNC_CALL, 3),
     (SERVING_PY, "ServingSession",
      ("_decode_once", "step", "_prefill_chunks", "_speculate"),
      SERVING_SYNC_CALL, 3),
@@ -76,16 +75,18 @@ TAG_LOOKBACK = 6  # lines
 
 # -- span-recording sites (ISSUE 7 observability) ----------------------------
 #
-# Spans in the hot loops must go through the obs ring buffer (trace.span /
-# trace.record_span / trace.span_from_monotonic — a no-op truth test when
-# PADDLE_TPU_TRACE is off) and carry a `span-ok` tag naming the site; the
+# Spans in the hot loops must go through the obs ring buffer — trace.span /
+# trace.record_span / trace.span_from_monotonic (a no-op truth test when
+# PADDLE_TPU_TRACE is off), or trace.flight / trace.record_flight (the
+# flight recorder: ALWAYS a ring write, so the count per dispatch is what
+# is reviewed here) — and carry a `span-ok` tag naming the site; the
 # count is pinned so a new per-step span forces a review here. Two hard bans
 # ride along: no file I/O in a hot-loop body at all, and no string formatting
 # inside a span call's arguments (f-strings/%/.format evaluate at the call
-# site even when tracing is disabled — exactly the cost the gate exists to
-# avoid).
+# site whether or not anything is recorded).
 SPAN_CALL = re.compile(
-    r"(?<![\w.])trace\.(?:span|record_span|span_from_monotonic)\("
+    r"(?<![\w.])trace\."
+    r"(?:span|record_span|span_from_monotonic|flight|record_flight)\("
 )
 SPAN_TAG = "span-ok"
 # (file, class, hot methods, max span-ok tags)
@@ -94,8 +95,16 @@ SPAN_TAG = "span-ok"
 # per-ASSIGNMENT / per-FAILOVER / per-HEDGE (never per pump cycle — note
 # _pump_once is in the list precisely to keep it span-free), and the file-IO
 # + span-formatting bans below apply to those bodies too.
+#
+# ISSUE 26 made the train loop's spans a flight recorder (recorded without a
+# switch): train.pass (once a pass), train.input_wait (once per item pulled),
+# train.handler (one site, `emit`, around every event handed to the caller),
+# train.dispatch (once per dispatch) and train.cost_fetch (once a pass,
+# around the pass-end sync) — five sites, each a fixed number of ring
+# writes per dispatch or per pass. The prefetch worker's sites are pinned below
+# (PIPELINE_SPAN_SITES).
 SPAN_HOT_LOOPS = [
-    (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), 2),
+    (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), 5),
     (SERVING_PY, "ServingSession",
      ("_decode_once", "step", "_prefill_chunks", "_speculate",
       "_notify_streams"), 3),
@@ -104,7 +113,7 @@ SPAN_HOT_LOOPS = [
 ]
 HOT_IO_CALL = re.compile(r"(?<![\w.])open\(|\.write\(|json\.dump")
 SPAN_FMT = re.compile(
-    r"trace\.(?:span|record_span|span_from_monotonic)\("
+    r"trace\.(?:span|record_span|span_from_monotonic|flight|record_flight)\("
     r"[^\n]*(?:f\"|f'|\.format\(|% ?\()"
 )
 
@@ -190,6 +199,33 @@ def test_span_sites_in_hot_loops_tagged_and_pinned():
             "records per-dispatch (not per-step work beyond a ring write) "
             "and bump this bound deliberately"
         )
+
+
+PIPELINE_PY = os.path.join(_REPO, "paddle_tpu", "data", "pipeline.py")
+# the prefetch worker's flight-recorder sites: hostFeed, h2d and stack in
+# DevicePrefetcher (per batch / per put / per stack_k group), queue_full in
+# iter_async (per queue item). iter_async is also DoubleBuffer's producer
+# loop (data/provider.py), so a DoubleBuffer records queue_full per item
+# through this same site: tests/test_obs.py pins that it records nothing else
+PIPELINE_SPAN_SITES = 4
+
+
+def test_prefetcher_span_sites_tagged_pinned_and_unformatted():
+    """The prefetch worker's spans are always recorded, so each site is a
+    fixed cost per batch: tagged, count-pinned, with literal arguments and
+    no file I/O anywhere in the module."""
+    with open(PIPELINE_PY) as f:
+        lines = f.read().splitlines()
+    sites = [i for i, text in enumerate(lines)
+             if SPAN_CALL.search(text.split("#", 1)[0])]
+    assert len(sites) == PIPELINE_SPAN_SITES, [lines[i].strip() for i in sites]
+    for i in sites:
+        window = lines[max(0, i - TAG_LOOKBACK):i + 1]
+        assert any(SPAN_TAG in w for w in window), lines[i].strip()
+        assert not SPAN_FMT.search(lines[i]), lines[i].strip()
+    assert sum(SPAN_TAG in text for text in lines) == PIPELINE_SPAN_SITES
+    code = [text.split("#", 1)[0] for text in lines]
+    assert not [c for c in code if HOT_IO_CALL.search(c)]
 
 
 # -- precision-cast sites (ISSUE 9 mixed precision) --------------------------

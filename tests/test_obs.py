@@ -385,6 +385,29 @@ def test_profiler_start_stop_idempotent(tmp_path):
     stats.profiler_stop()  # double stop: no-op
 
 
+def test_profiler_start_passes_the_options_that_do_not_starve_the_host(
+    tmp_path, monkeypatch
+):
+    """--profile pass:N goes through stats.profiler_start: Python tracer
+    off, host tracer at user annotations only, no HLO copies (with jax's
+    defaults the trace itself starved the chip: PERF.md, PR 25)."""
+    import jax
+
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda logdir, **kw: seen.update(logdir=logdir, **kw),
+    )
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    stats.profiler_start(str(tmp_path / "p"))
+    stats.profiler_stop()
+    options = seen["profiler_options"]
+    assert seen["logdir"] == str(tmp_path / "p")
+    assert options.python_tracer_level == 0
+    assert options.host_tracer_level == 1
+    assert options.enable_hlo_proto is False
+
+
 def _toy_trainer_and_batch():
     from paddle_tpu.nn import costs as C
     from paddle_tpu.nn import layers as L
@@ -448,23 +471,7 @@ def test_parse_profile_spec_rejects_bad_forms():
             parse_profile_spec(bad)
 
 
-def test_statset_report_percent_and_deterministic_ties():
-    """Satellite: report() shows percent-of-total and breaks total ties by
-    name so timer splits diff cleanly across runs."""
-    ss = stats.StatSet()
-    ss.get("zeta").add(0.010)
-    ss.get("alpha").add(0.010)
-    ss.get("big").add(0.080)
-    rep = ss.report()
-    lines = rep.splitlines()[1:]
-    names = [ln.strip().split(":")[0] for ln in lines]
-    assert names == ["big", "alpha", "zeta"]  # total desc, then name
-    assert "80.0%" in lines[0]
-    assert "10.0%" in lines[1]
-    assert ss.report() == rep  # stable across calls
-
-
-# -- trainer spans ------------------------------------------------------------
+# -- trainer spans: the flight recorder -----------------------------------------
 
 
 def test_trainer_emits_pass_dispatch_checkpoint_spans(tmp_path):
@@ -475,5 +482,235 @@ def test_trainer_emits_pass_dispatch_checkpoint_spans(tmp_path):
     )
     names = [r[0] for r in trace.TRACER.snapshot()]
     assert names.count("train.dispatch") == 3
+    assert names.count("train.input_wait") == 4  # three items and the end
     assert "train.pass" in names
     assert "train.checkpoint" in names
+
+
+NAME, START, DUR, TRACE, SPAN, PARENT, ATTRS, THREAD = range(8)
+
+
+def _train_over_prefetcher(stack_k, n=8, k=2, sleep_s=0.0):
+    """One train() of n batches, k steps a dispatch, fed by a
+    DevicePrefetcher; returns the ring's rows and the pass's row."""
+    from paddle_tpu.data.pipeline import DevicePrefetcher
+
+    trainer, batch = _toy_trainer_and_batch()
+
+    def reader():
+        for _ in range(n):
+            if sleep_s:
+                time.sleep(sleep_s)
+            yield batch
+
+    seen = []
+    trainer.train(
+        DevicePrefetcher(reader, stack_k=stack_k), num_passes=1,
+        steps_per_dispatch=k, log_period=10 ** 9,
+        event_handler=lambda ev: seen.append(type(ev).__name__),
+    )
+    rows = trace.TRACER.snapshot()
+    passes = [r for r in rows if r[NAME] == "train.pass"]
+    assert len(passes) == 1
+    return rows, passes[0], seen
+
+
+@pytest.mark.parametrize("tracing", [False, True], ids=["trace_off", "trace_on"])
+@pytest.mark.parametrize("stack_k", [1, 2])
+def test_train_pass_is_a_span_tree_over_the_prefetcher(stack_k, tracing):
+    """Recorded whether or not PADDLE_TPU_TRACE is set: every train.* and
+    pipeline.* span carries the pass's trace id and a parent; the train
+    thread's children of the pass do not overlap and fit inside it."""
+    trace.enable_tracing(tracing)
+    rows, tp, seen = _train_over_prefetcher(stack_k)
+    ours = [r for r in rows if r[NAME].startswith(("train.", "pipeline."))]
+    assert {r[TRACE] for r in ours} == {tp[TRACE]}
+    assert all(r[PARENT] for r in ours if r is not tp) and tp[PARENT] is None
+    assert (tp[ATTRS]["pass_id"], tp[ATTRS]["batches"]) == (0, 8)
+    by_name = {}
+    for r in ours:
+        by_name.setdefault(r[NAME], []).append(r)
+    assert len(by_name["train.dispatch"]) == 4
+    assert {r[ATTRS]["k"] for r in by_name["train.dispatch"]} == {2}
+    # one pull per item and one that finds the end
+    assert len(by_name["train.input_wait"]) == (8 if stack_k == 1 else 4) + 1
+    # the caller's handler: BeginPass, EndPass, Begin- and EndIteration
+    assert len(by_name["train.handler"]) == len(seen) == 2 + 2 * 4
+    assert len(by_name["pipeline.hostFeed"]) == 8
+    assert len(by_name["pipeline.h2d"]) == (8 if stack_k == 1 else 4)
+    assert len(by_name.get("pipeline.stack", ())) == (0 if stack_k == 1 else 4)
+    # the worker's spans sit on another thread, under the pass
+    worker = {r[THREAD] for r in ours if r[NAME].startswith("pipeline.")}
+    assert len(worker) == 1 and tp[THREAD] not in worker
+    assert all(r[PARENT] == tp[SPAN] for r in by_name["pipeline.hostFeed"])
+    children = sorted(
+        (r for r in ours if r[PARENT] == tp[SPAN] and r[THREAD] == tp[THREAD]),
+        key=lambda r: r[START],
+    )
+    assert {r[NAME] for r in children} == {
+        "train.input_wait", "train.handler", "train.dispatch", "train.cost_fetch"}
+    for before, after in zip(children, children[1:]):
+        assert before[START] + before[DUR] <= after[START], (before, after)
+    assert children[0][START] >= tp[START]
+    assert children[-1][START] + children[-1][DUR] <= tp[START] + tp[DUR]
+    assert sum(r[DUR] for r in children) <= tp[DUR]
+
+
+def test_one_batch_reads_feed_put_queue_wait_dispatch():
+    """The worker's spans and the train thread's carry the same batch
+    index, so one batch can be followed across the two threads."""
+    rows, tp, _ = _train_over_prefetcher(stack_k=1, n=4, k=2)
+
+    def batch_indices(name, key="batch"):
+        return sorted(r[ATTRS][key] for r in rows if r[NAME] == name)
+
+    assert batch_indices("pipeline.hostFeed") == [0, 1, 2, 3]
+    assert batch_indices("pipeline.h2d") == [0, 1, 2, 3]
+    assert batch_indices("pipeline.queue_full", "item") == [0, 1, 2, 3, 4]  # and the stop mark
+    assert batch_indices("train.input_wait") == [0, 1, 2, 3, 4]
+    assert batch_indices("train.dispatch", "first") == [0, 2]
+    # the feed of a batch ends before the wait that received it ends
+    feeds = {r[ATTRS]["batch"]: r for r in rows if r[NAME] == "pipeline.hostFeed"}
+    waits = {r[ATTRS]["batch"]: r for r in rows if r[NAME] == "train.input_wait"}
+    for i, feed in feeds.items():
+        assert feed[START] + feed[DUR] <= waits[i][START] + waits[i][DUR]
+
+
+def test_a_double_buffer_records_one_queue_full_span_an_item_and_nothing_else():
+    """DoubleBuffer shares the prefetcher's producer loop (iter_async), so
+    its worker writes `pipeline.queue_full` per queue item (and the stop
+    mark), inside the trace of whoever iterates it."""
+    from paddle_tpu.data.provider import DoubleBuffer
+
+    with trace.flight("train.pass") as tp:
+        assert list(DoubleBuffer(lambda: iter([1, 2, 3]), capacity=2)) == [1, 2, 3]
+    rows = [r for r in trace.TRACER.snapshot() if r[NAME] != "train.pass"]
+    assert [r[NAME] for r in rows] == ["pipeline.queue_full"] * 4
+    assert sorted(r[ATTRS]["item"] for r in rows) == [0, 1, 2, 3]
+    assert {(r[TRACE], r[PARENT]) for r in rows} == {(tp.trace_id, tp.span_id)}
+
+
+def test_counters_equal_the_sums_of_the_spans_they_sit_beside():
+    def read():
+        return obs_metrics.snapshot()
+
+    before = read()
+    rows, tp, _ = _train_over_prefetcher(stack_k=1, sleep_s=0.002)
+    after = read()
+
+    def delta(key):
+        return after.get(key, 0.0) - before.get(key, 0.0)
+
+    def total_s(name):
+        return sum(r[DUR] for r in rows if r[NAME] == name) * 1e-9
+
+    def count(name):
+        return sum(1 for r in rows if r[NAME] == name)
+
+    assert delta("paddle_tpu_train_input_wait_seconds_total") == pytest.approx(
+        total_s("train.input_wait"), rel=1e-9)
+    assert total_s("train.input_wait") > 0.002  # the feed sleeps: the loop waited
+    assert delta("paddle_tpu_train_dispatches_total") == count("train.dispatch") == 4
+    assert delta("paddle_tpu_pipeline_batches_total") == count("pipeline.hostFeed") == 8
+    for phase in ("trace", "lower", "backend"):
+        key = "paddle_tpu_compile_seconds_total{phase=%s}" % phase
+        assert delta(key) == pytest.approx(total_s("compile." + phase), rel=1e-9, abs=1e-12)
+    text = obs_metrics.to_prometheus_text()
+    assert "paddle_tpu_train_input_wait_seconds_total" in text
+    assert "paddle_tpu_timer_ms_total" not in text  # the timers are gone
+
+
+def test_a_new_batch_shape_inside_a_pass_leaves_a_compile_span_naming_the_step():
+    """Which step recompiled, when, for how long: a compile.backend span
+    with the jitted step's name, inside the dispatch that paid for it."""
+    trainer, batch = _toy_trainer_and_batch()
+    small = {k: v[:5] for k, v in batch.items()}
+    trainer.train(lambda: iter([batch, batch]), num_passes=1, log_period=10 ** 9)
+    trace.reset()
+    trainer.train(lambda: iter([batch, small, batch]), num_passes=1, log_period=10 ** 9)
+    rows = trace.TRACER.snapshot()
+    tp = next(r for r in rows if r[NAME] == "train.pass")
+    dispatches = {r[SPAN]: r for r in rows if r[NAME] == "train.dispatch"}
+    step_name = trainer._step_fn.__name__
+    backend = [r for r in rows if r[NAME] == "compile.backend"
+               and r[PARENT] in dispatches]
+    assert len(backend) == 1, [(r[NAME], r[ATTRS]) for r in rows if r[NAME].startswith("compile.")]
+    assert step_name in backend[0][ATTRS]["fun_name"]
+    assert dispatches[backend[0][PARENT]][ATTRS]["first"] == 1  # the small batch
+    assert backend[0][TRACE] == tp[TRACE]
+    phases = {r[NAME] for r in rows if r[PARENT] == backend[0][PARENT]}
+    assert phases >= {"compile.trace", "compile.lower", "compile.backend"}
+
+
+def test_what_compiles_inside_anothers_trace_leaves_no_span_of_its_own():
+    """Every jnp ufunc is a jit: traced inside a model they would be
+    thousands of microsecond spans and seconds counted twice; an op run
+    eagerly on a concrete value there is lowered and compiled inside the
+    outer trace too. The outer function's trace span covers them all, so a
+    thread's compile spans never overlap; on their own they have theirs."""
+    import jax
+    import jax.numpy as jnp
+
+    def outer_fn(x):
+        with jax.ensure_compile_time_eval():  # compiled and run during the trace
+            table = jnp.sin(np.ones((3, 11), np.float32))
+        return jnp.multiply(jnp.add(x, table[0, 0]), jax.jit(lambda y: y * 3.0)(x))
+
+    jax.jit(outer_fn)(np.ones((3, 5), np.float32)).block_until_ready()
+    rows = [r for r in trace.TRACER.snapshot() if r[NAME].startswith("compile.")]
+    assert [r[NAME] for r in rows] == ["compile.trace", "compile.lower", "compile.backend"]
+    assert all("outer_fn" in r[ATTRS]["fun_name"] for r in rows)  # "jit(outer_fn)" once lowered
+    for a, b in zip(rows, rows[1:]):
+        assert a[START] + a[DUR] <= b[START]
+    trace.reset()
+    jnp.sin(np.ones((3, 7), np.float32)).block_until_ready()
+    names = [(r[NAME], r[ATTRS]["fun_name"]) for r in trace.TRACER.snapshot()]
+    assert {("compile.trace", "sin"), ("compile.backend", "jit(sin)")} <= set(names)
+
+
+def test_tracing_off_records_no_gated_span_and_sends_no_wire_context():
+    """PADDLE_TPU_TRACE unset: the flight recorder runs, and still no RPC,
+    serving or router span is recorded and no `_trace` key rides a frame,
+    not even from inside an open train span."""
+    trace.enable_tracing(False)
+    with trace.flight("train.pass"):
+        assert trace.current_context() is not None
+        assert trace.wire_context() is None
+        with trace.span("rpc.get_task"):
+            trace.record_span("serving.ttft", 0, 1)
+            trace.span_from_monotonic("serving.queue_wait", time.monotonic())
+        with trace.server_span("rpc.submit", {"t": "ab" * 8, "s": "1.1"}):
+            pass
+        with trace.activate({"t": "ab" * 8, "s": "1.1"}):  # a wire context
+            assert trace.current_context()[0] != "ab" * 8
+    assert [r[NAME] for r in trace.TRACER.snapshot()] == ["train.pass"]
+
+
+def test_a_thread_adopts_a_pass_context_whether_or_not_tracing_is_on():
+    trace.enable_tracing(False)
+    got = {}
+
+    def worker(ctx):
+        with trace.activate(ctx):
+            with trace.flight("pipeline.hostFeed") as sp:
+                pass
+        got["span"] = sp
+
+    with trace.flight("train.pass") as tp:
+        t = threading.Thread(target=worker, args=(trace.current_context(),))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    assert (got["span"].trace_id, got["span"].parent_id) == (tp.trace_id, tp.span_id)
+
+
+def test_the_ring_keeps_nanoseconds_and_the_chrome_export_microseconds():
+    t0 = time.time_ns()
+    with trace.flight("train.dispatch", k=1) as sp:
+        time.sleep(0.001)
+    t1 = time.time_ns()
+    row = trace.TRACER.snapshot()[-1]
+    assert t0 <= row[START] <= t1 and row[DUR] == sp.dur_ns
+    assert 1_000_000 <= row[DUR] <= t1 - t0
+    ev = trace.export_chrome()["traceEvents"][-1]
+    assert ev["ts"] == row[START] / 1000 and ev["dur"] == row[DUR] / 1000

@@ -202,24 +202,21 @@ def test_trainer_trains_through_prefetcher():
     assert np.isfinite(res["cost"]) and res["samples"] == 8 * 16
 
 
-def test_trainer_timer_split(monkeypatch):
-    """PADDLE_TPU_TIMER surfaces the hostFeed / h2d / forwardBackward split."""
-    from paddle_tpu.core.stats import GLOBAL_STATS, enable_timers
+def test_trainer_without_a_prefetcher_feeds_inside_its_input_wait():
+    """No prefetcher: the reader's own work IS the wait, on the train
+    thread, and no pipeline.* span is recorded."""
+    from paddle_tpu.obs import trace
 
-    GLOBAL_STATS.reset()
-    enable_timers(True)
-    try:
-        trainer = _tiny_trainer()
-        trainer.train(
-            lambda: iter(_raw_batches(n=3, bs=16)), num_passes=1,
-            feeder=_feeder(),
-        )
-        report = GLOBAL_STATS.as_dict()
-        assert report["hostFeed"]["count"] == 3
-        assert report["forwardBackward"]["count"] == 3
-    finally:
-        enable_timers(False)
-        GLOBAL_STATS.reset()
+    trace.reset()
+    trainer = _tiny_trainer()
+    trainer.train(
+        lambda: iter(_raw_batches(n=3, bs=16)), num_passes=1,
+        feeder=_feeder(),
+    )
+    names = [r[0] for r in trace.TRACER.snapshot()]
+    assert names.count("train.input_wait") == 4
+    assert names.count("train.dispatch") == 3
+    assert not [n for n in names if n.startswith("pipeline.")]
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Cross-cutting utils: Stat timers (utils/Stat.h parity), layer-name crash
-context (CustomStackTrace parity), flags."""
+"""Cross-cutting utils: layer-name crash context (CustomStackTrace parity),
+flags."""
 
 import os
 
@@ -8,34 +8,7 @@ import pytest
 
 import jax
 
-from paddle_tpu.core import stats
 from paddle_tpu.core.stack_trace import LayerError
-
-
-def test_stat_set_accumulates_and_reports():
-    stats.GLOBAL_STATS.reset()
-    stats.enable_timers(True)
-    try:
-        for _ in range(3):
-            with stats.timer("unit_test_timer"):
-                pass
-        s = stats.GLOBAL_STATS.get("unit_test_timer")
-        assert s.count == 3 and s.total >= 0
-        rep = stats.GLOBAL_STATS.report()
-        assert "unit_test_timer" in rep and "count=3" in rep
-        d = stats.GLOBAL_STATS.as_dict()
-        assert d["unit_test_timer"]["count"] == 3
-    finally:
-        stats.enable_timers(False)
-        stats.GLOBAL_STATS.reset()
-
-
-def test_timers_disabled_record_nothing():
-    stats.GLOBAL_STATS.reset()
-    stats.enable_timers(False)
-    with stats.timer("should_not_exist"):
-        pass
-    assert "should_not_exist" not in stats.GLOBAL_STATS.as_dict()
 
 
 def test_layer_error_names_failing_layer():
@@ -54,38 +27,6 @@ def test_layer_error_names_failing_layer():
         )
     assert "mismatched_add" in str(ei.value)
     assert ei.value.layer_name == "mismatched_add"
-
-
-def test_trainer_hot_loop_stamps_timer():
-    from paddle_tpu.nn import layers as L
-    from paddle_tpu.nn import costs as C
-    from paddle_tpu.nn.graph import Network, reset_name_scope
-    from paddle_tpu.optim import SGD
-    from paddle_tpu.trainer.trainer import SGDTrainer
-
-    stats.GLOBAL_STATS.reset()
-    stats.enable_timers(True)
-    try:
-        reset_name_scope()
-        x = L.Data("x", shape=(4,))
-        y = L.Data("y", shape=())
-        cost = C.ClassificationCost(L.Fc(x, 3, act=None), y)
-        trainer = SGDTrainer(cost, SGD(learning_rate=0.1))
-        rs = np.random.RandomState(0)
-
-        def reader():
-            yield [
-                (rs.randn(4).astype(np.float32), rs.randint(3)) for _ in range(8)
-            ]
-
-        from paddle_tpu.data.feeder import DataFeeder, dense_vector, integer_value
-
-        feeder = DataFeeder({"x": dense_vector(4), "y": integer_value(3)})
-        trainer.train(reader, num_passes=1, feeder=feeder)
-        assert stats.GLOBAL_STATS.get("forwardBackward").count >= 1
-    finally:
-        stats.enable_timers(False)
-        stats.GLOBAL_STATS.reset()
 
 
 def test_chunk_evaluator_config_plumbing():
