@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.core.registry import LAYERS
 from paddle_tpu.nn.graph import Argument, Context, Layer
+from paddle_tpu.obs import metrics as obs_metrics
 from paddle_tpu.ops import sequence as seq_ops
 from paddle_tpu.ops import xent as xent_ops
 
@@ -63,7 +64,11 @@ class CostLayer(Layer):
     def per_example(self, ctx, pred: Array, label: Array) -> Array:
         raise NotImplementedError
 
-    def forward(self, ctx: Context, ins: List[Argument]) -> Argument:
+    def forward(self, ctx: Context, ins: List[Argument], projection=None) -> Argument:
+        """`projection` is set by a Network that gave this cost its input
+        layer's work (graph._plan_fused_projections): ins[0] is then
+        that layer's INPUT, and per_example_projected applies its
+        parameters. Masks, weights and the mean are the same either way."""
         pred_arg, label_arg = ins[0], ins[1]
         if pred_arg.lengths is not None and label_arg.lengths is None:
             # sequence predictions against one label per sequence: the label
@@ -77,7 +82,10 @@ class CostLayer(Layer):
             )
         pred, pmask = _flatten_seq(pred_arg.value, pred_arg.lengths)
         label, _ = _flatten_seq(label_arg.value, label_arg.lengths)
-        cost = self.per_example(ctx, pred, label)
+        if projection is None:
+            cost = self.per_example(ctx, pred, label)
+        else:
+            cost = self.per_example_projected(ctx, projection, pred, label)
         if cost.ndim > 1:
             cost = cost.reshape(cost.shape[0], -1).sum(-1)
         if pmask is not None:
@@ -94,6 +102,14 @@ class CostLayer(Layer):
         return Argument(total)
 
 
+def _count_xent_path(ctx, value, path: str) -> None:
+    """One count per cost each time a forward is TRACED (jit, grad,
+    eval_shape), so a compiled step counts once however often it runs. Not
+    in init, which runs every layer as written, and not on an eager call."""
+    if ctx.mode == "apply" and isinstance(value, jax.core.Tracer):
+        obs_metrics.observe_fused_projection_xent(path)
+
+
 @LAYERS.register("classification_cost", "multi_class_cross_entropy")
 class ClassificationCost(CostLayer):
     """Softmax + multi-class cross-entropy (CostLayer.cpp
@@ -108,15 +124,30 @@ class ClassificationCost(CostLayer):
         super().__init__(input, label, weight, name, coeff)
         self.from_logits = from_logits
 
+    @property
+    def fuses_projection(self) -> bool:
+        """Whether this cost can take a linear projection's place in front
+        of it (ops/xent.linear_softmax_xent): only from logits."""
+        return self.from_logits
+
     def per_example(self, ctx, pred, label):
         label = label.astype(jnp.int32).reshape(-1)
         if self.from_logits:
-            # fused big-vocab path: all [N, V] tensors stay in pred's dtype,
-            # reductions in f32 (ops/xent.py — r3 profile showed the f32
-            # log_softmax dominating the NMT step's bandwidth)
+            # every [N, V] tensor stays in pred's dtype, reductions in f32
+            # (ops/xent.py); pred is float32 when a biased Fc made it
+            _count_xent_path(ctx, pred, "unfused")
             return xent_ops.softmax_xent_with_logits(pred, label)
         logp = jnp.log(jnp.maximum(pred.astype(jnp.float32), 1e-10))
         return -jnp.take_along_axis(logp, label[:, None], axis=-1)[:, 0]
+
+    def per_example_projected(self, ctx, projection, rows, label):
+        """rows [N, ...] are the projection layer's input: the logits exist
+        only inside the op."""
+        label = label.astype(jnp.int32).reshape(-1)
+        x = rows.reshape(rows.shape[0], -1)
+        w, b = projection.projection_params(ctx, x.shape[-1])
+        _count_xent_path(ctx, x, "fused")
+        return xent_ops.linear_softmax_xent(x, w, b, label, ctx.policy)
 
 
 @LAYERS.register("soft_binary_class_cross_entropy")

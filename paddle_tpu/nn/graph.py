@@ -386,6 +386,9 @@ class Network:
                 raise ValueError(f"duplicate layer name {l.name!r}")
             self.layers_by_name[l.name] = l
         self.param_attrs: Dict[str, ParamAttr] = {}
+        self.fused_projections: Dict[str, Layer] = _plan_fused_projections(
+            self.layer_order, self.outputs
+        )
 
     # -- data layer discovery ----------------------------------------------
     @property
@@ -458,16 +461,30 @@ class Network:
             ctx.sample_mask = jnp.asarray(batch[SAMPLE_MASK_KEY])
             batch = {k: v for k, v in batch.items() if k != SAMPLE_MASK_KEY}
         values: Dict[str, Argument] = {}
+        # init runs every layer as written: each makes its own parameters,
+        # in its own place in the rng stream, and every layer has a value
+        # (config/dump.py shapes each layer from it)
+        fused = {} if ctx.mode == "init" else self.fused_projections
+        given_away = {id(p) for p in fused.values()}
         for layer in self.layer_order:
             if layer.type_name == "data":
                 values[layer.name] = _feed_to_argument(batch, layer)
                 continue
-            ins = [values[l.name] for l in layer.inputs]
+            if id(layer) in given_away:
+                continue  # its cost does its work, below
+            projection = fused.get(layer.name)
+            sources = list(layer.inputs)
+            if projection is not None:
+                sources[0] = projection.inputs[0]
+            ins = [values[l.name] for l in sources]
             # layer-name crash context (CustomStackTrace parity,
             # NeuralNetwork.cpp:259-261)
             with stack_trace.layer_frame(layer.name):
                 try:
-                    out = layer.forward(ctx, ins)
+                    if projection is not None:
+                        out = layer.forward(ctx, ins, projection=projection)
+                    else:
+                        out = layer.forward(ctx, ins)
                 except stack_trace.LayerError:
                     raise
                 except Exception as e:
@@ -480,6 +497,39 @@ class Network:
                 )
             values[layer.name] = out
         return values
+
+
+def _plan_fused_projections(
+    order: Sequence[Layer], outputs: Sequence[Layer]
+) -> Dict[str, Layer]:
+    """{cost layer's name: the linear projection it takes over}, decided
+    once from the layer types and the graph: a cost that can fuse its
+    projection (`fuses_projection`, costs.ClassificationCost from logits)
+    does so when its input is a linear projection (`is_linear_projection`,
+    layers.Fc with one input and no activation) whose value nothing else
+    wants: no other consumer, not an output of this Network. The cost then
+    runs on the projection's INPUT and the logits exist only inside
+    ops/xent.linear_softmax_xent; the projection layer itself is skipped
+    (in apply: init runs both as written, Network._run).
+    Anything else (an evaluator or an extra output on the logits, a second
+    cost on them) leaves both layers as they are."""
+    consumers: Dict[int, int] = {}
+    for layer in order:
+        for src in layer.inputs:
+            consumers[id(src)] = consumers.get(id(src), 0) + 1
+    wanted = {id(o) for o in outputs}
+    plan: Dict[str, Layer] = {}
+    for layer in order:
+        if not getattr(layer, "fuses_projection", False):
+            continue
+        src = layer.inputs[0]
+        if (
+            getattr(src, "is_linear_projection", False)
+            and consumers[id(src)] == 1
+            and id(src) not in wanted
+        ):
+            plan[layer.name] = src
+    return plan
 
 
 def _topo_sort(outputs: Sequence[Layer]) -> List[Layer]:

@@ -75,6 +75,32 @@ class Fc(Layer):
         self.param_attr = _attr(param_attr)
         self.bias_attr = _attr(bias_attr)
 
+    def _weight(self, ctx: Context, i: int, d: int) -> Array:
+        suffix = "" if len(self.inputs) == 1 else f".{i}"
+        pa = self.param_attr
+        if isinstance(pa, list):
+            pa = pa[i] if i < len(pa) else None
+        return ctx.param(
+            self, "w" + suffix, (d, self.size), init_mod.smart_normal, pa
+        )
+
+    def _bias(self, ctx: Context) -> Optional[Array]:
+        if not self.bias:
+            return None
+        return ctx.param(self, "b", (self.size,), init_mod.zeros, self.bias_attr)
+
+    @property
+    def is_linear_projection(self) -> bool:
+        """One input, no activation: x @ w + b and nothing else, which a
+        cost that fuses its projection (ops/xent.linear_softmax_xent) can
+        take over. Whether it does is the Network's decision."""
+        return len(self.inputs) == 1 and act_mod.get(self.act) is act_mod.linear
+
+    def projection_params(self, ctx: Context, d: int):
+        """(w, b) of a linear projection from width d, under the parameters'
+        own names, for the cost that does this layer's work."""
+        return self._weight(ctx, 0, d), self._bias(ctx)
+
     def forward(self, ctx: Context, ins: List[Argument]) -> Argument:
         total = None
         any_seq = any(a.is_seq for a in ins)
@@ -84,22 +110,15 @@ class Fc(Layer):
                 # image/feature-map input: v1 fc operates on the flattened
                 # vector (FullyConnectedLayer consumes the flat Argument)
                 x = x.reshape(x.shape[0], -1)
-            d = x.shape[-1]
-            suffix = "" if len(ins) == 1 else f".{i}"
-            pa = self.param_attr
-            if isinstance(pa, list):
-                pa = pa[i] if i < len(pa) else None
-            w = ctx.param(
-                self, "w" + suffix, (d, self.size), init_mod.smart_normal, pa
-            )
+            w = self._weight(ctx, i, x.shape[-1])
             y = linalg.matmul(x, w, ctx.policy)
             if any_seq and y.ndim == 2:
                 # flat input mixed with sequence inputs: broadcast over time
                 # (the reference adds the non-seq row to every token)
                 y = y[:, None]
             total = y if total is None else total + y
-        if self.bias:
-            b = ctx.param(self, "b", (self.size,), init_mod.zeros, self.bias_attr)
+        b = self._bias(ctx)
+        if b is not None:
             total = total + b
         total = act_mod.apply(self.act, total)
         return ins[0].with_value(total)

@@ -291,6 +291,18 @@ def observe_compile(phase: str, seconds: float) -> None:
     ).inc(seconds, phase=phase)
 
 
+def observe_fused_projection_xent(path: str) -> None:
+    """A classification cost from logits was TRACED in a forward other than
+    init's (once per cost per trace, never per step, never on an eager
+    call; nn/costs._count_xent_path): path is 'fused' where the Network gave
+    it its linear projection (ops/xent.linear_softmax_xent), 'unfused' where
+    the projection's value is wanted elsewhere and the logits exist."""
+    REGISTRY.counter(
+        "paddle_tpu_fused_projection_xent_total",
+        "classification costs traced, by whether they fused their projection",
+    ).inc(path=path)
+
+
 # -- serving resilience (ISSUE 10) -------------------------------------------
 #
 # One naming authority for the serving failure-path counters, so the

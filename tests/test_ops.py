@@ -185,3 +185,118 @@ def test_softmax_xent_matches_log_softmax_oracle():
     np.testing.assert_allclose(
         np.asarray(g16, np.float32), np.asarray(g_want), rtol=5e-2, atol=5e-2
     )
+
+
+# -- ops/xent.linear_softmax_xent: the projection and its cross-entropy --------
+
+
+def _proj_case(n=300, d=24, v=97, seed=11):
+    rng = np.random.RandomState(seed)
+    return dict(
+        x=jnp.asarray(rng.randn(n, d).astype(np.float32)),
+        w=jnp.asarray(rng.randn(d, v).astype(np.float32) * 0.3),
+        b=jnp.asarray(rng.randn(v).astype(np.float32)),
+        y=jnp.asarray(rng.randint(0, v, n).astype(np.int32)),
+        g=jnp.asarray(rng.rand(n).astype(np.float32)),
+    )
+
+
+# (rows, vocabulary, bias)
+_PROJ_CASES = {
+    "tall": (300, 97, True),
+    "whole_tiles": (384, 128, True),
+    "wide_vocab": (37, 1000, True),
+    "no_bias": (300, 97, False),
+    "small_no_bias": (64, 97, False),
+}
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_PROJ_CASES))
+def test_linear_softmax_xent_matches_the_unfused_pair(case, precision):
+    """linear_softmax_xent(x, w, b, y) against softmax_xent_with_logits(
+    linalg.linear(x, w, b), y): values and the gradients of x, w and b, to
+    1e-6 of each array's size under the f32 policy and at the oracle test's
+    bf16 tolerance under bf16; rows and vocabularies that fill whole tiles
+    and that do not, with and without a bias."""
+    import jax
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.ops import linalg
+    from paddle_tpu.ops import xent as xent_ops
+
+    n, v, bias = _PROJ_CASES[case]
+    c = _proj_case(n=n, v=v)
+    policy = dtypes.get(precision)
+    b = c["b"] if bias else None
+    argnums = (0, 1, 2) if bias else (0, 1)
+
+    def fused(x, w, b_):
+        return (xent_ops.linear_softmax_xent(x, w, b_, c["y"], policy) * c["g"]).sum()
+
+    def pair(x, w, b_):
+        logits = linalg.linear(x, w, b_, policy)
+        return (xent_ops.softmax_xent_with_logits(logits, c["y"]) * c["g"]).sum()
+
+    per_row = xent_ops.linear_softmax_xent(c["x"], c["w"], b, c["y"], policy)
+    want_rows = xent_ops.softmax_xent_with_logits(
+        linalg.linear(c["x"], c["w"], b, policy), c["y"]
+    )
+    assert per_row.dtype == jnp.float32 and per_row.shape == (n,)
+    got = jax.value_and_grad(fused, argnums)(c["x"], c["w"], b)
+    want = jax.value_and_grad(pair, argnums)(c["x"], c["w"], b)
+    tol = 1e-6 if precision == "f32" else 5e-2
+    np.testing.assert_allclose(np.asarray(per_row), np.asarray(want_rows), rtol=tol, atol=tol)
+    for name, a, e in zip("xwb", got[1], want[1]):
+        assert a.dtype == e.dtype == jnp.float32, name  # masters' cotangents
+        scale = max(1.0, float(jnp.max(jnp.abs(e))))
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(e), rtol=tol, atol=tol * scale, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_projected_cost_of_a_masked_weighted_sequence(precision):
+    """A [B, T] sequence with a length mask and a per-token weight through
+    CostLayer.forward: the cost that took its Fc's work against the same
+    layers with the logits kept as an output, which leaves the Fc in place."""
+    import jax
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.nn import costs as C
+    from paddle_tpu.nn import layers as L
+    from paddle_tpu.nn.graph import Network, reset_name_scope
+
+    reset_name_scope()
+    bsz, t, d, v = 20, 15, 12, 33
+    policy = dtypes.get(precision)
+    h = L.Data("h", shape=(d,), is_seq=True)
+    lbl = L.Data("lbl", shape=(), is_seq=True)
+    wt = L.Data("wt", shape=(), is_seq=True)
+    logits = L.Fc(h, v, act=None, name="proj")
+    cost = C.ClassificationCost(logits, lbl, weight=wt, name="cost", coeff=0.5)
+    fused, plain = Network([cost]), Network([cost, logits])
+    assert list(fused.fused_projections) == ["cost"] and not plain.fused_projections
+    rng = np.random.RandomState(2)
+    lens = rng.randint(1, t + 1, bsz).astype(np.int32)
+    batch = {
+        "h": rng.randn(bsz, t, d).astype(np.float32), "h.lengths": lens,
+        "lbl": rng.randint(0, v, (bsz, t)).astype(np.int32), "lbl.lengths": lens,
+        "wt": rng.rand(bsz, t).astype(np.float32), "wt.lengths": lens,
+    }
+    params, states = fused.init(jax.random.PRNGKey(0), batch, policy=policy)
+    params["proj.b"] = jnp.asarray(rng.randn(v).astype(np.float32))
+
+    def loss(net):
+        def f(p, hv):
+            outs, _ = net.apply(p, states, dict(batch, h=hv), train=True, policy=policy)
+            return outs["cost"].value
+        return jax.value_and_grad(f, (0, 1))(params, jnp.asarray(batch["h"]))
+
+    (lf, (gf, gxf)), (lp, (gp, gxp)) = loss(fused), loss(plain)
+    tol = 1e-6 if precision == "f32" else 5e-2
+    np.testing.assert_allclose(float(lf), float(lp), rtol=tol, atol=tol)
+    for k in gp:
+        np.testing.assert_allclose(np.asarray(gf[k]), np.asarray(gp[k]), rtol=tol, atol=tol, err_msg=k)
+    np.testing.assert_allclose(np.asarray(gxf), np.asarray(gxp), rtol=tol, atol=tol)
+    # rows past a sequence's length carry no gradient either way
+    dead = np.arange(t)[None, :] >= lens[:, None]
+    assert not np.asarray(gxf)[dead].any()

@@ -170,6 +170,91 @@ def test_bf16_step_contains_bf16_dots():
     assert not _bf16_dots(_step_hlo(_trainer(precision="f32")))
 
 
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry (scan
+    and while bodies, cond branches, pjit and custom-vjp calls)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+# what may read a float32 value as large as the logits: work that the
+# compiler fuses into the pass that reads the bf16 product, and no more
+_FUSED_INTO_THE_READ = {
+    "add", "sub", "mul", "div", "neg", "exp", "log", "max", "min", "eq", "ne",
+    "select_n", "convert_element_type", "stop_gradient", "reduce_max",
+    "reduce_sum", "argmax", "jit", "pjit", "custom_jvp_call", "custom_vjp_call",
+}
+
+
+def test_seq2seq_step_at_the_cell_sizes_has_no_float32_logits():
+    """Shapes only, nothing runs: the loss and gradient of models.seq2seq
+    (30000, 30000, 512, 512) at 512 x 50 under the bf16 policy, as
+    seq2seq_nmt.train runs it. The fault this pins (ledger, PR 26: a third
+    of that cell's step): Fc's bf16 product plus its float32 bias is
+    float32; that float32 [512, 50, 30000] was the cross-entropy's residual,
+    was reshaped to [25600, 30000] AFTER the projection and re-laid-out for
+    it, and its gradient came back the same way. With the projection inside
+    the cost's op: nothing of the logits' size is kept for the backward in
+    any dtype; a float32 value that large is only ever read by elementwise
+    work and reductions, which fuse into the pass over the bf16 product (so
+    none is reshaped, transposed, sliced, multiplied by a matrix or carried
+    by a loop); no 30000-wide operand that large is reshaped or transposed
+    in any dtype; every product takes bf16 operands."""
+    from paddle_tpu import models
+    from paddle_tpu.nn.graph import Network
+
+    bsz, t, vocab = 512, 50, 30000
+    n = bsz * t
+    policy = dtypes.bf16_policy()
+    net = Network([models.seq2seq(vocab, vocab, 512, 512).cost])
+    ids = jax.ShapeDtypeStruct((bsz, t), jnp.int32)
+    lens = jax.ShapeDtypeStruct((bsz,), jnp.int32)
+    batch = {
+        k: v for name in ("source_ids", "target_ids", "label_ids")
+        for k, v in ((name, ids), (name + ".lengths", lens))
+    }
+    tiny = {k: np.ones((2,) + v.shape[1:], np.int32) for k, v in batch.items()}
+    params, states = jax.eval_shape(
+        lambda: net.init(jax.random.PRNGKey(0), tiny, policy=policy)
+    )
+
+    def loss(p, st, b):
+        outs, _ = net.apply(p, st, b, train=True, rng=jax.random.PRNGKey(1), policy=policy)
+        return outs["cost"].value
+
+    def logits_sized(aval):
+        return hasattr(aval, "shape") and int(np.prod(aval.shape)) >= n * vocab
+
+    # what the forward keeps for the backward: the leaves of the vjp function
+    _, pullback = jax.eval_shape(lambda p, st, b: jax.vjp(lambda q: loss(q, st, b), p), params, states, batch)
+    kept = [x for x in jax.tree_util.tree_leaves(pullback) if logits_sized(x)]
+    assert not kept, kept
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss))(params, states, batch)
+    relaid, misread, wide_dots, seen_f32 = [], [], [], 0
+    for eqn in _all_eqns(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        big = [v.aval for v in eqn.invars if logits_sized(getattr(v, "aval", None))]
+        if big and name in ("reshape", "transpose", "copy"):
+            relaid.append((name, big[0].str_short()))
+        big32 = [a for a in big if a.dtype == jnp.float32]
+        seen_f32 += bool(big32)
+        if big32 and name not in _FUSED_INTO_THE_READ:
+            misread.append((name, big32[0].str_short()))
+        if name == "dot_general":
+            if {str(v.aval.dtype) for v in eqn.invars} != {"bfloat16"}:
+                wide_dots.append([v.aval.str_short() for v in eqn.invars])
+    assert not relaid, relaid[:5]
+    assert not misread, misread[:5]
+    assert not wide_dots, wide_dots[:5]
+    assert seen_f32  # the walk did reach the op's float32 arithmetic
+
+
 def test_policy_scope_reaches_rnn_attention_dots():
     """The seq2seq decoder's GRU/additive-attention matmuls take no policy
     parameter — they consult the AMBIENT dtypes.current() global.
