@@ -1,4 +1,4 @@
-"""Metric arithmetic on plain numbers: percentiles, rates, spreads. No jax."""
+"""Metric arithmetic on plain numbers: percentiles, tail means, rates, spreads. No jax."""
 
 from __future__ import annotations
 
@@ -16,6 +16,21 @@ def percentile(values: Sequence[float], q: float) -> float:
     ordered = sorted(values)
     rank = max(1, math.ceil(q / 100.0 * len(ordered)))
     return float(ordered[rank - 1])
+
+
+TAIL_MIN_SAMPLES = 10
+
+
+def tail_mean(values: Sequence[float], share: float) -> float:
+    """Mean of the largest `share` (0..1) of a sample: the ceil(share * n)
+    largest values. Where a percentile reads ONE order statistic and jumps
+    when a plateau's share crosses the cut, this moves by that one value's
+    weight in the tail. A tail of fewer than TAIL_MIN_SAMPLES values is no
+    tail: NaN, never a number."""
+    k = math.ceil(share * len(values))
+    if k < TAIL_MIN_SAMPLES:
+        return float("nan")
+    return float(statistics.fmean(sorted(values)[-k:]))
 
 
 def rate(items: float, seconds: float, chips: int = 1) -> float:

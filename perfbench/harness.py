@@ -140,6 +140,20 @@ def compile_count() -> int:
     return stats.RECOMPILES.cache_hits + stats.RECOMPILES.cache_misses
 
 
+def engagement() -> Dict[str, float]:
+    """The program's engagement counter after set-up, by label: how many
+    classification costs were traced through the fused projection and how
+    many beside it. Empty where nothing has moved it: a program without the
+    counter (a parent of PR 27), a cell that traces no such cost."""
+    from paddle_tpu.obs.metrics import REGISTRY
+
+    counter = REGISTRY.counter("paddle_tpu_fused_projection_xent_total")
+    return {
+        ",".join(f"{k}={v}" for k, v in s.labels): s.value
+        for s in counter.samples() if s.labels
+    }
+
+
 def device_info() -> dict:
     import jax
 
@@ -200,6 +214,7 @@ def run_cell(
     system.setup(say)
     warm_s = time.perf_counter() - t0
     compiles_setup = compile_count() - compiles0
+    say(f"info: fused projection xent, costs traced by path: {engagement() or 'none'}")
     profiler = Profiler(os.path.join(scratch, "trace") if trace else None)
     profiler.warm()
     compiles1 = compile_count()

@@ -18,6 +18,8 @@ from perfbench import arith, registry, weights
 from perfbench.reference import lowprec
 
 DRAIN_S = 60.0  # wait this long past the close for answers that are late
+TTFT_TAIL = 0.10  # ttft_tail_ms: mean of the slowest tenth of requests
+ITL_TAIL = 0.01   # itl_tail_ms: mean of the longest hundredth of gaps
 
 
 class ServeSystem:
@@ -85,7 +87,7 @@ class ServeSystem:
         step_spans: List[tuple] = []    # (seconds, ran_prefill, decoded)
         traced_steps: List[tuple] = []  # (step's start, contexts of the slots decoding)
         t_prof = t_prof_stop = None
-        backlog_mid = None
+        backlog_mid = waiting_mid = None
         nxt, n = 0, len(recs)
         t0 = clock()
         t_end = t0 + seconds
@@ -106,6 +108,7 @@ class ServeSystem:
                 t_prof = now
             if backlog_mid is None and now >= t0 + seconds / 2:
                 backlog_mid = len(live)
+                waiting_mid = sum(1 for r in live if not r["stamps"])
             if not live:
                 if nxt >= n:
                     break
@@ -150,6 +153,11 @@ class ServeSystem:
             1 for r in recs
             if r["handle"] is not None and (not r["stamps"] or r["stamps"][-1] > t_end)
         )
+        # of those, the requests with no first token when the window closed
+        waiting_end = sum(
+            1 for r in recs if r["handle"] is not None
+            and (not r["stamps"] or r["stamps"][0] > t_end)
+        )
         self.records = recs
         # the reduction keeps the trace's last KEEP_S seconds: so here
         kept_from = (t_prof_stop or t1) - (profiler.KEEP_S if profiler is not None else 0.0)
@@ -158,7 +166,8 @@ class ServeSystem:
             "step_spans": step_spans,
             "traced_contexts": [c for t, c in traced_steps if t >= kept_from],
             "lateness": lateness, "backlog_mid": backlog_mid or 0,
-            "backlog_end": backlog_end,
+            "backlog_end": backlog_end, "waiting_mid": waiting_mid or 0,
+            "waiting_end": waiting_end,
         }
 
     def reduce(self, run: dict) -> dict:
@@ -191,6 +200,11 @@ class ServeSystem:
             "untraced": untraced,
             "attempted": len(recs),
             "failed": failed,
+            # the end-to-end pair: what the unluckiest tenth of requests wait
+            # for a first token, and what a stall costs, averaged over the
+            # longest hundredth of ALL gaps (arith.tail_mean)
+            "ttft_tail_ms": 1e3 * arith.tail_mean(ttft, TTFT_TAIL),
+            "itl_tail_ms": 1e3 * arith.tail_mean(gaps, ITL_TAIL),
             "ttft_p95_ms": 1e3 * arith.percentile(ttft, 95),
             "ttft_p50_ms": 1e3 * arith.percentile(ttft, 50),
             "itl_p99_ms": 1e3 * arith.percentile(gaps, 99) if gaps else float("nan"),
@@ -206,8 +220,13 @@ class ServeSystem:
             "lateness_p99_ms": 1e3 * arith.percentile(run["lateness"], 99) if run["lateness"] else 0.0,
             "backlog_mid": run["backlog_mid"],
             "backlog_end": run["backlog_end"],
+            "waiting_mid": run["waiting_mid"],
+            "waiting_end": run["waiting_end"],
             "traced_contexts": run["traced_contexts"],
             "steps": len(run["step_spans"]),
+            # a stall of the host or the engine shows here before it shows in a tail
+            "step_max_ms": 1e3 * max((s for s, _, _ in run["step_spans"]), default=0.0),
+            "gap_max_ms": 1e3 * max(gaps, default=0.0),
             "prefill_steps": sum(1 for _, pre, _ in run["step_spans"] if pre),
         }
 
@@ -222,12 +241,14 @@ class ServeSystem:
         m = self.reduce(run)
         info = [
             f"{m['attempted']} requests due in {seconds:.0f} s, {m['failed']} failed or "
-            f"unfinished; ttft p50 {m['ttft_p50_ms']:.1f} ms p95 {m['ttft_p95_ms']:.1f} ms; "
-            f"gap p50 {m['itl_p50_ms']:.1f} ms p98 {m['itl_p98_ms']:.1f} ms p99 {m['itl_p99_ms']:.1f} ms "
+            f"unfinished; ttft p50 {m['ttft_p50_ms']:.1f} ms p95 {m['ttft_p95_ms']:.1f} ms "
+            f"tail mean {m['ttft_tail_ms']:.1f} ms; gap tail mean {m['itl_tail_ms']:.1f} ms p50 {m['itl_p50_ms']:.1f} ms p98 {m['itl_p98_ms']:.1f} ms p99 {m['itl_p99_ms']:.1f} ms "
             f"p99.5 {m['itl_p995_ms']:.1f} ms over {m['n_gaps']} gaps",
             f"generator lateness p99 {m['lateness_p99_ms']:.2f} ms; backlog at the middle "
-            f"{m['backlog_mid']}, at the close {m['backlog_end']}; {m['steps']} engine steps, "
-            f"{m['prefill_steps']} with a prefill; {m['prompt_tokens']} prompt and "
+            f"{m['backlog_mid']} ({m['waiting_mid']} with no first token yet), at the close "
+            f"{m['backlog_end']} ({m['waiting_end']}); {m['steps']} engine steps, "
+            f"{m['prefill_steps']} with a prefill, the longest {m['step_max_ms']:.1f} ms (longest gap "
+            f"{m['gap_max_ms']:.1f} ms); {m['prompt_tokens']} prompt and "
             f"{m['output_tokens']} generated tokens in {m['serve_s']:.2f} s",
         ]
         facts = {k: m[k] for k in (
@@ -242,7 +263,7 @@ class ServeSystem:
         return {
             "attempted": m["attempted"], "failed": m["failed"],
             "end_to_end": {
-                "ttft_p95_ms": m["ttft_p95_ms"], "itl_p99_ms": m["itl_p99_ms"],
+                "ttft_tail_ms": m["ttft_tail_ms"], "itl_tail_ms": m["itl_tail_ms"],
                 "setup_s": t_window - t_process_start,
             },
             "facts": facts, "info": info,
@@ -351,7 +372,7 @@ class ServeSystem:
         self.release()
         sample = self.sample()
         base = {"requests": m["attempted"], "failed": m["failed"],
-                "ttft_p95_ms": m["ttft_p95_ms"], "itl_p99_ms": m["itl_p99_ms"]}
+                "ttft_tail_ms": m["ttft_tail_ms"], "itl_tail_ms": m["itl_tail_ms"]}
         if program:
             yield dict(base, who="program", numbers=self.gaps(sample))
         if control:
@@ -377,8 +398,9 @@ class ServeSystem:
             )
             m = self.reduce(self.drive(schedule, seconds))
             yield {"rate_per_s": rate, **{k: m[k] for k in (
-                "attempted", "failed", "ttft_p50_ms", "ttft_p95_ms", "itl_p50_ms",
-                "itl_p99_ms", "backlog_mid", "backlog_end", "serve_s",
+                "attempted", "failed", "ttft_p50_ms", "ttft_p95_ms", "ttft_tail_ms",
+                "itl_p50_ms", "itl_p99_ms", "itl_tail_ms", "backlog_mid", "backlog_end",
+                "waiting_mid", "waiting_end", "serve_s",
                 "prompt_tokens", "output_tokens", "lateness_p99_ms", "steps", "prefill_steps")},
                 "decode_step_ms_p50": 1e3 * float(np.median(m["decode_only_step_s"])) if m["decode_only_step_s"] else None}
         self.release()

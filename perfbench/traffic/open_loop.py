@@ -1,15 +1,17 @@
 """Open-loop request schedule: independent users.
 
-The SEQUENCE (gap before each arrival, prompt length, output length) is
-drawn once from the cell's own `sizes_seed`, so every run of the cell carries
-the same work with the same neighbours; the run's --seed gives the point of
-the cycle at which the window starts (a rotation: another order, the same
-bursts and lulls) and the prompts' token ids. A free permutation moved the
-tails by several percent from seed to seed (my chip runs, PR 25): which long
-prompts happen to land together is part of the work, not of the seed. Gaps are exponential at the cell's fixed rate, rescaled so the
-last request is due inside the window; lengths are log-normal with the given
-medians and sigma, clipped. (A copy of serving/workload.py's idea of a
-schedule, with seeded gaps in place of its even spacing.)"""
+ONE multiset of (gap before the arrival, prompt length, output length) is
+drawn from the cell's own `sizes_seed`, so every run of the cell carries the
+same work at the same rate; the run's --seed gives a free permutation of it
+(the same set, another order) and the prompts' token ids. Which long
+requests happen to land together therefore changes from seed to seed, and
+moved PR 25's tails by several percent: that spread is what the serving
+bounds stand on, and a check pairs parent and change on the same seed. Gaps
+are exponential at the cell's fixed rate, rescaled (by the same factor under
+every seed: the sum of a permutation is the sum) so that the last request is
+due inside the window; lengths are log-normal with the given medians and
+sigma, clipped. (A copy of serving/workload.py's idea of a schedule, with
+seeded gaps in place of its even spacing.)"""
 
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ def make_schedule(params: dict, seconds: float, seed: int, vocab: int, bos_id: i
     prompt_lens = _lognormal(sizes, n, p["median"], p["sigma"], p["min"], p["max"])
     output_lens = _lognormal(sizes, n, o["median"], o["sigma"], o["min"], o["max"])
     rs = np.random.default_rng(seed)
-    order = np.roll(np.arange(n), -int(rs.integers(n)))
+    order = rs.permutation(n)
     due = np.cumsum(gaps[order])
     due = due * (seconds * n / (n + 1.0)) / due[-1]
     out = []

@@ -18,7 +18,8 @@ if ROOT not in sys.path:
 
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 SERVING_CELL = "servable_lm_tiny.chat_steady"
-SERVING_METRICS = ("decode_step_ms", "queue_wait_p95_ms", "mfu.serve",
+SERVING_E2E = ("ttft_tail_ms", "itl_tail_ms")
+SERVING_METRICS = ("decode_step_ms", "queue_wait_p95_ms", "mfu.serve", "prefill_step_share",
                    "device_idle_share.serve", "paged_attention_roofline")
 V5E_PEAKS = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
 
@@ -50,32 +51,37 @@ def extended_benchmark() -> dict:
     from perfbench import registry
 
     bench = copy.deepcopy(registry.load_benchmark())
-    for name in ("mlp_tiny", "servable_lm_tiny"):
+    for name in ("mlp_tiny", "servable_lm_tiny", "servable_lm_2048"):
         bench["configs"].append({"name": name, "source": "tests", "file": "x", "reduced": [], "why": "t"})
     bench["workloads"].append({"name": "mlp_tiny.train", "config": "mlp_tiny", "traffic": "train",
                                "chips": 1, "why": "t"})
-    bench["workloads"].append({"name": SERVING_CELL, "config": "servable_lm_tiny",
-                               "traffic": "chat_steady", "chips": 1, "why": "t"})
+    for config in ("servable_lm_tiny", "servable_lm_2048"):
+        bench["workloads"].append({"name": config + ".chat_steady", "config": config,
+                                   "traffic": "chat_steady", "chips": 1, "why": "t"})
+    # the cell whose data file waits in perfbench/workloads (PERF.md section 7)
+    bench["workloads"].append({"name": "resnet50.train_cli_feed", "config": "resnet50",
+                               "traffic": "train_cli_feed", "chips": 1, "why": "t"})
     bench["per_layer"].append({"name": "steps_per_s", "unit": "steps/s", "better": "higher",
                                "source": "host_clock", "layer": "Train loop",
                                "moves": "throughput", "workloads": ["mlp_tiny.train"]})
     for m in bench["end_to_end"]:
         if m["name"] == "throughput":
-            m["workloads"] = m["workloads"] + ["mlp_tiny.train"]
+            m["workloads"] = m["workloads"] + ["mlp_tiny.train", "resnet50.train_cli_feed"]
     # the first serving cell brings its end-to-end metrics with it (a
-    # `benchmark` PR's to add: they carry bounds) and the serving readers'
-    # metric files
-    for name in ("ttft_p95_ms", "itl_p99_ms"):
-        bench["end_to_end"].append({"name": name, "unit": "ms", "better": "lower", "bound": 0.05,
-                                    "source": "host_clock", "workloads": [SERVING_CELL]})
+    # `benchmark` PR's to add: they carry bounds, and PR 28's runs refused
+    # both, PERF.md section 7) and the serving readers' metric files
+    cells = [SERVING_CELL, "servable_lm_2048.chat_steady"]
+    for name in SERVING_E2E:
+        bench["end_to_end"].append({"name": name, "unit": "ms", "better": "lower", "bound": 0.1,
+                                    "source": "host_clock", "workloads": cells})
     for name in SERVING_METRICS:
         with open(os.path.join(EXTRA, "metrics", name + ".json")) as f:
             entry = {k: v for k, v in json.load(f).items() if k != "reader"}
-        bench["per_layer"].append(dict(entry, workloads=[SERVING_CELL]))
+        bench["per_layer"].append(dict(entry, workloads=cells))
     return bench
 
 
-def run_cell(base, name, seed=3000000019, seconds=0.5, trace=False, tmp="."):
+def run_cell(base, name, seed=3000000019, seconds=0.5, trace=False, tmp=".", say=lambda *_: None):
     from paddle_tpu.core.init_ctx import enable_compilation_cache
     from perfbench import harness
 
@@ -83,5 +89,5 @@ def run_cell(base, name, seed=3000000019, seconds=0.5, trace=False, tmp="."):
     cell = harness.load_cell(name, base=base, benchmark=extended_benchmark())
     return harness.run_cell(
         cell, seed, seconds, trace, time.perf_counter(), CPU_DEVICE, V5E_PEAKS,
-        scratch=os.path.join(str(tmp), "scratch"), say=lambda *_: None,
+        scratch=os.path.join(str(tmp), "scratch"), say=say,
     )

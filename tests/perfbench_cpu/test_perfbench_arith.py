@@ -45,6 +45,60 @@ def test_a_request_with_no_first_token_counts_as_the_worst():
     assert arith.percentile(samples, 95) == 90.0
 
 
+def two_plateaus(n_decode, n_behind_512, n_behind_1024):
+    """Gaps as the served decoder makes them: a decode step, a step behind a
+    512-bucket prefill, a step behind a 1024-bucket one (seconds)."""
+    return [0.019] * n_decode + [0.068] * n_behind_512 + [0.106] * n_behind_1024
+
+
+def test_tail_mean_is_the_mean_of_the_largest_share():
+    sample = [float(v) for v in range(1, 201)]
+    assert arith.tail_mean(sample, 0.10) == pytest.approx(sum(range(181, 201)) / 20)
+    assert arith.tail_mean(sample[::-1], 0.10) == arith.tail_mean(sample, 0.10)
+    # ceil(share * n) values: 10% of 101 is the 11 largest
+    assert arith.tail_mean([float(v) for v in range(101)], 0.10) == pytest.approx(95.0)
+
+
+def test_one_gap_moving_flips_the_percentile_and_barely_moves_the_tail_mean():
+    # 10,000 gaps: the nearest-rank 99th percentile is the 101st-largest. With
+    # 101 gaps on the 106 ms plateau it reads 106; move ONE down and it reads 68.
+    before = two_plateaus(9699, 200, 101)
+    after = two_plateaus(9699, 201, 100)
+    p_before, p_after = arith.percentile(before, 99), arith.percentile(after, 99)
+    assert (p_before, p_after) == (0.106, 0.068)
+    assert abs(p_after - p_before) / p_before > 0.35
+    t_before, t_after = arith.tail_mean(before, 0.01), arith.tail_mean(after, 0.01)
+    assert t_before == pytest.approx(0.106)
+    assert abs(t_after - t_before) / t_before < 0.01
+    # and each further gap that leaves the plateau moves it by that gap's
+    # weight in the tail, 0.36% here: smoothly, where the percentile jumped
+    moved = [arith.tail_mean(two_plateaus(9699, 201 + k, 100 - k), 0.01) for k in range(1, 4)]
+    steps = [a - b for a, b in zip([t_after] + moved, moved)]
+    assert all(s == pytest.approx((0.106 - 0.068) / 100) for s in steps)
+    assert steps[0] / t_before < 0.004
+
+
+def test_failed_requests_count_at_the_worst_in_the_ttft_tail():
+    due = [0.1 * i for i in range(100)]
+    first = [d + 0.05 for d in due]
+    served = arith.tail_mean(arith.ttft_samples(due, first, worst=90.0), 0.10)
+    assert served == pytest.approx(0.05)
+    first[40] = None   # shed, failed or never answered
+    one_lost = arith.tail_mean(arith.ttft_samples(due, first, worst=90.0), 0.10)
+    assert one_lost == pytest.approx((90.0 + 9 * 0.05) / 10)
+
+
+@pytest.mark.parametrize("n,share,is_number", [
+    (99, 0.10, True), (91, 0.10, True), (90, 0.10, False), (12, 0.10, False),
+    (0, 0.10, False), (901, 0.01, True), (900, 0.01, False),
+])
+def test_a_tail_of_fewer_than_ten_samples_is_not_a_number(n, share, is_number):
+    value = arith.tail_mean([1.0] * n, share)
+    assert (value == value) is is_number
+    if is_number:
+        assert value == 1.0
+
+
 def test_spread_is_the_contracts_quartile_distance_over_the_median():
     vals = [100.0, 101.0, 99.0, 100.5, 99.5, 100.0]
     assert arith.iqr_share(vals) == pytest.approx(
@@ -77,3 +131,25 @@ def test_profiler_starts_its_lead_before_the_part_that_is_kept():
     assert not p.due(now=44.9, t_end=50.0)
     assert p.due(now=45.1, t_end=50.0)        # 50 - KEEP_S - LEAD_S
     assert not harness.Profiler(None).due(now=49.0, t_end=50.0)
+
+
+def test_spread_tool_gives_the_bound_two_sets_of_the_same_seeds_stand_on(tmp_path, capsys):
+    import json
+
+    from perfbench import spread
+
+    def write(prefix, values):
+        for i, v in enumerate(values):
+            line = {"correct": True, "metrics": {"itl_tail_ms": {"value": v, "unit": "ms"}},
+                    "checks": {"token_logit_gap": {"value": 0.5 + 0.01 * i, "limit": 3.0}},
+                    "device": {"memory_peak_bytes": 7}}
+            (tmp_path / f"{prefix}{i}.out").write_text("info: x\n" + json.dumps(line) + "\n")
+
+    write("a.", [100.0, 101.0, 99.0, 100.5, 99.5, 100.0])      # spread 1.25%
+    write("b.", [101.0, 102.0, 100.0, 101.5, 100.5, 101.0])    # the same, 1% higher
+    assert spread.main([str(tmp_path / "a."), str(tmp_path / "b.")]) == 0
+    out = capsys.readouterr().out
+    assert "itl_tail_ms: median 100 spread 1.250%" in out
+    assert "check token_logit_gap: max 0.55 limit 3.0" in out
+    assert ("itl_tail_ms: wider spread 1.250%, five times it 0.0625, eight times 0.1000; "
+            "second median +1.000% of the first") in out

@@ -37,6 +37,19 @@ def test_added_cell_runs_and_is_correct(train_result):
     json.dumps(r)
 
 
+def test_the_engagement_counter_is_on_an_info_line_after_set_up(base, tmp_path):
+    from perfbench import harness
+
+    lines = []
+    run_cell(base, "mlp_tiny.train", tmp=tmp_path, say=lines.append)
+    told = [ln for ln in lines if ln.startswith("info: fused projection xent")]
+    assert len(told) == 1 and lines.index(told[0]) < next(
+        i for i, ln in enumerate(lines) if ln.startswith("info: set-up"))
+    counted = harness.engagement()
+    assert counted and set(counted) <= {"path=fused", "path=unfused"}
+    assert str(counted) in told[0]
+
+
 def test_throughput_counts_all_steps_over_the_whole_window(base, tmp_path):
     from perfbench import harness
     from perfbench_testlib import extended_benchmark
@@ -47,6 +60,53 @@ def test_throughput_counts_all_steps_over_the_whole_window(base, tmp_path):
     assert cell.chips == 1 and set(cell.per_layer) == {
         "steps_per_s", "compile_s", "device_idle_share.train", "mfu.train"}
     assert set(cell.end_to_end) == {"throughput", "setup_s"}
+
+
+def test_the_set_of_list_less_metrics_is_pinned():
+    """A metric without `workloads` is reported by every cell that reports
+    what it moves, later cells too: so the set grows only by a `benchmark`
+    PR's decision."""
+    from perfbench import registry
+
+    bench = registry.load_benchmark()
+    assert {m["name"] for m in bench["per_layer"] if "workloads" not in m} == {
+        "compile_s", "device_idle_share.train", "mfu.train"}
+    assert {m["name"] for m in bench["end_to_end"] if "workloads" not in m} == {"setup_s"}
+
+
+@pytest.mark.parametrize("cell,e2e", [
+    ("resnet50.train_cli_feed", {"throughput", "setup_s"}),
+    ("servable_lm_2048.chat_steady", {"ttft_tail_ms", "itl_tail_ms", "setup_s"}),
+])
+def test_the_cells_that_wait_load_by_name_once_a_benchmark_lists_them(base, cell, e2e):
+    """PR 28 measured both and admitted neither (PERF.md section 7): their
+    files load by name, and report what they would once BENCHMARK.json
+    lists them."""
+    from perfbench import harness, registry
+    from perfbench_testlib import extended_benchmark
+
+    assert cell not in {w["name"] for w in registry.load_benchmark()["workloads"]}
+    loaded = harness.load_cell(cell, base=base, benchmark=extended_benchmark())
+    assert set(loaded.end_to_end) == e2e and loaded.chips == 1
+    if cell.startswith("resnet50"):
+        # a data file beside resnet50.train's, differing in stack_k alone
+        assert harness.load_cell(cell).workload == loaded.workload
+        assert loaded.workload["params"]["stack_k"] == 8 == loaded.config["steps_per_dispatch"]
+        plain = registry.load_workload("resnet50.train")
+        assert loaded.workload["check"] == plain["check"]
+        assert dict(loaded.workload["params"], stack_k=1) == plain["params"]
+        assert set(loaded.per_layer) == {"compile_s", "device_idle_share.train", "mfu.train"}
+    else:
+        p = loaded.workload["params"]
+        assert (p["prompt_len"], p["output_len"], p["sizes_seed"], p["temperature"]) == (
+            {"median": 117, "sigma": 0.8, "min": 16, "max": 1024},
+            {"median": 245, "sigma": 0.8, "min": 16, "max": 1024}, 20260930, 0.0)
+        s = loaded.config["session"]
+        assert p["prompt_len"]["max"] + p["output_len"]["max"] <= loaded.config["max_position_embeddings"]
+        assert p["output_len"]["max"] <= s["max_new_limit"] and p["prompt_len"]["max"] <= s["prefill_buckets"][-1]
+        assert set(loaded.per_layer) == {
+            "compile_s", "mfu.serve", "paged_attention_roofline", "decode_step_ms",
+            "prefill_step_share", "device_idle_share.serve", "queue_wait_p95_ms"}
 
 
 def test_added_metric_is_read_by_its_own_reader(base):
@@ -86,8 +146,10 @@ def test_decide_needs_every_number_under_its_limit():
 def test_serving_cell_runs_and_is_correct(base, tmp_path):
     r = run_cell(base, "servable_lm_tiny.chat_steady", seconds=1.5, tmp=tmp_path)
     assert r["correct"] is True, r["checks"]
-    assert set(r["metrics"]) == {"ttft_p95_ms", "itl_p99_ms", "setup_s"}
-    assert r["attempted"] == 12 and r["failed"] == 0
+    assert set(r["metrics"]) == {"ttft_tail_ms", "itl_tail_ms", "setup_s"}
+    # enough requests and gaps for both tails to be tails (arith.tail_mean)
+    assert all(m["value"] > 0 for m in r["metrics"].values()), r["metrics"]
+    assert r["attempted"] == 150 and r["failed"] == 0
     assert r["checks"]["never_answered"]["value"] == 0.0
 
 
@@ -100,7 +162,8 @@ def test_traced_serving_run_reports_the_per_layer_metrics_it_can_read(base, tmp_
     r = run_cell(base, "servable_lm_tiny.chat_steady", seconds=1.5, trace=True, tmp=tmp_path)
     assert r["correct"] is True, r["checks"]
     assert set(r["metrics"]) == {"compile_s", "mfu.serve", "decode_step_ms",
-                                 "queue_wait_p95_ms"}
+                                 "queue_wait_p95_ms", "prefill_step_share"}
+    assert 0 < r["metrics"]["prefill_step_share"]["value"] < 100
     assert all(m["value"] > 0 for m in r["metrics"].values())
     assert "busy_s" not in r["device"] and "breakdown" not in r
 
