@@ -40,6 +40,12 @@ def rate(items: float, seconds: float, chips: int = 1) -> float:
     return items / seconds / chips
 
 
+def count_until(stamps: Sequence[float], t_end: float) -> int:
+    """How many of the stamps lie at or before t_end: the work a window that
+    closes at t_end completed."""
+    return sum(1 for s in stamps if s <= t_end)
+
+
 def token_gaps(stamps: Sequence[float]) -> List[float]:
     """Gaps between consecutive token times of one request."""
     return [b - a for a, b in zip(stamps, stamps[1:])]
@@ -53,9 +59,25 @@ def ttft_samples(
     return [worst if f is None else f - d for d, f in zip(due, first)]
 
 
+def ceil_sig(value: float, digits: int) -> float:
+    """`value` (above 0) rounded UP to `digits` significant digits."""
+    unit = 10.0 ** (math.floor(math.log10(value)) - digits + 1)
+    return round(math.ceil(value / unit - 1e-9) * unit, 12)
+
+
 def iqr_share(values: Iterable[float]) -> float:
     """The contract's spread: distance between the first and third quartile
     (statistics.quantiles, n=4) as a share of the median."""
     vals = list(values)
     q1, _, q3 = statistics.quantiles(vals, n=4)
     return (q3 - q1) / statistics.median(vals)
+
+
+def trimmed_iqr_share(values: Iterable[float]) -> float:
+    """The spread with the run farthest from the median left out: what the
+    driver's check reads for tightness (a bound is too tight where the mean
+    of its two sets' trimmed spreads is over half of it)."""
+    vals = list(values)
+    mid = statistics.median(vals)
+    vals.remove(max(vals, key=lambda v: abs(v - mid)))
+    return iqr_share(vals)

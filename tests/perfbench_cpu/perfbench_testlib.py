@@ -4,7 +4,6 @@ directories in a temporary directory with the files of a would-be later PR
 only, no file that exists edited."""
 
 import copy
-import json
 import os
 import shutil
 import sys
@@ -18,7 +17,8 @@ if ROOT not in sys.path:
 
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 SERVING_CELL = "servable_lm_tiny.chat_steady"
-SERVING_E2E = ("ttft_tail_ms", "itl_tail_ms")
+SATURATED_CELL = "servable_lm_tiny.chat_saturated"
+SERVING_E2E = ("serve_throughput",)
 SERVING_METRICS = ("decode_step_ms", "queue_wait_p95_ms", "mfu.serve", "prefill_step_share",
                    "device_idle_share.serve", "paged_attention_roofline")
 V5E_PEAKS = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
@@ -51,13 +51,17 @@ def extended_benchmark() -> dict:
     from perfbench import registry
 
     bench = copy.deepcopy(registry.load_benchmark())
-    for name in ("mlp_tiny", "servable_lm_tiny", "servable_lm_2048"):
+    for name in ("mlp_tiny", "servable_lm_tiny"):
         bench["configs"].append({"name": name, "source": "tests", "file": "x", "reduced": [], "why": "t"})
     bench["workloads"].append({"name": "mlp_tiny.train", "config": "mlp_tiny", "traffic": "train",
                                "chips": 1, "why": "t"})
-    for config in ("servable_lm_tiny", "servable_lm_2048"):
-        bench["workloads"].append({"name": config + ".chat_steady", "config": config,
-                                   "traffic": "chat_steady", "chips": 1, "why": "t"})
+    # later serving cells: the tiny ones of the tests, and the open-loop cell
+    # that waits for a latency metric (PERF.md section 7)
+    cells = [SERVING_CELL, SATURATED_CELL, "servable_lm_2048.chat_steady"]
+    for name in cells:
+        config, traffic = name.split(".")
+        bench["workloads"].append({"name": name, "config": config, "traffic": traffic,
+                                   "chips": 1, "why": "t"})
     # the cell whose data file waits in perfbench/workloads (PERF.md section 7)
     bench["workloads"].append({"name": "resnet50.train_cli_feed", "config": "resnet50",
                                "traffic": "train_cli_feed", "chips": 1, "why": "t"})
@@ -67,17 +71,11 @@ def extended_benchmark() -> dict:
     for m in bench["end_to_end"]:
         if m["name"] == "throughput":
             m["workloads"] = m["workloads"] + ["mlp_tiny.train", "resnet50.train_cli_feed"]
-    # the first serving cell brings its end-to-end metrics with it (a
-    # `benchmark` PR's to add: they carry bounds, and PR 28's runs refused
-    # both, PERF.md section 7) and the serving readers' metric files
-    cells = [SERVING_CELL, "servable_lm_2048.chat_steady"]
-    for name in SERVING_E2E:
-        bench["end_to_end"].append({"name": name, "unit": "ms", "better": "lower", "bound": 0.1,
-                                    "source": "host_clock", "workloads": cells})
-    for name in SERVING_METRICS:
-        with open(os.path.join(EXTRA, "metrics", name + ".json")) as f:
-            entry = {k: v for k, v in json.load(f).items() if k != "reader"}
-        bench["per_layer"].append(dict(entry, workloads=cells))
+    # a later serving cell appends its name to serve_throughput's list and to
+    # the serving per-layer metrics' lists
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in SERVING_E2E + SERVING_METRICS:
+            m["workloads"] = m["workloads"] + cells
     return bench
 
 

@@ -133,23 +133,85 @@ def test_profiler_starts_its_lead_before_the_part_that_is_kept():
     assert not harness.Profiler(None).due(now=49.0, t_end=50.0)
 
 
-def test_spread_tool_gives_the_bound_two_sets_of_the_same_seeds_stand_on(tmp_path, capsys):
+@pytest.mark.parametrize("second,verdict", [
+    # the same spread 1% higher: five times 1.25% is 0.0625, rounded up to 0.063
+    ([101.0, 102.0, 100.0, 101.5, 100.5, 101.0],
+     "five times the wider spread 0.0625, six times the narrower 0.0743, twice the wider "
+     "0.0250; second median +1.000% of the first; bound 0.063: admit"),
+    # a narrower second set: six times ITS spread is the bound, under twice the wider
+    ([100.0, 100.1, 99.9, 100.05, 99.95, 100.0],
+     "five times the wider spread 0.0625, six times the narrower 0.0075, twice the wider "
+     "0.0250; second median +0.000% of the first; bound 0.01: refuse"),
+    # the medians apart by more than half the bound
+    ([104.0, 105.0, 103.0, 104.5, 103.5, 104.0],
+     "five times the wider spread 0.0625, six times the narrower 0.0721, twice the wider "
+     "0.0250; second median +4.000% of the first; bound 0.063: refuse"),
+])
+def test_spread_tool_gives_the_rule_two_sets_of_the_same_seeds_stand_on(tmp_path, capsys, second, verdict):
     import json
 
     from perfbench import spread
 
     def write(prefix, values):
         for i, v in enumerate(values):
-            line = {"correct": True, "metrics": {"itl_tail_ms": {"value": v, "unit": "ms"}},
+            line = {"correct": True, "metrics": {"serve_throughput": {"value": v, "unit": "tokens/s/chip"},
+                                                 "setup_s": {"value": 20.0 + i, "unit": "s"}},
                     "checks": {"token_logit_gap": {"value": 0.5 + 0.01 * i, "limit": 3.0}},
                     "device": {"memory_peak_bytes": 7}}
             (tmp_path / f"{prefix}{i}.out").write_text("info: x\n" + json.dumps(line) + "\n")
 
     write("a.", [100.0, 101.0, 99.0, 100.5, 99.5, 100.0])      # spread 1.25%
-    write("b.", [101.0, 102.0, 100.0, 101.5, 100.5, 101.0])    # the same, 1% higher
+    write("b.", second)
     assert spread.main([str(tmp_path / "a."), str(tmp_path / "b.")]) == 0
     out = capsys.readouterr().out
-    assert "itl_tail_ms: median 100 spread 1.250%" in out
+    assert "serve_throughput: median 100 spread 1.250%" in out
     assert "check token_logit_gap: max 0.55 limit 3.0" in out
-    assert ("itl_tail_ms: wider spread 1.250%, five times it 0.0625, eight times 0.1000; "
-            "second median +1.000% of the first") in out
+    assert "serve_throughput: " + verdict in out.splitlines()
+    # the window the driver's check leaves: set a's trimmed spread is 1% (the
+    # run at 101 or 99 left out), and eight times the wider spread is 0.1
+    window = next(ln for ln in out.splitlines() if "the check takes a bound from" in ln)
+    assert window.startswith("serve_throughput: on these runs") and "to 0.1000 (eight" in window
+    assert "setup_s: second median +0.000% of the first; bound 0.1 by the contract" in out.splitlines()
+
+
+@pytest.mark.parametrize("value,up", [(0.0625, 0.063), (0.0234, 0.024), (0.05, 0.05),
+                                      (0.00123, 0.0013), (0.1, 0.1), (0.0301, 0.031)])
+def test_a_bound_is_rounded_up_to_two_significant_digits(value, up):
+    assert arith.ceil_sig(value, 2) == pytest.approx(up, rel=1e-9)
+
+
+@pytest.mark.parametrize("stamps,counted", [
+    ([9.0, 9.5, 10.0], 3),            # a token stamped AT the close counts
+    ([9.0, 9.5, 10.0, 10.001, 11.0], 3),   # a request that straddles it: the tokens it had
+    ([10.5, 11.0], 0),                # first token after the close: none
+    ([], 0),
+])
+def test_serve_throughput_counts_tokens_stamped_by_the_close_and_none_after(stamps, counted):
+    from types import SimpleNamespace
+
+    from perfbench.serving import ServeSystem
+
+    assert arith.count_until(stamps, 10.0) == counted
+    # and through the reduction: one request beside one that finished early
+    cell = SimpleNamespace(config={}, workload={}, chips=1)
+    done = SimpleNamespace(done=True, tokens=[5, 6])
+    recs = [{"due": 0.0, "prompt": [1, 2, 3], "handle": done, "stamps": [1.0, 2.0]},
+            {"due": 0.0, "prompt": [1, 2], "handle": SimpleNamespace(done=True, tokens=stamps),
+             "stamps": stamps}]
+    run = {"recs": recs, "t0": 0.0, "t1": 12.0, "t_end": 10.0, "step_spans": [], "lateness": [],
+           "backlog_mid": 0, "backlog_end": 0, "waiting_mid": 0, "waiting_end": 0,
+           "traced_contexts": []}
+    m = ServeSystem(cell, 1).reduce(run)
+    assert m["window_tokens"] == 2 + counted
+    assert m["serve_throughput"] == pytest.approx((2 + counted) / 10.0)
+    assert m["finished_in_window"] == 1 + (bool(stamps) and stamps[-1] <= 10.0)
+
+
+@pytest.mark.parametrize("values,trimmed", [
+    ([100.0, 101.0, 99.0, 100.5, 99.5, 100.0], arith.iqr_share([100.0, 99.0, 100.5, 99.5, 100.0])),
+    ([100.0, 100.0, 100.0, 100.0, 100.0, 140.0], 0.0),     # one far-off run does no harm
+    ([100.0, 100.0, 100.0, 100.0, 140.0, 140.0], arith.iqr_share([100.0, 100.0, 100.0, 100.0, 140.0])),
+])
+def test_the_trimmed_spread_leaves_out_the_run_farthest_from_the_median(values, trimmed):
+    assert arith.trimmed_iqr_share(values) == pytest.approx(trimmed)
+    assert arith.trimmed_iqr_share(values) <= arith.iqr_share(values)

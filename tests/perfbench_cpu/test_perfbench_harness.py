@@ -76,12 +76,12 @@ def test_the_set_of_list_less_metrics_is_pinned():
 
 @pytest.mark.parametrize("cell,e2e", [
     ("resnet50.train_cli_feed", {"throughput", "setup_s"}),
-    ("servable_lm_2048.chat_steady", {"ttft_tail_ms", "itl_tail_ms", "setup_s"}),
+    ("servable_lm_2048.chat_steady", {"serve_throughput", "setup_s"}),
 ])
 def test_the_cells_that_wait_load_by_name_once_a_benchmark_lists_them(base, cell, e2e):
     """PR 28 measured both and admitted neither (PERF.md section 7): their
     files load by name, and report what they would once BENCHMARK.json
-    lists them."""
+    lists them (the served one still waits for a latency metric)."""
     from perfbench import harness, registry
     from perfbench_testlib import extended_benchmark
 
@@ -144,13 +144,60 @@ def test_decide_needs_every_number_under_its_limit():
 
 
 def test_serving_cell_runs_and_is_correct(base, tmp_path):
-    r = run_cell(base, "servable_lm_tiny.chat_steady", seconds=1.5, tmp=tmp_path)
+    lines = []
+    r = run_cell(base, "servable_lm_tiny.chat_steady", seconds=1.5, tmp=tmp_path, say=lines.append)
     assert r["correct"] is True, r["checks"]
-    assert set(r["metrics"]) == {"ttft_tail_ms", "itl_tail_ms", "setup_s"}
-    # enough requests and gaps for both tails to be tails (arith.tail_mean)
+    assert set(r["metrics"]) == {"serve_throughput", "setup_s"}
+    assert r["metrics"]["serve_throughput"]["unit"] == "tokens/s/chip"
     assert all(m["value"] > 0 for m in r["metrics"].values()), r["metrics"]
     assert r["attempted"] == 150 and r["failed"] == 0
     assert r["checks"]["never_answered"]["value"] == 0.0
+    # the tails left the end-to-end metrics for the info line, numbers still:
+    # enough requests and gaps for both to be tails (arith.tail_mean)
+    told = next(ln for ln in lines if "gap tail mean" in ln)
+    assert "tail mean nan" not in told
+
+
+def test_the_closed_loop_cell_runs_end_to_end_and_is_correct(base, tmp_path):
+    lines = []
+    r = run_cell(base, "servable_lm_tiny.chat_saturated", seconds=1.0, tmp=tmp_path, say=lines.append)
+    assert r["correct"] is True, r["checks"]
+    assert set(r["metrics"]) == {"serve_throughput", "setup_s"}
+    assert all(m["value"] > 0 for m in r["metrics"].values()), r["metrics"]
+    # six clients: six at the start, one more for each that finished by the close
+    assert r["attempted"] > 6 and r["failed"] == 0
+    assert r["checks"]["never_answered"] == {"value": 0.0, "limit": 0.0}
+    told = next(ln for ln in lines if ln.startswith("info: serve_throughput"))
+    assert "mean gap between tokens in it (tpot)" in told and "nan" not in told
+    finished = int(told.split(" requests finished in it")[0].split()[-1])
+    # the lead-in's six and the window's finished were each replaced while the
+    # loop was open; at the close those with no first token were cancelled
+    # (six clients on four slots: at least two) and are not waited for
+    assert 6 + finished - 4 <= r["attempted"] <= 6 + 6 + 4 + finished
+    gave_up = next(ln for ln in lines if "cancelled at the close" in ln)
+    assert int(gave_up.split(" requests with no first token")[0].split()[-1]) >= 2
+    assert r["metrics"]["setup_s"]["value"] > float(gave_up.split("lead-in ")[1].split(" s")[0]) > 0
+
+
+def test_the_benchmarks_cells_report_what_benchmark_json_gives_them():
+    from perfbench import harness
+
+    served = harness.load_cell("servable_lm_2048.chat_saturated")
+    assert set(served.end_to_end) == {"serve_throughput", "setup_s"} and served.chips == 1
+    assert set(served.per_layer) == {
+        "compile_s", "decode_step_ms", "prefill_step_share", "queue_wait_p95_ms", "mfu.serve",
+        "paged_attention_roofline", "device_idle_share.serve"}
+    assert all(m["moves"] == "serve_throughput" for n, m in served.per_layer.items()
+               if n != "compile_s")
+    p = served.workload["params"]
+    assert (p["clients"], p["plan_requests"], p["sizes_seed"]) == (48, 512, 20260930)
+    assert served.config["session"]["max_slots"] == 32 < p["clients"]
+    for name, kernel in (("resnet50.train", set()), ("seq2seq_nmt.train", {"gru_seq_roofline"})):
+        cell = harness.load_cell(name)
+        assert set(cell.end_to_end) == {"throughput", "setup_s"}
+        assert set(cell.per_layer) == kernel | {
+            "compile_s", "device_idle_share.train", "mfu.train", "input_wait_share.train",
+            "host_ms_per_dispatch", "setup_trace_lower_s", "setup_backend_s"}
 
 
 def test_traced_serving_run_reports_the_per_layer_metrics_it_can_read(base, tmp_path, monkeypatch):
