@@ -9,21 +9,40 @@ masking — sequence length is *data*, never *shape*). The jnp path in
 HBM — before a masked softmax. This kernel is the TPU shape of the same
 computation:
 
-  * grid = (slots, pages_per_seq); the PAGE loop is the inner grid dim;
-  * the block table rides in as a SCALAR-PREFETCH operand, so each grid
-    step's k/v BlockSpec index map picks the slot's PHYSICAL page straight
-    out of it — the gather happens in the DMA engine, one [PS, KD] page at
-    a time, and the dense [S, P, PS, KD] intermediate never exists;
-  * per-slot length masking against the slot's own position (logical token
-    index <= position), so ragged mixed-age batches share the executable;
-  * numerically-stable ONLINE softmax in f32: running max / denominator /
-    weighted-value accumulator live in VMEM scratch across the page loop
-    (the flash-attention recurrence), flushed to the output on the last
-    page.
+  * grid = (slots,): one grid step a slot, and inside it a `fori_loop` over
+    the slot's BLOCKS of B pages whose trip count comes from the slot's own
+    position, `ceil(pages_held / B)` with `pages_held = pos // PS + 1` — a
+    slot does work for the pages it holds and for no other (an empty slot,
+    position 0 over an all-dump table, walks one block of one page);
+  * the pools stay in HBM (`memory_space=HBM`) and ride in whole; the block
+    table and the positions are SCALAR-PREFETCH operands, and the kernel
+    itself issues one async copy a held page, `pool[layer, table[slot, p]]`
+    -> row p of a [B*PS, KD] VMEM tile, K and V each: the gather happens in
+    the DMA engine and the dense [S, P, PS, KD] intermediate never exists.
+    Pages of a block past the slot's position are not fetched at all;
+  * the tiles are DOUBLE-BUFFERED along one chain that runs through the
+    whole call: while block j is computed block j+1 of the slot is in
+    flight, and while a slot's last block is computed the NEXT slot's first
+    is (the grid is sequential, `arbitrary`, and the buffer's turn is
+    carried from slot to slot in SMEM);
+  * the two products and the online-softmax update run once a block over
+    B*PS tokens: per-slot length masking against the slot's own position
+    (logical token index <= position), running max / denominator /
+    weighted-value accumulator in VMEM scratch in f32 (the flash-attention
+    recurrence), flushed to the output after the slot's last block.
 
-Unused block-table entries point at dump page 0 and their logical indices
-exceed the slot's position, so they contribute exp(-1e9 - m) == 0 exactly —
-bitwise the same masking contract as the oracle.
+B comes from the shapes (`_pages_per_block`: page size, KD, the table's
+width, against the VMEM budget `TILE_BUDGET` and the `BLOCK_TOKENS` cap stated
+below), never from an argument or the environment; the table's width need not
+be a multiple of it. The choice is logged once a geometry on the `paddle_tpu`
+logger.
+
+Masking contract, bitwise the oracle's: a token past the slot's position
+scores NEG_INF, so its weight is exp(-1e9 - m) == 0 exactly, whatever the
+tile holds there. The rows of a tile that no copy wrote hold what an earlier
+block left, or the zeros both V tiles start a call with — finite either way,
+so 0 x row is 0 (K's rows need no such care: their scores are replaced, not
+multiplied).
 
 The jnp gather path remains the CPU oracle: `paged_attention_decode` must
 match it to float tolerance (argmax-equal under greedy decode) for every
@@ -36,6 +55,7 @@ the kernel through the Pallas interpreter for the equality tests."""
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +66,8 @@ from paddle_tpu.ops.pallas import interpret_mode
 
 Array = jax.Array
 
+log = logging.getLogger("paddle_tpu")
+
 # must equal serving/model.NEG_INF: fully-masked pages then degrade to a
 # zero contribution exactly as the oracle's softmax does
 NEG_INF = -1e9
@@ -55,71 +77,163 @@ _LANES = 128
 # the f32 oracle to float tolerance, not to one bf16 pass
 _PRECISION = jax.lax.Precision.HIGHEST
 
+# VMEM the four gathered tiles may take together (K and V, two buffers
+# each), of the 16 MiB Mosaic scopes a kernel by default on a v5e: the rest
+# is the products' operands split into bf16 passes, q and the accumulator
+TILE_BUDGET = 4 << 20
+# tokens a block at most: past a few MXU tiles of tokens a longer block
+# amortises nothing more, and a short slot's one block masks most of it
+BLOCK_TOKENS = 512
+
+
+@functools.lru_cache(maxsize=None)
+def _pages_per_block(page_size: int, kd: int, pmax: int) -> int:
+    """B, the pages one block gathers: as many as the budget, the token cap
+    and the table's width allow. Logged here, once a geometry."""
+    page_bytes = page_size * kd * 4
+    b = min(
+        TILE_BUDGET // (4 * page_bytes), BLOCK_TOKENS // page_size, pmax
+    )
+    b = max(1, b)
+    log.info(
+        "paged_attention_decode: %d pages a block (%d tokens of %d lanes, "
+        "%d KiB of VMEM tiles), at most %d blocks a slot",
+        b, b * page_size, kd, (4 * b * page_bytes) >> 10, -(-pmax // b),
+    )
+    return b
+
 
 def _paged_decode_kernel(
+    layer_ref,  # scalar prefetch: [1] the pool's layer (SMEM)
     bt_ref,    # scalar prefetch: [S * P] flattened block table (SMEM)
     pos_ref,   # scalar prefetch: [S] positions (SMEM)
-    q_ref,     # [1, H, KD] — this slot's query, pre-scaled, block-diagonal:
-               # row h holds head h's hd values in its own lane segment
-    k_ref,     # [PS, KD] — this grid step's physical page (layer squeezed)
-    v_ref,     # [PS, KD]
+    q_ref,     # [1, 1, KD] — this slot's query
+    k_hbm,     # [L, NP, PS, KD] — the whole pool, in HBM
+    v_hbm,     # [L, NP, PS, KD]
     out_ref,   # [1, 1, KD]
+    k_buf,     # VMEM [2, B*PS, KD] gathered K tiles, double-buffered
+    v_buf,     # VMEM [2, B*PS, KD]
+    sems,      # DMA semaphores [2 (K, V), 2 (buffer)]
+    turn_ref,  # SMEM [1]: the buffer this slot's first block was sent to
+    q_scr,     # VMEM [H, KD] the query, pre-scaled, block-diagonal: row h
+               # holds head h's hd values in its own lane segment
     m_scr,     # VMEM [H, LANES] running max (lane-replicated)
     l_scr,     # VMEM [H, LANES] running denominator (lane-replicated)
     acc_scr,   # VMEM [H, KD] running probs @ v, every head against ALL lanes
     *,
+    scale: float,
     page_size: int,
     head_dim: int,
+    pages_per_block: int,
+    pmax: int,
 ):
     s = pl.program_id(0)
-    p = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    layer = layer_ref[0]
+    ps, b = page_size, pages_per_block
+    t = b * ps
+
+    def pages_held(slot):
+        return jnp.minimum(pos_ref[slot] // ps + 1, pmax)
+
+    def each_page(slot, blk, act):
+        """`act(i, p)` for each page p = blk*B + i of the block the slot
+        holds: the same pages when a block is sent for and when it is
+        waited for, since both read the prefetched scalars."""
+        held = pages_held(slot)
+        for i in range(b):
+            pl.when(blk * b + i < held)(
+                functools.partial(act, i, blk * b + i)
+            )
+
+    tiles = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def copy(kv, buf, i, page):
+        hbm, buf_ref = tiles[kv]
+        return pltpu.make_async_copy(
+            hbm.at[layer, page],
+            buf_ref.at[buf, pl.ds(i * ps, ps)],
+            sems.at[kv, buf],
+        )
+
+    def start(slot, blk, buf):
+        def act(i, p):
+            page = bt_ref[slot * pmax + p]
+            copy(0, buf, i, page).start()
+            copy(1, buf, i, page).start()
+
+        each_page(slot, blk, act)
+
+    def wait(kv, blk, buf):
+        # a wait takes its size from the descriptor, not its source
+        each_page(s, blk, lambda i, p: copy(kv, buf, i, 0).wait())
+
+    @pl.when(s == 0)
+    def _open_chain():
+        v_buf[...] = jnp.zeros_like(v_buf)
+        turn_ref[0] = 0
+        start(0, 0, 0)
+
     pos = pos_ref[s]
+    n_blk = (pages_held(s) + b - 1) // b
+    first = turn_ref[0]
 
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    kd = q_scr.shape[1]
+    head = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 1)
+    own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
+    q_scr[...] = jnp.where(own, q_ref[0].astype(jnp.float32) * scale, 0.0)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # pages wholly past the slot's position are fully masked: their
-    # contribution is exp(NEG_INF - m) == 0 exactly, so skipping them is
-    # bitwise the masked computation (page 0 always runs: index 0 <= pos)
-    @pl.when(p * page_size <= pos)
-    def _page():
+    def block(j, carry):
+        buf = (first + j) % 2
+        # the chain's next link: this slot's next block, or behind its last
+        # the next slot's first
+        last = j + 1 == n_blk
+        nxt_slot = jnp.where(last, s + 1, s)
+        nxt_blk = jnp.where(last, 0, j + 1)
+        pl.when(nxt_slot < n_slots)(
+            lambda: start(nxt_slot, nxt_blk, 1 - buf)
+        )
+
+        wait(0, j, buf)
         # all heads in one MXU call: q is block-diagonal over the lane
         # segments, so row h of q @ k^T contracts head h's lanes only
         sc = jax.lax.dot_general(
-            q_ref[0], k_ref[:].astype(jnp.float32), (((1,), (1,)), ((), ())),
+            q_scr[...], k_buf[buf].astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_PRECISION,
-        )  # [H, PS]
+        )  # [H, T]
         # ragged masking: logical token index within THIS slot's sequence
-        idx = p * page_size + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        idx = j * t + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         sc = jnp.where(idx <= pos, sc, NEG_INF)
         # online-softmax recurrence (f32 throughout)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        probs = jnp.exp(sc - m_new)  # [H, PS]
+        probs = jnp.exp(sc - m_new)  # [H, T]
         l_new = l_scr[:, :1] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        wait(1, j, buf)
         # row h against every lane; only head h's own segment is kept at
-        # the flush (H-fold redundant MXU work on a DMA-bound kernel, in
-        # exchange for no in-kernel reshape/transpose of the [PS, KD] tile)
+        # the flush (H-fold redundant MXU work, in exchange for no
+        # in-kernel reshape/transpose of the [T, KD] tile)
         pv = jax.lax.dot_general(
-            probs, v_ref[:].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            probs, v_buf[buf].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_PRECISION,
         )  # [H, KD]
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        return carry
 
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _flush():
-        # l >= exp(0 - m) > 0 always: logical index 0 is <= every position
-        ctx = acc_scr[:] / l_scr[:, :1]  # [H, KD]
-        head = jax.lax.broadcasted_iota(jnp.int32, ctx.shape, 0)
-        lane = jax.lax.broadcasted_iota(jnp.int32, ctx.shape, 1)
-        own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
-        out_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0, keepdims=True)
+    jax.lax.fori_loop(0, n_blk, block, 0)
+    turn_ref[0] = (first + n_blk) % 2
+
+    # l >= exp(0 - m) > 0 always: logical index 0 is <= every position
+    ctx = acc_scr[...] / l_scr[:, :1]  # [H, KD]
+    out_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0, keepdims=True)
 
 
 def paged_attention_decode(
@@ -139,50 +253,88 @@ def paged_attention_decode(
     softmax; the online recurrence reassociates the sum so equality is to
     float tolerance, argmax/token-exact under greedy decode).
 
-    The pool rides in whole and `layer` picks the page inside the DMA's
-    index map: slicing `k_pages[layer]` outside would make XLA copy one
-    layer of the pool per layer per step to feed the custom call."""
-    s, kd = q.shape
+    The pool rides in whole, in HBM, and `layer` picks the page inside the
+    kernel's own copies (`_decode_layer`): slicing `k_pages[layer]` outside would make XLA
+    copy one layer of the pool per layer per step to feed the custom call."""
+    s, kd_model = q.shape
+    head_dim = kd_model // n_heads
+    if kd_model % _LANES:
+        # Mosaic (jax 0.9.0) slices no HBM ref whose minor dimension is not
+        # whole lanes, so the kernel's own copies cannot name a page of such
+        # a pool: a model that narrow (the demo's 2 heads of 16) pays a
+        # lane-padded copy of ONE layer of its pool a call. The padded lanes
+        # belong to no head: q is zero there and the flush drops them.
+        def widen(x):
+            lanes = [(0, 0)] * (x.ndim - 1) + [(0, -kd_model % _LANES)]
+            return jnp.pad(x, lanes)
+
+        q = widen(q)
+        k_pages = widen(k_pages[layer:layer + 1])
+        v_pages = widen(v_pages[layer:layer + 1])
+        layer = 0
+    b = _pages_per_block(k_pages.shape[2], q.shape[1], block_table.shape[1])
+    out = _decode_layer(
+        jnp.asarray([layer], jnp.int32),
+        block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
+        q[:, None, :], k_pages, v_pages,
+        scale=scale, n_heads=n_heads, head_dim=head_dim, pages_per_block=b,
+        interpret=interpret_mode(),
+    )
+    return out[:, 0, :kd_model]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "scale", "n_heads", "head_dim", "pages_per_block", "interpret"
+    ),
+)
+def _decode_layer(
+    layer, table, positions, q, k_pages, v_pages,
+    *, scale, n_heads, head_dim, pages_per_block, interpret,
+):
+    """The kernel's call. The layer is DATA (a third prefetched scalar) and
+    the call is jitted, so the L calls of one decode step share one trace
+    and one lowering of the kernel: traced a layer at a time its unrolled
+    page copies cost the served cell 9 s of warm-up (chip run, PR 30)."""
+    s, _, kd = q.shape
     ps = k_pages.shape[2]
-    pmax = block_table.shape[1]
-    hd = kd // n_heads
-
-    def page_map(i, j, bt, pos):
-        return (layer, bt[i * pmax + j], 0, 0)
-
+    b = pages_per_block
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, pmax),
+        num_scalar_prefetch=3,
+        grid=(s,),
         in_specs=[
-            pl.BlockSpec((1, n_heads, kd), lambda i, j, bt, pos: (i, 0, 0)),
-            # the ragged gather: the block table (prefetched to SMEM before
-            # the body runs) drives which physical page the DMA fetches
-            pl.BlockSpec((None, None, ps, kd), page_map),
-            pl.BlockSpec((None, None, ps, kd), page_map),
+            pl.BlockSpec((1, 1, kd), lambda i, *_: (i, 0, 0)),
+            # the ragged gather is the kernel's own: the block table
+            # (prefetched to SMEM before the body runs) names the physical
+            # page each of its copies fetches
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, 1, kd), lambda i, j, bt, pos: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, kd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((2, b * ps, kd), k_pages.dtype),
+            pltpu.VMEM((2, b * ps, kd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((n_heads, kd), jnp.float32),
             pltpu.VMEM((n_heads, _LANES), jnp.float32),
             pltpu.VMEM((n_heads, _LANES), jnp.float32),
             pltpu.VMEM((n_heads, kd), jnp.float32),
         ],
     )
-    # block-diagonal queries [S, H, KD]: row h = head h's values in lanes
-    # [h*hd, (h+1)*hd), zeros elsewhere
-    own = (jnp.arange(kd)[None, :] // hd) == jnp.arange(n_heads)[:, None]
-    qs = q.astype(jnp.float32) * scale
-    q_bd = jnp.where(own[None], qs[:, None, :], 0.0)
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page_size=ps, head_dim=hd),
+    return pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, scale=scale, page_size=ps,
+            head_dim=head_dim, pages_per_block=b,
+            pmax=table.shape[0] // s,
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, kd), jnp.float32),
+        # sequential: the double-buffer chain runs from slot to slot
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
-        interpret=interpret_mode(),
+        interpret=interpret,
         name="paged_attention_decode",
-    )(
-        block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
-        q_bd, k_pages, v_pages,
-    )
-    return out[:, 0]
+    )(layer, table, positions, q, k_pages, v_pages)

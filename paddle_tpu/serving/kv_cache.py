@@ -397,7 +397,8 @@ class PagedKVCache:
         """The [max_slots, max_pages_per_seq] int32 table (live view — copy
         is taken by the device transfer itself). On TPU this same table is
         the SCALAR-PREFETCH operand of the ragged paged-attention kernel
-        (ops/pallas/paged_attention.py): its rows drive the page-gather DMA."""
+        (ops/pallas/paged_attention.py): its rows name the physical page of
+        each copy the kernel issues, a block of pages at a time."""
         return self._table
 
     def slot_row(self, slot: int) -> np.ndarray:

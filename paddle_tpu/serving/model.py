@@ -27,6 +27,17 @@ The serving runtime is split the way TPU inference engines split it
 On TPU the decode gather+softmax runs as the Pallas ragged paged-attention
 kernel (ops/pallas/paged_attention.py) — the jnp gather path here stays the
 CPU oracle, asserted equivalent in interpret mode (tests/test_decode_fastpath).
+The kernel walks a slot in BLOCKS of B pages: its own async copies gather the
+B physical pages the block table names into one [B*PS, KD] VMEM tile for K
+and one for V, double-buffered from block to block and from slot to slot, and
+the two products and the online-softmax update run once a block. A slot's
+trip count is ceil(pages it holds / B), read from its position, so an empty
+slot costs one block and a page past a slot's position is never fetched. B
+comes from the shapes (page size, KD and the table's width against a VMEM
+budget the kernel's file states; 8 pages = 128 tokens at 16 heads of 128 and
+pages of 16) and is logged once a geometry. At that geometry, 20 of 32 slots
+live over 796 pages, the 24 calls of one decode step take 8.7 ms where the
+page-a-grid-step kernel before it took 25.2 ms (chip run, PR 30).
 
 Sampling (ISSUE 11) happens ON DEVICE in every token-emitting executable:
 `_sample` draws through a per-request key `fold_in(PRNGKey(seed), step)` with
@@ -597,8 +608,9 @@ class ServableLM:
         single-chip math for those heads).
 
         Two numerically-equivalent paths behind one seam: the Pallas kernel
-        (ops/pallas/paged_attention.py — block table drives the page gathers
-        in the DMA engine, online f32 softmax in VMEM) when `pallas.enabled()`
+        (ops/pallas/paged_attention.py — the block table names the pages its
+        copies gather, B a block and only those a slot holds, online f32
+        softmax in VMEM) when `pallas.enabled()`
         (TPU, or PADDLE_TPU_PALLAS=1/interpret), else the dense jnp gather —
         which is also the kernel's CPU ORACLE: interpret-mode equality across
         mixed lengths/block tables is pinned in tests/test_decode_fastpath."""
@@ -644,8 +656,9 @@ class ServableLM:
         CPU, identical in_specs) on its resident kv_heads slice of the page
         pool, with the block table and positions replicated; attention never
         crosses heads, so the seam adds ZERO collectives and the kernel's
-        scalar-prefetch block-table operand (its grid geometry) is the same
-        per shard as on one chip — just fewer heads per page fetch."""
+        scalar-prefetch block-table operand is the same per shard as on one
+        chip — fewer heads a page, so narrower pages and more of them a
+        block (B comes from the shard's own shapes)."""
         if self.mesh is None:
             return self._paged_attention_local(
                 q, k_pages, v_pages, block_table, positions,

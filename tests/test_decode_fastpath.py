@@ -87,33 +87,19 @@ def _oracle_paged_attention(q, k_pages, v_pages, block_table, positions,
     return jnp.einsum("sht,sthd->shd", w, v_seq).reshape(s, -1)
 
 
-@pytest.mark.parametrize("seed,ps,pmax", [(0, 8, 4), (1, 4, 7), (2, 16, 3)])
-def test_kernel_matches_oracle_mixed_lengths(seed, ps, pmax):
-    """Interpret-mode equality across mixed lengths, pages and block-table
-    layouts — including empty slots (position 0, all-dump tables), partially
-    filled pages, and out-of-order physical page assignments."""
+def _assert_kernel_matches_oracle(rng, bt, positions, ps, H=2, HD=8):
+    """The kernel in interpret mode against the jnp gather oracle over one
+    block table and one set of positions, at atol 1e-5. The kernel takes the
+    whole [L, NP, PS, KD] pool and a layer index: the layer under test is
+    buried behind a decoy so a wrong index shows."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas.paged_attention import paged_attention_decode
 
-    rng = np.random.RandomState(seed)
-    S, H, HD = 5, 2, 8
-    NP = 1 + pmax * S
-    KD = H * HD
+    S, NP, KD = bt.shape[0], int(bt.max()) + 2, H * HD
     q = jnp.asarray(rng.randn(S, KD), jnp.float32)
     kp = jnp.asarray(rng.randn(NP, ps, KD), jnp.float32)
     vp = jnp.asarray(rng.randn(NP, ps, KD), jnp.float32)
-    # ragged: each slot owns a random number of shuffled physical pages
-    bt = np.zeros((S, pmax), np.int32)
-    free = list(rng.permutation(np.arange(1, NP)))
-    positions = np.zeros(S, np.int32)
-    for s_ in range(S - 1):  # last slot stays empty (dump table, position 0)
-        n = rng.randint(1, pmax + 1)
-        pages = [free.pop() for _ in range(n)]
-        bt[s_, :n] = pages
-        positions[s_] = rng.randint(0, n * ps)
-    # the kernel takes the whole [L, NP, PS, KD] pool and a layer index:
-    # bury the layer under test behind a decoy so a wrong index shows
     got = paged_attention_decode(
         q, jnp.stack([vp, kp]), jnp.stack([kp, vp]),
         jnp.asarray(bt), jnp.asarray(positions),
@@ -124,6 +110,127 @@ def test_kernel_matches_oracle_mixed_lengths(seed, ps, pmax):
         1.0 / np.sqrt(HD), H,
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("seed,ps,pmax", [(0, 8, 4), (1, 4, 7), (2, 16, 3)])
+def test_kernel_matches_oracle_mixed_lengths(seed, ps, pmax):
+    """Interpret-mode equality across mixed lengths, pages and block-table
+    layouts — including empty slots (position 0, all-dump tables), partially
+    filled pages, and out-of-order physical page assignments."""
+    rng = np.random.RandomState(seed)
+    S = 5
+    NP = 1 + pmax * S
+    # ragged: each slot owns a random number of shuffled physical pages
+    bt = np.zeros((S, pmax), np.int32)
+    free = list(rng.permutation(np.arange(1, NP)))
+    positions = np.zeros(S, np.int32)
+    for s_ in range(S - 1):  # last slot stays empty (dump table, position 0)
+        n = rng.randint(1, pmax + 1)
+        pages = [free.pop() for _ in range(n)]
+        bt[s_, :n] = pages
+        positions[s_] = rng.randint(0, n * ps)
+    _assert_kernel_matches_oracle(rng, bt, positions, ps)
+
+
+@pytest.fixture
+def three_page_blocks(monkeypatch):
+    """B = 3 pages of 8 tokens: the token cap the kernel states, lowered for
+    the test so that a table of 7 pages is walked in blocks of 3, 3 and 1
+    (B itself stays a function of the shapes and that cap)."""
+    from paddle_tpu.ops.pallas import paged_attention
+
+    monkeypatch.setattr(paged_attention, "BLOCK_TOKENS", 24)
+    paged_attention._pages_per_block.cache_clear()
+    assert paged_attention._pages_per_block(8, 128, 7) == 3
+    yield
+    paged_attention._pages_per_block.cache_clear()
+
+
+def _owned(pmax, held):
+    """A block table of len(held) slots: slot i owns held[i] distinct pages
+    in a shuffled physical order, the rest of its row the dump page."""
+    bt = np.zeros((len(held), pmax), np.int32)
+    pages = iter(np.random.RandomState(11).permutation(
+        np.arange(1, 1 + sum(held))
+    ))
+    for i, n in enumerate(held):
+        bt[i, :n] = [next(pages) for _ in range(n)]
+    return bt
+
+
+# name -> (pages each slot holds, each slot's position); PS 8, PMAX 7, B 3:
+# block 0 is tokens 0-23, block 1 tokens 24-47, block 2 the one page 48-55
+BLOCK_WALK = {
+    # the table's width is no multiple of B: the last block is one page wide
+    "pmax_not_multiple_of_b": ([7, 6, 4, 1, 2], [50, 41, 30, 3, 12]),
+    # contexts that end in the first and in the last token of a block
+    "ends_on_block_edges": ([4, 6, 3, 3, 7], [24, 47, 23, 0, 48]),
+    # full context: position PMAX * PS - 1
+    "full_context": ([7, 7, 1], [55, 55, 7]),
+    "every_slot_empty": ([0, 0, 0, 0], [0, 0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_WALK))
+def test_kernel_matches_oracle_block_walk(case, three_page_blocks):
+    """What walking a slot in blocks of B pages makes new: a ragged last
+    block, contexts ending on a block's edges, full and empty tables."""
+    held, positions = BLOCK_WALK[case]
+    _assert_kernel_matches_oracle(
+        np.random.RandomState(5), _owned(7, held),
+        np.asarray(positions, np.int32), ps=8,
+    )
+
+
+def test_kernel_matches_oracle_aliased_pages(three_page_blocks):
+    """Two slots name the SAME physical pages (the prefix cache's aliasing,
+    read-only) at different positions, a third shares only their first
+    block: each slot's walk fetches the pages for itself."""
+    bt = _owned(7, [5, 5, 6])
+    bt[1] = bt[0]
+    bt[2, :3] = bt[0, :3]
+    _assert_kernel_matches_oracle(
+        np.random.RandomState(6), bt, np.asarray([39, 33, 44], np.int32), ps=8,
+    )
+
+
+def test_kernel_block_wider_than_table():
+    """At the stated budget and cap B would be 64 pages of 8 tokens; a table
+    of 7 pages holds it to 7, one block a slot, its tail past the slot's
+    position never fetched."""
+    from paddle_tpu.ops.pallas import paged_attention
+
+    assert paged_attention._pages_per_block(8, 128, 7) == 7
+    _assert_kernel_matches_oracle(
+        np.random.RandomState(7), _owned(7, [7, 2, 0, 5]),
+        np.asarray([55, 9, 0, 32], np.int32), ps=8,
+    )
+
+
+def test_pages_per_block_comes_from_the_shapes(caplog):
+    """B at the geometries the chip sees, each logged once: the benchmark's
+    cell (16 heads of 128), a --tp=4 shard of it (4 heads), chip_smoke's
+    demo (2 heads of 16, padded to one lane tile) and its aligned one."""
+    import logging
+
+    from paddle_tpu.ops.pallas import paged_attention
+
+    paged_attention._pages_per_block.cache_clear()
+    with caplog.at_level(logging.INFO, logger="paddle_tpu"):
+        assert paged_attention._pages_per_block(16, 2048, 128) == 8
+        assert paged_attention._pages_per_block(16, 2048, 128) == 8
+        assert paged_attention._pages_per_block(16, 512, 128) == 32
+        assert paged_attention._pages_per_block(16, 128, 8) == 8
+        assert paged_attention._pages_per_block(16, 2048, 8) == 8
+    lines = [
+        r.getMessage() for r in caplog.records
+        if "pages a block" in r.getMessage()
+    ]
+    assert len(lines) == 4
+    assert lines[0].startswith(
+        "paged_attention_decode: 8 pages a block (128 tokens"
+    )
+    assert "at most 16 blocks a slot" in lines[0]
 
 
 def test_kernel_session_tokens_equal_oracle_session(

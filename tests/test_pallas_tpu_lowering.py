@@ -68,15 +68,16 @@ def _gru_shapes(t, b, h):
     return [(t, b, 3 * h), (t, b, 1), (h, 2 * h), (h, h), (3 * h,), (b, h)]
 
 
-def _paged_shapes(s, heads, hd, ps, pmax):
-    kd, n_pages = heads * hd, 1 + s * pmax
-    pool = (2, n_pages, ps, kd)
+def _paged_shapes(s, heads, hd, ps, pmax, layers=2, n_pages=None):
+    kd = heads * hd
+    pool = (layers, n_pages or 1 + s * pmax, ps, kd)
     return [(s, kd), pool, pool, ((s, pmax), jnp.int32), ((s,), jnp.int32)]
 
 
 # (name, fn, argument shapes, pallas_calls expected) at chip_smoke.py's FULL
 # shapes: seq2seq's GRU, rnn_bench's LSTMs, the demo and the lane-aligned
-# serving geometries. The backward programs re-run the forward kernel.
+# serving geometries, and the shapes the benchmark and --tp=4 send the
+# paged-attention kernel. The backward programs re-run the forward kernel.
 CASES = [
     ("lstm_fwd_h256", _lstm_fwd, _lstm_shapes(100, 64, 256), 1),
     ("lstm_bwd_h256", _lstm_bwd, _lstm_shapes(100, 64, 256), 2),
@@ -88,6 +89,13 @@ CASES = [
      [(16, 128, 128), (16, 128, 128), (16, 128, 128), (16, 1, 128)], 1),
     ("paged_demo", _paged(2), _paged_shapes(8, 2, 16, 16, 8), 1),
     ("paged_aligned", _paged(16), _paged_shapes(16, 16, 128, 16, 8), 1),
+    # the benchmark's served cell (servable_lm_2048: 32 slots, 16 heads of
+    # 128, 128 pages of 16 a slot, a pool of 24 layers x 833 pages) and one
+    # shard of it under --tp=4 (4 of the 16 heads)
+    ("paged_cell", _paged(16),
+     _paged_shapes(32, 16, 128, 16, 128, layers=24, n_pages=833), 1),
+    ("paged_tp_shard", _paged(4),
+     _paged_shapes(32, 4, 128, 16, 128, layers=24, n_pages=833), 1),
 ]
 
 
