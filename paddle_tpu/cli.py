@@ -812,7 +812,7 @@ def _job_time(
                 lowered.compile()
             )
         except Exception as e:  # the timing line must survive a backend
-            # that cannot cost-analyze (bench.py's discipline)
+            # that cannot cost-analyze
             out["hlo_cost_error"] = repr(e)[-300:]
     print(json.dumps(out))
     return 0

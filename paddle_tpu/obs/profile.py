@@ -9,8 +9,8 @@ buckets" — which first needs the buckets. Two hooks deliver them:
     so a crashed pass or a double-wrapped handler cannot wedge the tracer.
   * `compiled_cost_report` / `trainer_cost_report` — lower+compile the step
     program(s) and rank XLA's `cost_analysis()` entries into top-k FLOP/byte
-    buckets, the machine-readable target list that lands in the bench JSON
-    (bench.py, `--job=time --profile`, and the `--profile` report file).
+    buckets, the machine-readable target list that lands in the JSON of
+    `--job=time --profile` and in the `--profile` report file.
 """
 
 from __future__ import annotations

@@ -1,12 +1,13 @@
 """Fused batch normalization for TPU.
 
-Why hand-write this (profiled on the real chip, see PROFILE_r03.md): the
-naive formulation (`xf = x.astype(f32); mean(xf); var(xf); normalize(xf)`)
-lets XLA materialize/share a float32 copy of every conv activation between the
+Why hand-write this: the naive formulation
+(`xf = x.astype(f32); mean(xf); var(xf); normalize(xf)`) lets XLA
+materialize/share a float32 copy of every conv activation between the
 statistics pass and the apply pass, and jax autodiff of that formulation emits
 more full passes over the activation than the textbook backward needs. On a
-bandwidth-bound model (ResNet-50 conv stack streams HBM at ~87% of peak) every
-extra pass over a [B,H,W,C] tensor is pure step time.
+bandwidth-bound model (ResNet-50's convolution fusions are HBM-bound on the
+chip, PERF.md section 5) every extra pass over a [B,H,W,C] tensor is pure
+step time.
 
 Design (reference behavioral contract: BatchNormLayer.cpp / CudnnBatchNorm,
 per-channel statistics over batch+spatial):
@@ -55,7 +56,7 @@ def _bn_fwd_impl(x, gamma, beta, eps):
     # scale/shift folded to per-channel a,b so the apply pass is one fma.
     # a/b stay f32 (they are [C]-sized — free) and the normalize arithmetic
     # runs f32 with ONE cast on the output: with bf16 activations and large
-    # beta/mean magnitudes, doing the fma in bf16 loses mantissa (ADVICE r3);
+    # beta/mean magnitudes, doing the fma in bf16 loses mantissa;
     # XLA fuses the converts into the elementwise pass either way.
     a = gamma.astype(jnp.float32) * inv
     b = beta.astype(jnp.float32) - gamma.astype(jnp.float32) * inv * mean

@@ -4,7 +4,7 @@ The problem with raw ``ParamAttr.sharding`` tuples is that every call site
 hard-codes MESH axis names ("model", "expert") into model code, so the same
 model cannot move between a data-only training mesh, a 2-D dp x tp mesh and
 a serving TP mesh without editing each tuple.  The fix is the DEFAULT_RULES
-pattern (SNIPPETS.md [2]/[3], the t5x/flax ``logical_axis_rules`` idiom):
+pattern (the t5x/flax ``logical_axis_rules`` idiom):
 
   * arrays declare LOGICAL axis names once at creation
     (``ParamAttr(logical_axes=("embed", "mlp"))``, or
@@ -68,8 +68,8 @@ def warn_legacy_sharding(param: str) -> None:
         stacklevel=3,
     )
 
-# the one serving+training sharding vocabulary (SNIPPETS.md DEFAULT_RULES
-# pattern). Values are mesh axis names or None (replicated).
+# the one serving+training sharding vocabulary. Values are mesh axis names
+# or None (replicated).
 DEFAULT_RULES: Dict[str, Optional[str]] = {
     "batch": "data",      # batch rows over the data axis
     "heads": "model",     # attention query heads (column-parallel qkv)

@@ -28,8 +28,7 @@ at temperature > 0.
 Shape discipline is *asserted*, not hoped for: every decode step's input
 signature is recorded into a serving-local stats.RecompileStats (the PR-1
 telemetry) and `decode_shape_signatures()` must stay at 1 over any request
-mix — the zero-recompile gate in tests/test_serving.py and
-benchmarks/serving_bench.py.
+mix — the zero-recompile gate in tests/test_serving.py.
 
 Hot-loop discipline matches the trainer's (README "Async execution"): the
 decode loop performs exactly ONE device->host fetch per step — the sampled

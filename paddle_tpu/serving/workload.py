@@ -1,7 +1,8 @@
 """Closed-loop serving workloads: N concurrent streams vs sequential.
 
-Shared by benchmarks/serving_bench.py and the bench.py serving leg so the
-acceptance numbers and the tracked metric are the same code path.
+Driven by chip_smoke.py's served round, tests/test_tp_serving.py and the
+drills of benchmarks/chaos_bench.py (the benchmark's own traffic is under
+perfbench/traffic/).
 
 A "stream" models one user connection: it keeps exactly one request in
 flight, submitting its next request the moment the previous one completes —
@@ -33,57 +34,6 @@ def make_prompts(
         ln = int(lengths[i % len(lengths)])
         body = rs.randint(3, vocab, size=ln - 1)
         out.append([bos_id] + [int(t) for t in body])
-    return out
-
-
-def make_repetitive_prompts(
-    n: int,
-    motif_len: int,
-    repeats: int,
-    vocab: int,
-    bos_id: int,
-    seed: int = 0,
-) -> List[List[int]]:
-    """High-overlap prompts for the speculative-decoding legs (ISSUE 16):
-    each prompt is BOS + a short random motif repeated, so the prompt-lookup
-    drafter has dense n-gram matches from the first generated token. A
-    per-prompt motif keeps the workload shape-diverse across requests while
-    every individual request stays self-similar — the regime prompt-lookup
-    speculation is built for (extraction, code edits, templated text)."""
-    rs = np.random.RandomState(seed)
-    out = []
-    for i in range(n):
-        motif = [int(t) for t in rs.randint(3, vocab, size=motif_len)]
-        out.append([bos_id] + motif * repeats)
-    return out
-
-
-def make_shared_prefix_prompts(
-    n: int,
-    n_prefixes: int,
-    prefix_len: int,
-    suffix_len: int,
-    vocab: int,
-    bos_id: int,
-    seed: int = 0,
-) -> List[List[int]]:
-    """The prefix-cache workload (ISSUE 19): `n_prefixes` distinct system
-    prompts, each shared by `n // n_prefixes`-ish user turns that differ only
-    in a short random suffix — the many-users-one-system-prompt regime the
-    shared-prefix KV cache is built for. Prompts cycle round-robin over the
-    prefixes so consecutive requests hit DIFFERENT chains (the adversarial
-    order for a naive single-tail cache; a radix-over-pages index must not
-    care). Every suffix is unique, so past the shared pages each request
-    still pays its own prefill — the measured win isolates the prefix."""
-    rs = np.random.RandomState(seed)
-    prefixes = [
-        [bos_id] + [int(t) for t in rs.randint(3, vocab, size=prefix_len - 1)]
-        for _ in range(n_prefixes)
-    ]
-    out = []
-    for i in range(n):
-        suffix = [int(t) for t in rs.randint(3, vocab, size=suffix_len)]
-        out.append(prefixes[i % n_prefixes] + suffix)
     return out
 
 

@@ -147,7 +147,7 @@ class SGDTrainer:
         # (ops/normalization.py), the pass-cost average and the divergence
         # guard's isfinite (both fed by the f32-pinned cost below).
         # None = inherit the ambient dtypes.current() global at build time
-        # (init_ctx's dtype_policy flag / bench.py's set_policy).
+        # (init_ctx's dtype_policy flag, or a caller's dtypes.set_policy).
         self._policy_override = (
             dtypes.get(precision) if precision is not None else None
         )

@@ -75,9 +75,9 @@ def _paged_shapes(s, heads, hd, ps, pmax, layers=2, n_pages=None):
 
 
 # (name, fn, argument shapes, pallas_calls expected) at chip_smoke.py's FULL
-# shapes: seq2seq's GRU, rnn_bench's LSTMs, the demo and the lane-aligned
-# serving geometries, and the shapes the benchmark and --tp=4 send the
-# paged-attention kernel. The backward programs re-run the forward kernel.
+# shapes: seq2seq's GRU, LSTMs of hidden 256 and 1280, the demo and the
+# lane-aligned serving geometries, and the shapes the benchmark and --tp=4
+# send the paged-attention kernel. The backward programs re-run the forward kernel.
 CASES = [
     ("lstm_fwd_h256", _lstm_fwd, _lstm_shapes(100, 64, 256), 1),
     ("lstm_bwd_h256", _lstm_bwd, _lstm_shapes(100, 64, 256), 2),
@@ -124,7 +124,7 @@ def test_rnn_dispatch_decides_from_shapes(monkeypatch, caplog):
     from paddle_tpu.ops import rnn
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
-    assert rnn._use_fused(True, "lstm", 64, 1280)   # rnn_bench: resident
+    assert rnn._use_fused(True, "lstm", 64, 1280)   # hidden 1280: resident
     assert rnn._use_fused(True, "gru", 128, 512)    # seq2seq
     with caplog.at_level(logging.INFO, logger="paddle_tpu"):
         assert not rnn._use_fused(True, "lstm", 256, 1280)

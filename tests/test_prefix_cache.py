@@ -464,23 +464,50 @@ def test_tenant_isolation_end_to_end(model_and_params):
 # -- speculation composition --------------------------------------------------
 
 
-def test_speculation_composes_with_prefix_cache(model_and_params):
+def test_speculation_composes_with_prefix_cache(model_and_params, monkeypatch):
     """Speculation's +K headroom and aliased prefix pages coexist: repeated
     repetitive prompts hit the cache AND speculate, tokens stay bitwise vs
     cache-off, trims only ever free private tail pages (no double-free),
     and the pool is whole after flush."""
-    prompt = SYS + [5, 9, 11] * 5  # shared prefix + a draftable cyclic tail
-    ref, rs = run_prompts(
+    from paddle_tpu.serving.speculation import PromptLookupDrafter
+
+    prompt = SYS + [5, 9, 11] * 5  # shared prefix + a cyclic tail
+    (want,), _ = run_prompts(
+        model_and_params, [prompt], prefix=False, speculate_k=0, max_new=12,
+    )
+    assert len(want) == 12
+    # What these weights generate after the tail repeats no bigram inside 12
+    # tokens, so the prompt-lookup drafter rightly drafts nothing; the draft
+    # this test needs is built: the true continuation but for its last
+    # token, so every round both accepts and rejects.
+    seen, drafts = [], []
+
+    def draft(self, k):
+        seen.append(self._ctx[: len(prompt)] == prompt)
+        n = len(self) - len(prompt)
+        d = want[n:n + k]
+        d[-1] = (d[-1] + 1) % VOCAB
+        drafts.append(d)
+        return d
+
+    monkeypatch.setattr(PromptLookupDrafter, "draft", draft)
+    ref, _ = run_prompts(
         model_and_params, [prompt, prompt], prefix=False, speculate_k=4,
         max_new=12,
     )
+    n_off = len(drafts)
     out, s = run_prompts(
         model_and_params, [prompt, prompt], prefix=True, speculate_k=4,
         max_new=12,
     )
-    assert out == ref
+    assert out == ref == [want, want]
+    # precondition: with its prompt's pages aliased the drafter was still
+    # shown the whole prompt and asked for (and gave) a draft every round
+    assert n_off > 0 and len(drafts) == 2 * n_off and all(seen)
     st = s.stats()
     assert st["spec_rounds"] > 0 and st["prefix_hits"] >= 1
+    assert st["spec_tokens_accepted"] > 0
+    assert st["spec_tokens_accepted"] < st["spec_tokens_drafted"]
     assert 1.0 <= st["spec_effective_k"] <= 4.0
     assert st["verify_shape_signatures"] <= 1
     total = s.cache.num_pages - 1

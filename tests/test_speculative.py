@@ -149,14 +149,52 @@ def test_speculate_k0_is_todays_engine(model_and_params):
     assert st["spec_pages_trimmed"] == 0
 
 
-def test_spec_pages_reserved_trimmed_and_recycled(model_and_params):
+def test_spec_pages_reserved_trimmed_and_recycled(model_and_params, monkeypatch):
     """The +K page headroom reserved at admission is trimmed back to the
     pool once unreachable and fully returned at retirement — later
     requests reuse the same pool with nothing leaked."""
+    from paddle_tpu.serving.speculation import PromptLookupDrafter
+
     s = make_session(model_and_params, speculate_k=8, page_size=8)
     free0 = s.cache.free_pages
-    _run_all(s, REPETITIVE, 16)
+    want = _run_all(s, REPETITIVE, 16)[0]
     assert s.cache.free_pages == free0, "pages leaked across retirement"
+    # The headroom is trimmed only for a slot that ENTERS a speculation round
+    # with one token left; a request whose last verify round commits its
+    # final tokens keeps the page until release, so whether the three above
+    # trimmed anything is their drafts' luck. Build the case: a request whose
+    # every draft provably misses (one token that is NOT the model's next:
+    # `want` is its greedy continuation, speculation being result-transparent)
+    # commits exactly one token a round and must pass the trim.
+    prompt = REPETITIVE[0]
+    assert len(prompt) == 16 and len(want) == 16, "case needs 4 full pages"
+    monkeypatch.setattr(
+        PromptLookupDrafter, "draft",
+        lambda self, k: [(want[len(self) - len(prompt)] + 1) % VOCAB],
+    )
+    trims = []  # (pages held, total_len asked, pages freed, still active)
+    real_trim = s.cache.trim
+
+    def spy(slot, total_len):
+        held = len(s.cache.slot_pages(slot))
+        freed = real_trim(slot, total_len)
+        active = slot in dict(s.scheduler.active_slots())
+        trims.append((held, total_len, freed, active))
+        return freed
+
+    monkeypatch.setattr(s.cache, "trim", spy)
+    rounds0, accepted0 = s.spec_rounds, s.spec_tokens_accepted
+    h = s.submit(prompt, 16)
+    s.run_until_idle()
+    assert h.tokens == want
+    # precondition: tokens 2..15 each came from a verify round that rejected
+    # its draft, so the slot entered the next round with exactly one left
+    assert s.spec_rounds - rounds0 == 14
+    assert s.spec_tokens_accepted == accepted0
+    # ... where the trim found prompt 16 + new 16 + K 8 = 5 pages reserved,
+    # 4 reachable, and gave the 5th back while the request was in flight
+    assert trims and trims[0] == (5, 32, 1, True), trims
+    assert s.cache.free_pages == free0
     # the trim counter moves when the reservation crossed a page boundary
     # the base length alone wouldn't have: prompt 16 + new 16 fills exactly
     # 4 pages, so +8 headroom adds a 5th that must come back mid-flight
