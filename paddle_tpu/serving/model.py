@@ -14,9 +14,13 @@ The serving runtime is split the way TPU inference engines split it
                     interleaved with decode (ISSUE 11 chunked prefill; the
                     Orca-style continuous-batching refinement, PAPERS.md).
                     One executable for every prompt length.
-  * `commit_prefill` — scatters prompt K/V into the slot's pages at an
-                    arbitrary `starts` offset (whole prompts and chunks
-                    share this one scatter).
+  * `commit_prefill` — writes prompt K/V into the slot's pages at an
+                    arbitrary `starts` offset (whole prompts, chunks and
+                    speculation's verify share this one write), a layer
+                    at a time into the donated pools. Never one scatter
+                    over all layers: XLA:TPU gives a scatter whose window
+                    spans the layer dim its operand pages-major, two
+                    relayouts of each whole pool a call (PERF.md, PR 32).
   * `decode_step` — ONE token for ALL slots at the fixed [max_slots] shape:
                     write the step K/V into each slot's current page, gather
                     each slot's pages through its block-table row, masked
@@ -414,7 +418,7 @@ class ServableLM:
 
         The chunk's K/V commits via `commit_prefill` INSIDE this program
         (pages donated in/out, the decode_step convention): reading and
-        scattering the pool in one executable lets XLA update it in place,
+        writing the pool in one executable lets XLA update it in place,
         where a separate commit dispatch would copy the whole pool — the
         donated input would still be pinned by this program's in-flight read.
 
@@ -569,10 +573,17 @@ class ServableLM:
         block_rows: Array,  # [B, max_pages_per_seq] int32
         starts: Array,  # [B] — position of kc[..., 0, :] (0 = whole prompt)
     ) -> Tuple[Array, Array]:
-        """Scatter prompt K/V into the slots' pages at offset `starts`
-        (whole-prompt prefill passes zeros; chunked prefill commits each
-        chunk at its own offset). Positions past a prompt's length land in
-        dump page 0 (never read unmasked)."""
+        """Write prompt K/V into the slots' pages at offset `starts`
+        (whole-prompt prefill passes zeros; chunked prefill and speculation
+        commit each chunk at its own offset). Positions past a prompt's
+        length land in dump page 0 (never read unmasked).
+
+        One scatter a LAYER, the form `decode_step` writes in, never one
+        `at[:, page, offs]` over all of them: XLA:TPU lays a scatter's
+        operand out with the indexed dims major, so a window that spans the
+        layer dim costs two relayouts of each whole pool a call (four
+        `copy f32[24,833,16,2048]`, 32-53 ms a prefill and 2.62 GB of
+        temporaries at the served cell's geometry; PERF.md, PR 32)."""
         ps = k_pages.shape[2]
         l, b, t, kd = kc.shape
         pos = starts[:, None] + jnp.arange(t)[None, :]  # [B, T] absolute
@@ -583,12 +594,20 @@ class ServableLM:
         offs = (pos % ps).reshape(-1)
         kf = kc.reshape(l, b * t, kd)
         vf = vc.reshape(l, b * t, kd)
-        # pool placement pinned at every producing seam: the scatter keeps
+
+        def write_layer(i, pools):
+            k, v = pools
+            return k.at[i, page, offs].set(kf[i]), v.at[i, page, offs].set(vf[i])
+
+        k_pages, v_pages = jax.lax.fori_loop(
+            0, l, write_layer, (k_pages, v_pages)
+        )
+        # pool placement pinned at every producing seam: the scatters keep
         # the kv_heads dim sharded (indices touch page/offset dims only), so
         # donated pools round-trip their TP layout with no resharding
         return (
-            self._constrain(k_pages.at[:, page, offs].set(kf), *POOL_LOGICAL_AXES),
-            self._constrain(v_pages.at[:, page, offs].set(vf), *POOL_LOGICAL_AXES),
+            self._constrain(k_pages, *POOL_LOGICAL_AXES),
+            self._constrain(v_pages, *POOL_LOGICAL_AXES),
         )
 
     # -- the ONE decode executable ------------------------------------------

@@ -7,7 +7,15 @@ lowering rules without a chip, at the shapes chip_smoke.py sends: a refusal
 shows here in seconds instead of costing chip time. What lowering cannot see
 — Mosaic's own passes and the VMEM budget — the slow-tier test below compiles
 ahead of time against a described v5e topology (libtpu, no device needed).
+
+The same described v5e compiles the serving programs that write the page
+pool (no Pallas in them; they are here because one file may describe the
+topology: a second file can land on another xdist worker, which cannot load
+libtpu too): what XLA:TPU does to the pool, a relayout or an in-place
+update, is in the compiled text and costs no chip time to read.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -133,16 +141,32 @@ def test_rnn_dispatch_decides_from_shapes(monkeypatch, caplog):
     assert not rnn._use_fused(False, "lstm", 8, 8)  # exotic activations
 
 
+@pytest.fixture(scope="module")
+def on_chip():
+    """One chip of a described v5e as a sharding for avals. The persistent
+    compile cache is off meanwhile: it cannot read such an entry back
+    without a chip and warns at every later compile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.slow
-def test_mosaic_compiles_for_v5e():
+def test_mosaic_compiles_for_v5e(on_chip):
     """Ahead-of-time compile against a described v5e: Mosaic's own passes
     and the scoped-VMEM allocation, including the largest carries the
     dispatch rule lets through."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    topo = topologies.get_topology_desc("v5e:2x2", "tpu")
-    on_chip = SingleDeviceSharding(topo.devices[0])
     edge = [
         ("lstm_bwd_edge", _lstm_bwd, _lstm_shapes(8, 128, 1280), 2),
         ("gru_bwd_edge", _gru_bwd, _gru_shapes(8, 64, 1536), 2),
@@ -150,3 +174,69 @@ def test_mosaic_compiles_for_v5e():
     for name, fn, shapes, n_calls in CASES + edge:
         compiled = jax.jit(fn).lower(*_avals(shapes, on_chip)).compile()
         assert compiled.as_text().count("tpu_custom_call") == n_calls, name
+
+
+# -- the page pool is written in place ---------------------------------------
+# The served cell's pool (servable_lm_2048: 24 layers x 833 pages of 16
+# positions x 2048 lanes, 2.62 GB each for K and V) under the commit program,
+# and the same pool at two layers (218 MB each) under the two programs that
+# run a forward before they commit: those keep 112 MB of relaid weights
+# whatever the commit does, so their temporaries get one pool's bytes where
+# the commit alone gets 64 MB.
+CELL_POOL = (24, 833, 16, 2048)
+MAX_PAGES = 128
+
+# program: (method, positions, layers, the most its temporaries may take)
+POOL_WRITERS = {
+    "commit_bucket_64": ("commit_prefill", 64, 24, 64e6),
+    "commit_bucket_1024": ("commit_prefill", 1024, 24, 64e6),
+    "prefill_chunk_64": ("prefill_chunk", 64, 2, 218e6),
+    "verify_chunk_5": ("verify_chunk", 5, 2, 218e6),
+}
+
+
+@pytest.mark.parametrize("program", sorted(POOL_WRITERS))
+def test_page_pool_is_written_in_place(program, on_chip):
+    """No instruction of the pool's shape is a `copy`, both donated pools
+    are the outputs' buffers, and the temporaries hold no pool: a scatter
+    whose window spans the layer dim costs four whole-pool copies and a pool
+    of temporaries here (PERF.md, PR 32)."""
+    from paddle_tpu.serving.model import LMConfig, ServableLM
+
+    method, positions, n_layers, most_temp = POOL_WRITERS[program]
+    pool = (n_layers,) + CELL_POOL[1:]
+    kd = pool[3]
+    model = ServableLM(LMConfig(vocab=512, n_layers=n_layers, d_model=kd,
+                                n_heads=16, max_len=2048))
+    one, rows = ((1,), jnp.int32), ((1, MAX_PAGES), jnp.int32)
+    seeds, temps = ((1,), jnp.uint32), ((1,), jnp.float32)
+    kv, tokens = (n_layers, 1, positions, kd), ((1, positions), jnp.int32)
+    shapes = [pool, pool] + {
+        # kc, vc, lengths, block_rows, starts
+        "commit_prefill": [kv, kv, one, rows, one],
+        # tokens, starts, lengths, block_rows, seeds, temps, top_ks
+        "prefill_chunk": [tokens, one, one, rows, seeds, temps, one],
+        # tokens, starts, block_rows, seeds, steps0, temps, top_ks
+        "verify_chunk": [tokens, one, rows, seeds, one, temps, one],
+    }[method]
+    args = _avals(shapes, on_chip)
+    if method != "commit_prefill":  # the two that take the parameters first
+        args.insert(0, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+            jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+        ))
+    pools = (len(args) - len(shapes), len(args) - len(shapes) + 1)
+    compiled = (
+        jax.jit(getattr(model, method), donate_argnums=pools)
+        .lower(*args).compile()
+    )
+    shape = re.escape("f32[" + ",".join(map(str, pool)) + "]")
+    copies = [
+        line.strip()[:120] for line in compiled.as_text().splitlines()
+        if re.search(r"= " + shape + r"\S* copy\(", line)
+    ]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    pool_bytes = 4 * n_layers * pool[1] * pool[2] * kd
+    assert memory.alias_size_in_bytes == 2 * pool_bytes
+    assert memory.temp_size_in_bytes < most_temp
