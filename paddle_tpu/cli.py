@@ -1006,14 +1006,15 @@ def build_serve_session(args: argparse.Namespace, quotas=None):
         engine_stall_timeout_s=args.engine_stall_timeout_s,
     )
     if args.load:
-        from paddle_tpu.serving.model import ServableLM
+        # the checkpoint records its architecture (ServableLM or LoopedLM)
+        from paddle_tpu.serving.looped_lm import load_checkpoint
 
         mesh = None
         if args.tp and args.tp > 1:
             from paddle_tpu.parallel.rules import make_tp_mesh
 
             mesh = make_tp_mesh(args.tp)
-        model, params = ServableLM.load(args.load, mesh=mesh)
+        model, params = load_checkpoint(args.load, mesh=mesh)
         return ServingSession(model, params, **session_kw)
     return make_demo_session(
         vocab=args.vocab, n_layers=args.n_layers,

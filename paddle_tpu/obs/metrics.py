@@ -349,6 +349,33 @@ def observe_pages_recycled(n: int) -> None:
     ).inc(n)
 
 
+def observe_layer_passes(phase: str, n: int) -> None:
+    """`n` token-layer applications ran (tokens x the layers each passed, a
+    looped stack's passes counted each); phase is 'prefill' or 'decode'."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_layer_passes_total",
+        "token-layer applications of the served model, by phase",
+    ).inc(n, phase=phase)
+
+
+def observe_decode_step(slots: int, layer_passes: int) -> None:
+    """One decode step advanced `slots` requests by a token each (one
+    `serve.decode` span), each through `layer_passes` layer applications."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_decode_slot_steps_total",
+        "slots advanced by decode steps: the batch a step's weights are shared over, summed",
+    ).inc(slots)
+    observe_layer_passes("decode", slots * layer_passes)
+
+
+def set_kv_bytes_per_token(n: int) -> None:
+    """What one token holds of the page pool, K and V over every cache layer."""
+    REGISTRY.gauge(
+        "paddle_tpu_serving_kv_bytes_per_token",
+        "bytes of the KV page pool one token occupies",
+    ).set(n)
+
+
 def observe_prefix_hit(pages: int) -> None:
     """An admission aliased `pages` cached prefix pages into a new slot's
     block table (ISSUE 19) — each page is prefill work the request skipped."""

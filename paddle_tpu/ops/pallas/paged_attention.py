@@ -73,8 +73,12 @@ log = logging.getLogger("paddle_tpu")
 NEG_INF = -1e9
 
 _LANES = 128
-# f32 operands through the MXU at full f32 accuracy: the kernel must track
-# the f32 oracle to float tolerance, not to one bf16 pass
+# f32 operands through the MXU at full f32 accuracy: over a float32 pool the
+# kernel must track the f32 oracle to float tolerance, not to one bf16 pass.
+# Over a bfloat16 pool the two products take their operands as the pool holds
+# them (q and the weights rounded to bfloat16, as the oracle rounds them), in
+# ONE pass with float32 accumulation: six passes over upcast pages would leave
+# the MXU, not the page copies, bounding the call
 _PRECISION = jax.lax.Precision.HIGHEST
 
 # VMEM the four gathered tiles may take together (K and V, two buffers
@@ -87,10 +91,10 @@ BLOCK_TOKENS = 512
 
 
 @functools.lru_cache(maxsize=None)
-def _pages_per_block(page_size: int, kd: int, pmax: int) -> int:
+def _pages_per_block(page_size: int, kd: int, pmax: int, itemsize: int = 4) -> int:
     """B, the pages one block gathers: as many as the budget, the token cap
     and the table's width allow. Logged here, once a geometry."""
-    page_bytes = page_size * kd * 4
+    page_bytes = page_size * kd * itemsize
     b = min(
         TILE_BUDGET // (4 * page_bytes), BLOCK_TOKENS // page_size, pmax
     )
@@ -116,7 +120,8 @@ def _paged_decode_kernel(
     sems,      # DMA semaphores [2 (K, V), 2 (buffer)]
     turn_ref,  # SMEM [1]: the buffer this slot's first block was sent to
     q_scr,     # VMEM [H, KD] the query, pre-scaled, block-diagonal: row h
-               # holds head h's hd values in its own lane segment
+               # holds head h's hd values in its own lane segment; in the
+               # pool's type, as the products take it
     m_scr,     # VMEM [H, LANES] running max (lane-replicated)
     l_scr,     # VMEM [H, LANES] running denominator (lane-replicated)
     acc_scr,   # VMEM [H, KD] running probs @ v, every head against ALL lanes
@@ -182,7 +187,11 @@ def _paged_decode_kernel(
     head = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 1)
     own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
-    q_scr[...] = jnp.where(own, q_ref[0].astype(jnp.float32) * scale, 0.0)
+    q_scr[...] = jnp.where(
+        own, q_ref[0].astype(jnp.float32) * scale, 0.0
+    ).astype(q_scr.dtype)
+    # the products' operands are of the pool's type (module docstring)
+    precision = _PRECISION if k_buf.dtype == jnp.float32 else None
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -202,9 +211,9 @@ def _paged_decode_kernel(
         # all heads in one MXU call: q is block-diagonal over the lane
         # segments, so row h of q @ k^T contracts head h's lanes only
         sc = jax.lax.dot_general(
-            q_scr[...], k_buf[buf].astype(jnp.float32),
+            q_scr[...], k_buf[buf],
             (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_PRECISION,
+            preferred_element_type=jnp.float32, precision=precision,
         )  # [H, T]
         # ragged masking: logical token index within THIS slot's sequence
         idx = j * t + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -220,8 +229,8 @@ def _paged_decode_kernel(
         # the flush (H-fold redundant MXU work, in exchange for no
         # in-kernel reshape/transpose of the [T, KD] tile)
         pv = jax.lax.dot_general(
-            probs, v_buf[buf].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_PRECISION,
+            probs.astype(v_buf.dtype), v_buf[buf], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
         )  # [H, KD]
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -243,15 +252,17 @@ def paged_attention_decode(
     block_table: Array,  # [S, P] int32 logical->physical page map
     positions: Array,    # [S] int32 — each slot's current token position
     *,
-    layer: int,
+    layer,               # int, or a traced int32 scalar (a scanned stack)
     scale: float,
     n_heads: int,
 ) -> Array:
     """One decode step of ragged paged attention for all slots over layer
     `layer` of the pool: [S, KD] f32 context, numerically equivalent to the
-    jnp gather oracle in `ServableLM.decode_step` (same masking, f32
+    jnp gather oracle in `PagedLM._paged_attention_local` (same masking, f32
     softmax; the online recurrence reassociates the sum so equality is to
-    float tolerance, argmax/token-exact under greedy decode).
+    float tolerance, argmax/token-exact under greedy decode; over a bfloat16
+    pool, to bfloat16's: the oracle rounds the normalised weights, the
+    recurrence the unnormalised).
 
     The pool rides in whole, in HBM, and `layer` picks the page inside the
     kernel's own copies (`_decode_layer`): slicing `k_pages[layer]` outside would make XLA
@@ -269,12 +280,15 @@ def paged_attention_decode(
             return jnp.pad(x, lanes)
 
         q = widen(q)
-        k_pages = widen(k_pages[layer:layer + 1])
-        v_pages = widen(v_pages[layer:layer + 1])
+        k_pages = widen(jax.lax.dynamic_slice_in_dim(k_pages, layer, 1))
+        v_pages = widen(jax.lax.dynamic_slice_in_dim(v_pages, layer, 1))
         layer = 0
-    b = _pages_per_block(k_pages.shape[2], q.shape[1], block_table.shape[1])
+    b = _pages_per_block(
+        k_pages.shape[2], q.shape[1], block_table.shape[1],
+        k_pages.dtype.itemsize,
+    )
     out = _decode_layer(
-        jnp.asarray([layer], jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
         q[:, None, :], k_pages, v_pages,
         scale=scale, n_heads=n_heads, head_dim=head_dim, pages_per_block=b,
@@ -317,7 +331,7 @@ def _decode_layer(
             pltpu.VMEM((2, b * ps, kd), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((n_heads, kd), jnp.float32),
+            pltpu.VMEM((n_heads, kd), k_pages.dtype),
             pltpu.VMEM((n_heads, _LANES), jnp.float32),
             pltpu.VMEM((n_heads, _LANES), jnp.float32),
             pltpu.VMEM((n_heads, kd), jnp.float32),

@@ -16,7 +16,8 @@ master's line-JSON request-routing plane.
 CLI: `python -m paddle_tpu serve` (README "Serving")."""
 
 from paddle_tpu.serving.kv_cache import PagedKVCache
-from paddle_tpu.serving.model import LMConfig, ServableLM
+from paddle_tpu.serving.looped_lm import LoopedLM, LoopedLMConfig, load_checkpoint
+from paddle_tpu.serving.model import LMConfig, PagedLM, ServableLM
 from paddle_tpu.serving.quota import QuotaExceeded, TenantQuotas
 from paddle_tpu.serving.scheduler import (
     FinishReason,
@@ -34,7 +35,11 @@ from paddle_tpu.serving.router import Router, RouterHandle, RouterServer
 __all__ = [
     "PagedKVCache",
     "LMConfig",
+    "LoopedLM",
+    "LoopedLMConfig",
+    "PagedLM",
     "ServableLM",
+    "load_checkpoint",
     "QuotaExceeded",
     "TenantQuotas",
     "FinishReason",

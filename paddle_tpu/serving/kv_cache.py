@@ -65,6 +65,7 @@ class PagedKVCache:
         max_slots: int,
         max_pages_per_seq: int,
         pool_sharding=None,
+        pool_dtype=None,
         prefix_cache: bool = False,
         prefix_cache_pages: Optional[int] = None,
     ):
@@ -82,6 +83,8 @@ class PagedKVCache:
         # recovery re-init) lands the pools on the same layout. None =
         # single-chip default placement.
         self.pool_sharding = pool_sharding
+        # the pools' element type, the model's to say (None: float32)
+        self.pool_dtype = pool_dtype
         # pop() hands out ascending ids; page 0 is never allocatable
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
@@ -111,14 +114,14 @@ class PagedKVCache:
         self._slot_node: List[int] = [0] * max_slots
 
     # -- device pool --------------------------------------------------------
-    def make_pools(self, dtype=None):
-        """Fresh zeroed (k_pages, v_pages) device arrays, placed on
-        `pool_sharding` when the cache is tensor-parallel."""
+    def make_pools(self):
+        """Fresh zeroed (k_pages, v_pages) device arrays of `pool_dtype`,
+        placed on `pool_sharding` when the cache is tensor-parallel."""
         import jax
         import jax.numpy as jnp
 
         shape = (self.n_layers, self.num_pages, self.page_size, self.kv_dim)
-        dtype = dtype or jnp.float32
+        dtype = self.pool_dtype or jnp.float32
         if self.pool_sharding is None:
             return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
         zeros = jax.jit(

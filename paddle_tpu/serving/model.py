@@ -117,7 +117,18 @@ def _rms(x: Array, scale: Array) -> Array:
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
 
 
-class ServableLM:
+class PagedLM:
+    """What every served decoder shares and no architecture owns: placement
+    on the TP mesh, on-device sampling, the three programs that are a
+    forward plus a commit (`prefill`, `prefill_chunk`, `verify_chunk`), the
+    in-place commit into the page pool and the paged-attention seam. A model
+    derives from it and brings its parameters (`param_logical_axes`,
+    `init_params`, `save` / `load`), its two forwards (`_context_forward`,
+    `_chunk_forward`) and its `decode_step`; its config has `vocab`,
+    `n_heads`, `head_dim`, `max_len`, `bos_id` and `eos_id`. The session asks
+    the model for the cache it needs (`cache_layers`, `cache_width`,
+    `cache_dtype`) and knows nothing else of its shape."""
+
     def __init__(self, cfg: LMConfig, mesh=None, rules=None):
         from paddle_tpu.parallel.rules import ShardingRules
 
@@ -143,37 +154,6 @@ class ServableLM:
         return int(dict(self.mesh.shape)["model"]) if self.mesh is not None else 1
 
     # -- named sharding (ISSUE 12) ------------------------------------------
-    def param_logical_axes(self) -> Dict[str, Tuple[Optional[str], ...]]:
-        """Every parameter's LOGICAL axes — declared once, resolved through
-        the rules table (parallel/rules.py DEFAULT_RULES). Megatron-style TP:
-        qkv/w1 column-parallel (heads/mlp), wo/w2 row-parallel, embed rows +
-        unembed columns over vocab; norms/biases/positions replicated.
-        Built once and cached: shard_params resolves every parameter
-        through here (O(P) placements, not O(P^2) dict rebuilds)."""
-        if self._axes_cache is not None:
-            return self._axes_cache
-        axes: Dict[str, Tuple[Optional[str], ...]] = {
-            "embed": ("vocab", "embed"),
-            "pos": ("length", "embed"),
-            "lnf": ("embed",),
-            "unembed": ("embed", "vocab"),
-        }
-        for i in range(self.cfg.n_layers):
-            axes.update({
-                f"l{i}.wq": ("embed", "heads"),
-                f"l{i}.wk": ("embed", "kv_heads"),
-                f"l{i}.wv": ("embed", "kv_heads"),
-                f"l{i}.wo": ("heads", "embed"),
-                f"l{i}.w1": ("embed", "mlp"),
-                f"l{i}.w2": ("mlp", "embed"),
-                f"l{i}.b1": ("mlp",),
-                f"l{i}.b2": ("embed",),
-                f"l{i}.ln1": ("embed",),
-                f"l{i}.ln2": ("embed",),
-            })
-        self._axes_cache = axes
-        return axes
-
     def param_sharding(self, name: str, ndim: int):
         """One param's NamedSharding through the rules table, or None when
         there is no TP mesh (single-chip: the session device_puts plainly).
@@ -220,62 +200,21 @@ class ServableLM:
             x, self.rules.sharding_for(self.mesh, logical, ndim=jnp.ndim(x))
         )
 
-    # -- params -------------------------------------------------------------
-    def init_params(self, rng: Array) -> Dict[str, Array]:
-        cfg = self.cfg
-        d, v = cfg.d_model, cfg.vocab
-        # per-tensor keys derived by name-stable fold_in so adding a tensor
-        # never reshuffles the others (checkpoint/test determinism)
-        p: Dict[str, Array] = {
-            "embed": 0.1 * jax.random.normal(
-                jax.random.fold_in(rng, 1), (v, d), jnp.float32
-            ),
-            "pos": 0.02 * jax.random.normal(
-                jax.random.fold_in(rng, 2), (cfg.max_len, d), jnp.float32
-            ),
-            "lnf": jnp.ones((d,)),
-            "unembed": 0.1 * jax.random.normal(
-                jax.random.fold_in(rng, 3), (d, v), jnp.float32
-            ),
-        }
-        for i in range(cfg.n_layers):
-            for j, (name, shape) in enumerate((
-                ("wq", (d, d)), ("wk", (d, d)), ("wv", (d, d)), ("wo", (d, d)),
-                ("w1", (d, 4 * d)), ("w2", (4 * d, d)),
-            )):
-                k = jax.random.fold_in(jax.random.fold_in(rng, 1000 + i), j)
-                p[f"l{i}.{name}"] = 0.1 * jax.random.normal(k, shape, jnp.float32)
-            p[f"l{i}.b1"] = jnp.zeros((4 * d,))
-            p[f"l{i}.b2"] = jnp.zeros((d,))
-            p[f"l{i}.ln1"] = jnp.ones((d,))
-            p[f"l{i}.ln2"] = jnp.ones((d,))
-        return p
+    # -- the cache this model needs (the session sizes the pool from it) ----
+    @property
+    def cache_layers(self) -> int:
+        """Layers of the page pool: one for every K/V entry a token leaves
+        (a looped stack leaves one a pass and layer)."""
+        return self.cfg.n_layers
 
-    def save(self, path: str, params: Dict[str, Array]) -> None:
-        np.savez(path, __vocab__=self.cfg.vocab, __n_layers__=self.cfg.n_layers,
-                 __d_model__=self.cfg.d_model, __n_heads__=self.cfg.n_heads,
-                 __max_len__=self.cfg.max_len, __bos__=self.cfg.bos_id,
-                 __eos__=self.cfg.eos_id,
-                 **{k: np.asarray(v) for k, v in params.items()})
+    @property
+    def cache_width(self) -> int:
+        """The pool's minor dimension, kv_heads * head_dim."""
+        return self.cfg.n_heads * self.cfg.head_dim
 
-    @classmethod
-    def load(
-        cls, path: str, mesh=None, rules=None
-    ) -> Tuple["ServableLM", Dict[str, Array]]:
-        """Checkpoints are CANONICAL full arrays (save() materializes every
-        shard), so the same .npz loads onto any layout: single chip, TP=2,
-        TP=4 — the cross-layout contract tests/test_tp_serving.py pins."""
-        with np.load(path) as z:
-            cfg = LMConfig(
-                vocab=int(z["__vocab__"]), n_layers=int(z["__n_layers__"]),
-                d_model=int(z["__d_model__"]), n_heads=int(z["__n_heads__"]),
-                max_len=int(z["__max_len__"]), bos_id=int(z["__bos__"]),
-                eos_id=int(z["__eos__"]),
-            )
-            params = {
-                k: jnp.asarray(z[k]) for k in z.files if not k.startswith("__")
-            }
-        return cls(cfg, mesh=mesh, rules=rules), params
+    @property
+    def cache_dtype(self):
+        return jnp.float32
 
     # -- on-device sampling -------------------------------------------------
     def _sample(
@@ -318,55 +257,7 @@ class ServableLM:
             jnp.any(temps > 0.0), _sampled, lambda _: greedy, operand=None
         )
 
-    # -- shared block body --------------------------------------------------
-    def _mlp(self, params, i: int, x: Array) -> Array:
-        h = _rms(x, params[f"l{i}.ln2"])
-        out = x + (
-            jax.nn.gelu(h @ params[f"l{i}.w1"] + params[f"l{i}.b1"])
-            @ params[f"l{i}.w2"] + params[f"l{i}.b2"]
-        )
-        # TP resharding point: w2 is row-parallel (contraction dim sharded
-        # over 'model'), so the partitioner all-reduces the partial sums
-        # HERE — one collective per layer's MLP, activations replicated out
-        return self._constrain(out)
-
     # -- full-context forward (prefill + the sequential reference path) -----
-    def _context_forward(self, params, tokens: Array) -> Tuple[Array, Array, Array]:
-        """The ONE causal-forward implementation: padded [B, T] tokens ->
-        (logits [B, T, V], kc, vc [L, B, T, kv_dim]). Both the sequential
-        reference path (forward_logits) and the serving prefill call this,
-        so the attention math the equivalence tests compare against cannot
-        drift between them. Unused outputs are DCE'd under jit."""
-        cfg = self.cfg
-        b, t = tokens.shape
-        h_, hd = cfg.n_heads, cfg.head_dim
-        x = self._constrain(params["embed"][tokens] + params["pos"][:t][None])
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        kcs, vcs = [], []
-        for i in range(cfg.n_layers):
-            h = _rms(x, params[f"l{i}.ln1"])
-            q = (h @ params[f"l{i}.wq"]).reshape(b, t, h_, hd)
-            kf = h @ params[f"l{i}.wk"]
-            vf = h @ params[f"l{i}.wv"]
-            kcs.append(kf)
-            vcs.append(vf)
-            k = kf.reshape(b, t, h_, hd)
-            v = vf.reshape(b, t, h_, hd)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self.scale
-            s = jnp.where(causal[None, None], s, NEG_INF)
-            w = jax.nn.softmax(s.astype(jnp.float32), -1).astype(x.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(b, t, -1)
-            # TP resharding point: wo is row-parallel — all-reduce here
-            x = self._constrain(x + ctx @ params[f"l{i}.wo"])
-            x = self._mlp(params, i, x)
-        # the unembed is column-parallel (vocab sharded): constraining the
-        # logits REPLICATED places one all-gather here, so sampling below is
-        # collective-free and bitwise the single-chip math
-        logits = self._constrain(_rms(x, params["lnf"]) @ params["unembed"])
-        kc = self._constrain(jnp.stack(kcs), None, None, None, "kv_heads")
-        vc = self._constrain(jnp.stack(vcs), None, None, None, "kv_heads")
-        return logits, kc, vc
-
     def forward_logits(self, params, tokens: Array) -> Array:
         """Causal forward over padded [B, T] prompts -> logits [B, T, V].
         Padding positions produce garbage logits but cannot leak into valid
@@ -442,68 +333,6 @@ class ServableLM:
             k_pages, v_pages, kc, vc, lengths, block_rows, starts,
         )
         return k_pages, v_pages, tok
-
-    def _chunk_forward(
-        self,
-        params,
-        k_pages: Array,      # [L, NP, PS, KD]
-        v_pages: Array,
-        tokens: Array,       # [1, C] int32
-        starts: Array,       # [1] int32 — position of tokens[:, 0]
-        block_rows: Array,   # [1, max_pages_per_seq] int32
-    ) -> Tuple[Array, Array, Array]:
-        """The ONE chunk-shaped forward: attention = (already-committed
-        pages, masked to positions < start) ++ (causal within the chunk).
-        Shared by `prefill_chunk` (long-prompt prefill) and `verify_chunk`
-        (speculative-decode scoring, ISSUE 16), so the two cannot drift —
-        the verify call literally IS a prefill-chunk forward over
-        [last_token, draft_1..K]. Returns (logits [1, C, V], kc, vc
-        [L, 1, C, KD]); the pools are only READ here — each caller commits
-        through `commit_prefill` itself."""
-        cfg = self.cfg
-        b, c = tokens.shape
-        h_, hd = cfg.n_heads, cfg.head_dim
-        ps = k_pages.shape[2]
-        pos = starts[:, None] + jnp.arange(c)[None, :]          # [1, C]
-        # padded tail may run past max_len; clamp the INDEX only (those
-        # positions are causally invisible to every valid one)
-        x = self._constrain(
-            params["embed"][tokens]
-            + params["pos"][jnp.minimum(pos, cfg.max_len - 1)]
-        )
-        t_ctx = block_rows.shape[1] * ps
-        ctx_idx = jnp.arange(t_ctx)
-        # committed-context mask: this chunk sees pages strictly before it
-        past = ctx_idx[None, None, :] < starts[:, None, None]   # [1, 1, T_ctx]
-        causal = jnp.tril(jnp.ones((c, c), bool))
-        kcs, vcs = [], []
-        for i in range(cfg.n_layers):
-            h = _rms(x, params[f"l{i}.ln1"])
-            q = (h @ params[f"l{i}.wq"]).reshape(b, c, h_, hd)
-            kf = h @ params[f"l{i}.wk"]
-            vf = h @ params[f"l{i}.wv"]
-            kcs.append(kf)
-            vcs.append(vf)
-            k_self = kf.reshape(b, c, h_, hd)
-            v_self = vf.reshape(b, c, h_, hd)
-            k_past = k_pages[i][block_rows].reshape(b, t_ctx, h_, hd)
-            v_past = v_pages[i][block_rows].reshape(b, t_ctx, h_, hd)
-            sp = jnp.einsum("bqhd,bkhd->bhqk", q, k_past) * self.scale
-            sp = jnp.where(past[:, None], sp, NEG_INF)
-            ss = jnp.einsum("bqhd,bkhd->bhqk", q, k_self) * self.scale
-            ss = jnp.where(causal[None, None], ss, NEG_INF)
-            s_all = jnp.concatenate([sp, ss], -1)               # [1,H,C,T+C]
-            w = jax.nn.softmax(s_all.astype(jnp.float32), -1).astype(x.dtype)
-            ctx = (
-                jnp.einsum("bhqk,bkhd->bqhd", w[..., :t_ctx], v_past)
-                + jnp.einsum("bhqk,bkhd->bqhd", w[..., t_ctx:], v_self)
-            ).reshape(b, c, -1)
-            # TP resharding point: row-parallel wo all-reduces here
-            x = self._constrain(x + ctx @ params[f"l{i}.wo"])
-            x = self._mlp(params, i, x)
-        # replicated logits: the one all-gather, sampling collective-free
-        logits = self._constrain(_rms(x, params["lnf"]) @ params["unembed"])
-        return logits, jnp.stack(kcs), jnp.stack(vcs)
 
     # -- speculative decoding (ISSUE 16) ------------------------------------
     def verify_chunk(
@@ -610,7 +439,7 @@ class ServableLM:
             self._constrain(v_pages, *POOL_LOGICAL_AXES),
         )
 
-    # -- the ONE decode executable ------------------------------------------
+    # -- the paged-attention seam ------------------------------------------
     def _paged_attention_local(
         self,
         q: Array,            # [S, KD_local] — this shard's head slice
@@ -618,7 +447,7 @@ class ServableLM:
         v_pages: Array,
         block_table: Array,  # [S, P]
         positions: Array,    # [S]
-        layer: int,
+        layer,               # int, or a traced scalar inside a scanned stack
         n_heads: int,
     ) -> Array:
         """Ragged paged attention over `n_heads` heads of layer `layer` (the
@@ -653,10 +482,15 @@ class ServableLM:
         v_seq = v_pages[layer][block_table].reshape(s, -1, h_, hd)
         ctx_idx = jnp.arange(block_table.shape[1] * ps)
         att_mask = ctx_idx[None, :] <= positions[:, None]  # [S, T_ctx]
-        sc = jnp.einsum("shd,sthd->sht", qh, k_seq) * self.scale
+        # float32 accumulation whatever the pool holds (a no-op on float32)
+        sc = jnp.einsum(
+            "shd,sthd->sht", qh, k_seq, preferred_element_type=jnp.float32
+        ) * self.scale
         sc = jnp.where(att_mask[:, None, :], sc, NEG_INF)
         w = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(q.dtype)
-        return jnp.einsum("sht,sthd->shd", w, v_seq).reshape(s, -1)
+        return jnp.einsum(
+            "sht,sthd->shd", w, v_seq, preferred_element_type=jnp.float32
+        ).astype(q.dtype).reshape(s, -1)
 
     def _paged_attention(
         self,
@@ -701,6 +535,213 @@ class ServableLM:
             check_vma=False,
         )(q, k_pages, v_pages, block_table, positions)
 
+
+class ServableLM(PagedLM):
+    """The first served architecture (module docstring): learned positions,
+    as many K/V heads as query heads, a GELU MLP of 4 * d, float32, its
+    layers unrolled."""
+
+    # -- named sharding (ISSUE 12) ------------------------------------------
+    def param_logical_axes(self) -> Dict[str, Tuple[Optional[str], ...]]:
+        """Every parameter's LOGICAL axes — declared once, resolved through
+        the rules table (parallel/rules.py DEFAULT_RULES). Megatron-style TP:
+        qkv/w1 column-parallel (heads/mlp), wo/w2 row-parallel, embed rows +
+        unembed columns over vocab; norms/biases/positions replicated.
+        Built once and cached: shard_params resolves every parameter
+        through here (O(P) placements, not O(P^2) dict rebuilds)."""
+        if self._axes_cache is not None:
+            return self._axes_cache
+        axes: Dict[str, Tuple[Optional[str], ...]] = {
+            "embed": ("vocab", "embed"),
+            "pos": ("length", "embed"),
+            "lnf": ("embed",),
+            "unembed": ("embed", "vocab"),
+        }
+        for i in range(self.cfg.n_layers):
+            axes.update({
+                f"l{i}.wq": ("embed", "heads"),
+                f"l{i}.wk": ("embed", "kv_heads"),
+                f"l{i}.wv": ("embed", "kv_heads"),
+                f"l{i}.wo": ("heads", "embed"),
+                f"l{i}.w1": ("embed", "mlp"),
+                f"l{i}.w2": ("mlp", "embed"),
+                f"l{i}.b1": ("mlp",),
+                f"l{i}.b2": ("embed",),
+                f"l{i}.ln1": ("embed",),
+                f"l{i}.ln2": ("embed",),
+            })
+        self._axes_cache = axes
+        return axes
+
+    # -- params -------------------------------------------------------------
+    def init_params(self, rng: Array) -> Dict[str, Array]:
+        cfg = self.cfg
+        d, v = cfg.d_model, cfg.vocab
+        # per-tensor keys derived by name-stable fold_in so adding a tensor
+        # never reshuffles the others (checkpoint/test determinism)
+        p: Dict[str, Array] = {
+            "embed": 0.1 * jax.random.normal(
+                jax.random.fold_in(rng, 1), (v, d), jnp.float32
+            ),
+            "pos": 0.02 * jax.random.normal(
+                jax.random.fold_in(rng, 2), (cfg.max_len, d), jnp.float32
+            ),
+            "lnf": jnp.ones((d,)),
+            "unembed": 0.1 * jax.random.normal(
+                jax.random.fold_in(rng, 3), (d, v), jnp.float32
+            ),
+        }
+        for i in range(cfg.n_layers):
+            for j, (name, shape) in enumerate((
+                ("wq", (d, d)), ("wk", (d, d)), ("wv", (d, d)), ("wo", (d, d)),
+                ("w1", (d, 4 * d)), ("w2", (4 * d, d)),
+            )):
+                k = jax.random.fold_in(jax.random.fold_in(rng, 1000 + i), j)
+                p[f"l{i}.{name}"] = 0.1 * jax.random.normal(k, shape, jnp.float32)
+            p[f"l{i}.b1"] = jnp.zeros((4 * d,))
+            p[f"l{i}.b2"] = jnp.zeros((d,))
+            p[f"l{i}.ln1"] = jnp.ones((d,))
+            p[f"l{i}.ln2"] = jnp.ones((d,))
+        return p
+
+    def save(self, path: str, params: Dict[str, Array]) -> None:
+        np.savez(path, __vocab__=self.cfg.vocab, __n_layers__=self.cfg.n_layers,
+                 __d_model__=self.cfg.d_model, __n_heads__=self.cfg.n_heads,
+                 __max_len__=self.cfg.max_len, __bos__=self.cfg.bos_id,
+                 __eos__=self.cfg.eos_id,
+                 **{k: np.asarray(v) for k, v in params.items()})
+
+    @classmethod
+    def load(
+        cls, path: str, mesh=None, rules=None
+    ) -> Tuple["ServableLM", Dict[str, Array]]:
+        """Checkpoints are CANONICAL full arrays (save() materializes every
+        shard), so the same .npz loads onto any layout: single chip, TP=2,
+        TP=4 — the cross-layout contract tests/test_tp_serving.py pins."""
+        with np.load(path) as z:
+            cfg = LMConfig(
+                vocab=int(z["__vocab__"]), n_layers=int(z["__n_layers__"]),
+                d_model=int(z["__d_model__"]), n_heads=int(z["__n_heads__"]),
+                max_len=int(z["__max_len__"]), bos_id=int(z["__bos__"]),
+                eos_id=int(z["__eos__"]),
+            )
+            params = {
+                k: jnp.asarray(z[k]) for k in z.files if not k.startswith("__")
+            }
+        return cls(cfg, mesh=mesh, rules=rules), params
+
+    # -- shared block body --------------------------------------------------
+    def _mlp(self, params, i: int, x: Array) -> Array:
+        h = _rms(x, params[f"l{i}.ln2"])
+        out = x + (
+            jax.nn.gelu(h @ params[f"l{i}.w1"] + params[f"l{i}.b1"])
+            @ params[f"l{i}.w2"] + params[f"l{i}.b2"]
+        )
+        # TP resharding point: w2 is row-parallel (contraction dim sharded
+        # over 'model'), so the partitioner all-reduces the partial sums
+        # HERE — one collective per layer's MLP, activations replicated out
+        return self._constrain(out)
+
+    # -- the two forwards ---------------------------------------------------
+    def _context_forward(self, params, tokens: Array) -> Tuple[Array, Array, Array]:
+        """The ONE causal-forward implementation: padded [B, T] tokens ->
+        (logits [B, T, V], kc, vc [L, B, T, kv_dim]). Both the sequential
+        reference path (forward_logits) and the serving prefill call this,
+        so the attention math the equivalence tests compare against cannot
+        drift between them. Unused outputs are DCE'd under jit."""
+        cfg = self.cfg
+        b, t = tokens.shape
+        h_, hd = cfg.n_heads, cfg.head_dim
+        x = self._constrain(params["embed"][tokens] + params["pos"][:t][None])
+        causal = jnp.tril(jnp.ones((t, t), bool))
+        kcs, vcs = [], []
+        for i in range(cfg.n_layers):
+            h = _rms(x, params[f"l{i}.ln1"])
+            q = (h @ params[f"l{i}.wq"]).reshape(b, t, h_, hd)
+            kf = h @ params[f"l{i}.wk"]
+            vf = h @ params[f"l{i}.wv"]
+            kcs.append(kf)
+            vcs.append(vf)
+            k = kf.reshape(b, t, h_, hd)
+            v = vf.reshape(b, t, h_, hd)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self.scale
+            s = jnp.where(causal[None, None], s, NEG_INF)
+            w = jax.nn.softmax(s.astype(jnp.float32), -1).astype(x.dtype)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", w, v).reshape(b, t, -1)
+            # TP resharding point: wo is row-parallel — all-reduce here
+            x = self._constrain(x + ctx @ params[f"l{i}.wo"])
+            x = self._mlp(params, i, x)
+        # the unembed is column-parallel (vocab sharded): constraining the
+        # logits REPLICATED places one all-gather here, so sampling below is
+        # collective-free and bitwise the single-chip math
+        logits = self._constrain(_rms(x, params["lnf"]) @ params["unembed"])
+        kc = self._constrain(jnp.stack(kcs), None, None, None, "kv_heads")
+        vc = self._constrain(jnp.stack(vcs), None, None, None, "kv_heads")
+        return logits, kc, vc
+
+    def _chunk_forward(
+        self,
+        params,
+        k_pages: Array,      # [L, NP, PS, KD]
+        v_pages: Array,
+        tokens: Array,       # [1, C] int32
+        starts: Array,       # [1] int32 — position of tokens[:, 0]
+        block_rows: Array,   # [1, max_pages_per_seq] int32
+    ) -> Tuple[Array, Array, Array]:
+        """The ONE chunk-shaped forward: attention = (already-committed
+        pages, masked to positions < start) ++ (causal within the chunk).
+        Shared by `prefill_chunk` (long-prompt prefill) and `verify_chunk`
+        (speculative-decode scoring, ISSUE 16), so the two cannot drift —
+        the verify call literally IS a prefill-chunk forward over
+        [last_token, draft_1..K]. Returns (logits [1, C, V], kc, vc
+        [L, 1, C, KD]); the pools are only READ here — each caller commits
+        through `commit_prefill` itself."""
+        cfg = self.cfg
+        b, c = tokens.shape
+        h_, hd = cfg.n_heads, cfg.head_dim
+        ps = k_pages.shape[2]
+        pos = starts[:, None] + jnp.arange(c)[None, :]          # [1, C]
+        # padded tail may run past max_len; clamp the INDEX only (those
+        # positions are causally invisible to every valid one)
+        x = self._constrain(
+            params["embed"][tokens]
+            + params["pos"][jnp.minimum(pos, cfg.max_len - 1)]
+        )
+        t_ctx = block_rows.shape[1] * ps
+        ctx_idx = jnp.arange(t_ctx)
+        # committed-context mask: this chunk sees pages strictly before it
+        past = ctx_idx[None, None, :] < starts[:, None, None]   # [1, 1, T_ctx]
+        causal = jnp.tril(jnp.ones((c, c), bool))
+        kcs, vcs = [], []
+        for i in range(cfg.n_layers):
+            h = _rms(x, params[f"l{i}.ln1"])
+            q = (h @ params[f"l{i}.wq"]).reshape(b, c, h_, hd)
+            kf = h @ params[f"l{i}.wk"]
+            vf = h @ params[f"l{i}.wv"]
+            kcs.append(kf)
+            vcs.append(vf)
+            k_self = kf.reshape(b, c, h_, hd)
+            v_self = vf.reshape(b, c, h_, hd)
+            k_past = k_pages[i][block_rows].reshape(b, t_ctx, h_, hd)
+            v_past = v_pages[i][block_rows].reshape(b, t_ctx, h_, hd)
+            sp = jnp.einsum("bqhd,bkhd->bhqk", q, k_past) * self.scale
+            sp = jnp.where(past[:, None], sp, NEG_INF)
+            ss = jnp.einsum("bqhd,bkhd->bhqk", q, k_self) * self.scale
+            ss = jnp.where(causal[None, None], ss, NEG_INF)
+            s_all = jnp.concatenate([sp, ss], -1)               # [1,H,C,T+C]
+            w = jax.nn.softmax(s_all.astype(jnp.float32), -1).astype(x.dtype)
+            ctx = (
+                jnp.einsum("bhqk,bkhd->bqhd", w[..., :t_ctx], v_past)
+                + jnp.einsum("bhqk,bkhd->bqhd", w[..., t_ctx:], v_self)
+            ).reshape(b, c, -1)
+            # TP resharding point: row-parallel wo all-reduces here
+            x = self._constrain(x + ctx @ params[f"l{i}.wo"])
+            x = self._mlp(params, i, x)
+        # replicated logits: the one all-gather, sampling collective-free
+        logits = self._constrain(_rms(x, params["lnf"]) @ params["unembed"])
+        return logits, jnp.stack(kcs), jnp.stack(vcs)
+
+    # -- the ONE decode executable ------------------------------------------
     def decode_step(
         self,
         params,

@@ -63,7 +63,7 @@ from paddle_tpu.core import stats
 from paddle_tpu.obs import metrics as obs_metrics
 from paddle_tpu.obs import trace
 from paddle_tpu.serving.kv_cache import PagedKVCache
-from paddle_tpu.serving.model import LMConfig, ServableLM
+from paddle_tpu.serving.model import LMConfig, PagedLM, ServableLM
 from paddle_tpu.serving.quota import TenantQuotas
 from paddle_tpu.serving.scheduler import RequestHandle, Scheduler
 
@@ -90,7 +90,7 @@ def _bucket_for(buckets: Sequence[int], n: int) -> int:
 class ServingSession:
     def __init__(
         self,
-        model: ServableLM,
+        model: PagedLM,
         params: Dict,
         *,
         max_slots: int = 8,
@@ -159,9 +159,13 @@ class ServingSession:
         if num_pages is None:
             # worst case every slot at full context, plus the dump page
             num_pages = max_slots * pages_per_seq + 1
+        # the MODEL declares the cache it needs: how many K/V entries a token
+        # leaves (a looped stack leaves one a pass and layer), how wide, and
+        # in what type
         self.cache = PagedKVCache(
-            n_layers=self.cfg.n_layers,
-            kv_dim=self.cfg.d_model,
+            n_layers=model.cache_layers,
+            kv_dim=model.cache_width,
+            pool_dtype=model.cache_dtype,
             num_pages=num_pages,
             page_size=page_size,
             max_slots=max_slots,
@@ -178,6 +182,11 @@ class ServingSession:
             speculate_k=self.speculate_k,
         )
         self.k_pages, self.v_pages = self.cache.make_pools()
+        # layer applications a token costs (every one leaves a cache entry)
+        self.layer_passes = int(model.cache_layers)
+        obs_metrics.set_kv_bytes_per_token(
+            2 * self.layer_passes * model.cache_width * self.k_pages.dtype.itemsize
+        )
 
         # warmup detection (ISSUE 17): each wrapped body runs ONLY while jax
         # traces it — exactly once per new input signature per executable,
@@ -472,6 +481,9 @@ class ServingSession:
             # TTFT completes here — span under the request trace + histogram
             self._observe_ttft(h, ctx)
             SERVING_EVENTS.incr("serving_prefills")
+            obs_metrics.observe_layer_passes(
+                "prefill", len(act.prompt) * self.layer_passes
+            )
             reason = act.finished(self.cfg.eos_id)
             if reason is not None:
                 self.scheduler.retire(slot, reason)
@@ -534,6 +546,9 @@ class ServingSession:
                                      act.prefill_pos)
             self.prefill_chunks_committed += 1
             SERVING_EVENTS.incr("serving_prefill_chunks")
+            obs_metrics.observe_layer_passes(
+                "prefill", (act.prefill_pos - start) * self.layer_passes
+            )
             if not act.prefilling:
                 # sync-ok: one host fetch per REQUEST (not per chunk, not per
                 # step) — the FINAL chunk's sampled first token, which the
@@ -645,6 +660,9 @@ class ServingSession:
                 # fetch); pages stay donated through, logits never land
                 out = np.asarray(sampled)
             act.engine_steps += 1
+            obs_metrics.observe_layer_passes(
+                "decode", (k + 1) * self.layer_passes
+            )
             limit = min(len(draft), remaining - 1)
             n_match = 0
             while n_match < limit and int(out[n_match]) == draft[n_match]:
@@ -724,7 +742,12 @@ class ServingSession:
         # span-ok: ring-buffer write only, constant name, int attr — no file
         # I/O or string formatting on the decode hot path; a no-op truth
         # test when PADDLE_TPU_TRACE is off (tests/test_lint_hotloop.py)
-        with trace.span("serving.decode_step", active=len(active)):
+        # span-ok: the flight recorder's one ring write a decode step, int
+        # attrs: the dispatch and the fetch, and the batch the step's weight
+        # traffic is shared over (perfbench reads `slots`)
+        with trace.flight(
+            "serve.decode", slots=len(active), layer_passes=self.layer_passes
+        ), trace.span("serving.decode_step", active=len(active)):
             self.k_pages, self.v_pages, next_tok = self._decode(
                 self.params, self.k_pages, self.v_pages,
                 tokens, positions, act_mask, bt, seeds, steps, temps, top_ks,
@@ -736,6 +759,7 @@ class ServingSession:
             toks = np.asarray(next_tok)
         self.decode_steps += 1
         SERVING_EVENTS.incr("serving_decode_steps")
+        obs_metrics.observe_decode_step(len(active), self.layer_passes)
         for slot, act in active:
             act.append(toks[slot])
             act.engine_steps += 1

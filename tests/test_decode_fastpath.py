@@ -222,6 +222,10 @@ def test_pages_per_block_comes_from_the_shapes(caplog):
         assert paged_attention._pages_per_block(16, 512, 128) == 32
         assert paged_attention._pages_per_block(16, 128, 8) == 8
         assert paged_attention._pages_per_block(16, 2048, 8) == 8
+    # a bfloat16 pool's page is half the bytes: ouro_2_6b's 80-page table
+    # walks 16 pages a block where a float32 pool's walks 8
+    assert paged_attention._pages_per_block(16, 2048, 80, 2) == 16
+    assert paged_attention._pages_per_block(16, 2048, 80, 4) == 8
     lines = [
         r.getMessage() for r in caplog.records
         if "pages a block" in r.getMessage()

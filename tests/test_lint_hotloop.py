@@ -105,9 +105,12 @@ SPAN_TAG = "span-ok"
 # (PIPELINE_SPAN_SITES).
 SPAN_HOT_LOOPS = [
     (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), 5),
+    # ISSUE 33: `serve.decode`, a flight span around the decode dispatch and
+    # its one fetch (one ring write a decode step, two int attrs), beside
+    # the gated `serving.decode_step` it wraps: four sites.
     (SERVING_PY, "ServingSession",
      ("_decode_once", "step", "_prefill_chunks", "_speculate",
-      "_notify_streams"), 3),
+      "_notify_streams"), 4),
     (ROUTER_PY, "Router",
      ("_forward", "_failover_requests", "_reap_once", "_pump_once"), 3),
 ]

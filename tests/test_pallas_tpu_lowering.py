@@ -240,3 +240,52 @@ def test_page_pool_is_written_in_place(program, on_chip):
     pool_bytes = 4 * n_layers * pool[1] * pool[2] * kd
     assert memory.alias_size_in_bytes == 2 * pool_bytes
     assert memory.temp_size_in_bytes < most_temp
+
+
+# -- the looped decoder's decode step, at Ouro-2.6B's size -------------------
+
+def test_the_looped_decode_step_is_one_layer_body_scanned_in_place(on_chip, monkeypatch):
+    """LoopedLM.decode_step at ouro_2_6b's shapes (48 stacked layers run 4
+    times, bfloat16, a pool 192 layers deep, 16 slots) compiled for the
+    described v5e: ONE Mosaic call serves the 192 cache layers (the layer a
+    traced scalar over a bfloat16 pool), no instruction of the pool's or of
+    a stacked weight's shape is a `copy` (the scan reads each layer's slice
+    where it lies: no second pass over 5 GB of weights a step), both pools
+    are the outputs' buffers and the temporaries are megabytes."""
+    from paddle_tpu.serving.looped_lm import LoopedLM, LoopedLMConfig
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
+    model = LoopedLM(LoopedLMConfig(
+        vocab=49152, n_layers=48, d_model=2048, n_heads=16, head_dim=128,
+        d_ff=5632, ut_steps=4, max_len=1280,
+    ))
+    slots, pages = 16, 300
+    pool = (model.cache_layers, pages, 16, model.cache_width)
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    params = jax.tree.map(
+        lambda a: aval(a.shape, a.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+    )
+    s = (slots,)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
+        params, aval(pool, jnp.bfloat16), aval(pool, jnp.bfloat16),
+        aval(s, jnp.int32), aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval((slots, 80), jnp.int32), aval(s, jnp.uint32), aval(s, jnp.int32),
+        aval(s, jnp.float32), aval(s, jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    big = [pool] + [tuple(a.shape) for a in params.values() if len(a.shape) == 3]
+    copies = [
+        line.strip()[:120] for line in text.splitlines()
+        if any(re.search(r"= bf16\[" + ",".join(map(str, shape)) + r"\]\S* copy\(", line)
+               for shape in big)
+    ]
+    assert not copies, copies
+    memory = compiled.memory_analysis()
+    pool_bytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
+    assert memory.alias_size_in_bytes == 2 * pool_bytes
+    assert memory.temp_size_in_bytes < 64e6
