@@ -349,6 +349,33 @@ def observe_pages_recycled(n: int) -> None:
     ).inc(n)
 
 
+def observe_preemption() -> None:
+    """A dry page pool took a slot back: the request admitted last returned
+    to the front of the queue with its tokens (ISSUE 34)."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_preemptions_total",
+        "requests preempted because a running request needed a KV page and none was free",
+    ).inc()
+
+
+def observe_replayed_tokens(n: int) -> None:
+    """`n` decode-lane token-steps rebuilt the K/V of preempted requests'
+    known tokens: device work that produced no new token."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_replayed_tokens_total",
+        "token-steps spent rebuilding the K/V of preempted requests",
+    ).inc(n)
+
+
+def set_kv_pages_in_use(n: int) -> None:
+    """Pages of the KV pool some slot or the prefix index holds right now,
+    as of the last engine step."""
+    REGISTRY.gauge(
+        "paddle_tpu_serving_kv_pages_in_use",
+        "KV pool pages held by slots or the prefix index at the last engine step",
+    ).set(n)
+
+
 def observe_layer_passes(phase: str, n: int) -> None:
     """`n` token-layer applications ran (tokens x the layers each passed, a
     looped stack's passes counted each); phase is 'prefill' or 'decode'."""

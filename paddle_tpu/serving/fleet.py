@@ -48,7 +48,10 @@ log = logging.getLogger("paddle_tpu.serving.fleet")
 # ServingSession.stats()): everything the router's least-loaded choice,
 # fleet-wide shed AND the autoscaler's pressure signals (cumulative shed /
 # deadline-miss counters, ISSUE 17) reason about, nothing more — heartbeats
-# stay small and the controller reads the whole fleet with zero new RPCs
+# stay small and the controller reads the whole fleet with zero new RPCs.
+# `free_pages` is pages free NOW (ISSUE 34): a replica hands pages out as
+# tokens are written, so it no longer counts what live requests will still
+# ask for; no routing rule reads it differently for that.
 LOAD_KEYS = (
     "queue_depth", "active_slots", "max_slots", "free_pages",
     "estimated_queue_wait_s", "engine_restarts", "decode_steps",

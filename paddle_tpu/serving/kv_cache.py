@@ -15,12 +15,19 @@ long each sequence is are pure *data*, never *shape*. That is what lets a
 mixed-age, mixed-length batch share a single executable with zero recompiles
 (asserted via stats.RecompileStats in the serving session).
 
-Allocation is a host-side free list over REFCOUNTED pages (ISSUE 19). A
-request reserves ceil((prompt_len + max_new_tokens) / page_size) pages at
-admission — worst case up front, so a running sequence can never hit page
-exhaustion mid-flight (admission control is the only place that says no).
-Without the prefix cache every page has refcount 1 and the arithmetic is
-bitwise the old free-list's.
+Allocation is a host-side free list over REFCOUNTED pages (ISSUE 19), and
+pages are handed out AS TOKENS ARE WRITTEN (ISSUE 34): a slot holds the pages
+its written tokens need plus the page its next write needs, and nothing more.
+Admission gives a slot its prompt's pages (`reserve`); before every decode
+step, and before a verify round's K+1 positions, the scheduler grows each
+writing slot by the page its write crosses into (`grow`), and `trim` gives
+back what a rejected draft leaves over. So `free_pages` counts pages free NOW,
+not pages unpromised, and a running sequence CAN find the pool dry: `grow`
+then returns False and the scheduler preempts the request admitted last
+(scheduler.Scheduler.grow), whose pages come back here through `release`.
+`can_admit` is the one admission predicate: the pages of what the request has
+to (re)write plus one, and one free page left for every slot already live.
+Without the prefix cache every page has refcount 1.
 
 With `prefix_cache=True` the shared-prefix index (prefix_cache.py) rides on
 top: reserve() first walks the tenant's chain and ALIASES every matching
@@ -151,15 +158,46 @@ class PagedKVCache:
             return True
         return False
 
-    def can_reserve(self, total_len: int) -> bool:
-        n = self.pages_needed(total_len)
+    def _available(self) -> int:
         avail = len(self._free)
         if self.prefix is not None:
             # unreferenced cached pages are reclaimable on demand (reserve
-            # evicts LRU under pressure), so admission counts them as free
+            # and grow evict LRU under pressure), so they count as free
             with self._prefix_lock:
                 avail += self.prefix.evictable(self._refcount)
-        return n <= self.max_pages_per_seq and n <= avail
+        return avail
+
+    def can_admit(self, written_len: int, total_len: int,
+                  live_slots: int) -> bool:
+        """The admission predicate: can the pool take a request that has
+        `written_len` tokens to write before it decodes on (its prompt; for
+        a preempted request its prompt and what it had generated) and
+        `total_len` in its whole life? It needs the pages of `written_len`
+        plus one (never more than `total_len`'s, so a request that fits the
+        pool alone is admitted into an empty one), and `live_slots` pages
+        must stay free besides: every slot already holding a request crosses
+        a page boundary within `page_size` steps. Aliased prefix pages are
+        counted as needed AND, while unreferenced, as available."""
+        whole = self.pages_needed(total_len)
+        n = min(self.pages_needed(written_len) + 1, whole)
+        return (whole <= self.max_pages_per_seq
+                and n + live_slots <= self._available())
+
+    def _evict_for(self, need: int) -> None:
+        """Under pool pressure, LRU-evict unreferenced cached prefix pages
+        until `need` pages are free or the index has nothing left to give."""
+        if self.prefix is None or need <= len(self._free):
+            return
+        evicted = 0
+        with self._prefix_lock:
+            while need > len(self._free):
+                page = self.prefix.evict_lru(self._refcount)
+                if page is None:
+                    break
+                self._decref(page)  # the index's own reference
+                evicted += 1
+        if evicted:
+            obs_metrics.observe_prefix_evictions(evicted)
 
     # -- reserve / release --------------------------------------------------
     def reserve(
@@ -169,9 +207,10 @@ class PagedKVCache:
         tenant: str = "default",
         prompt: Optional[Sequence[int]] = None,
     ) -> List[int]:
-        """Reserve pages covering `total_len` tokens for `slot`; returns the
-        physical page ids. Raises if the slot is occupied or pages are short —
-        callers gate on can_reserve (admission control).
+        """Give the empty `slot` pages covering its first `total_len` tokens
+        (admission passes the prompt's length; `grow` adds the rest as they
+        are written); returns the physical page ids. Raises if the slot is
+        occupied or pages are short — callers gate on can_admit.
 
         With the prefix cache enabled and `prompt` given, the leading pages
         come ALIASED from the tenant's chain (read-only, +1 ref each) and
@@ -202,35 +241,24 @@ class PagedKVCache:
                 obs_metrics.observe_prefix_hit(len(matched))
             if cow:
                 obs_metrics.observe_prefix_cow(cow)
-        need_fresh = n - len(matched)
-        if need_fresh > len(self._free) and self.prefix is not None:
-            evicted = 0
-            with self._prefix_lock:
-                while need_fresh > len(self._free):
-                    page = self.prefix.evict_lru(self._refcount)
-                    if page is None:
-                        break
-                    self._decref(page)  # the index's own reference
-                    evicted += 1
-            if evicted:
-                obs_metrics.observe_prefix_evictions(evicted)
-        if need_fresh > len(self._free):
+        # the aliased prefix first, then the uncached suffix as `grow` gives
+        # any page; the aliases (ref >= 2) are invisible to its eviction
+        self._slot_pages[slot] = list(matched)
+        self._table[slot, :] = 0
+        self._table[slot, : len(matched)] = matched
+        if not self.grow(slot, total_len):
+            self._slot_pages[slot] = []
+            self._table[slot, :] = 0
             for p in matched:  # roll the aliases back — nothing reserved
                 self._decref(p)
             raise RuntimeError(
-                f"KV pool exhausted: need {need_fresh} pages, "
+                f"KV pool exhausted: need {n - len(matched)} pages, "
                 f"{len(self._free)} free"
             )
-        fresh = [self._free.pop() for _ in range(need_fresh)]
-        for p in fresh:
-            self._refcount[p] = 1
-        pages = matched + fresh
-        self._slot_pages[slot] = pages
+        pages = self._slot_pages[slot]
         self._slot_hit[slot] = len(matched) * self.page_size
         self._slot_reg[slot] = len(matched)
         self._slot_node[slot] = node
-        self._table[slot, :] = 0
-        self._table[slot, : len(pages)] = pages
         return pages
 
     def hit_tokens(self, slot: int) -> int:
@@ -291,13 +319,39 @@ class PagedKVCache:
             evicted += 1
         return evicted
 
+    def grow(self, slot: int, total_len: int) -> bool:
+        """Extend the slot's pages to cover `total_len` tokens — the pages a
+        write about to happen lands in. All or nothing: False, with nothing
+        changed, when the pool is dry even after the prefix index has given
+        up what it can evict; the caller (Scheduler.grow) then preempts a
+        request and asks again. A few host ints a step: no clock, no device
+        work."""
+        pages = self._slot_pages[slot]
+        have = len(pages)
+        need = self.pages_needed(total_len) - have
+        if need <= 0:
+            return True
+        if have + need > self.max_pages_per_seq:
+            raise ValueError(
+                f"sequence of {total_len} tokens needs {have + need} pages > "
+                f"max_pages_per_seq={self.max_pages_per_seq}"
+            )
+        self._evict_for(need)
+        if need > len(self._free):
+            return False
+        for _ in range(need):
+            p = self._free.pop()
+            self._refcount[p] = 1
+            pages.append(p)
+        self._table[slot, have:have + need] = pages[have:]
+        return True
+
     def trim(self, slot: int, total_len: int) -> int:
         """Release the slot's surplus tail pages beyond what `total_len`
-        tokens need (speculative-decode rollback, ISSUE 16): admission
-        reserves `speculate_k` tokens of headroom so a verify chunk can
-        always scatter its K+1 positions, and once the request's remaining
-        budget can no longer use that headroom the surplus recycles here
-        instead of riding to retirement. Tail pages are always private
+        tokens need (speculative-decode rollback, ISSUE 16): a verify round
+        grows the slot to its K+1 positions first, and what a rejected draft
+        leaves past the accepted frontier recycles here instead of riding to
+        retirement. Tail pages are always private
         (aliased prefix pages sit at the FRONT and registration never
         reaches past the prompt), so the decref frees them physically.
         Returns how many pages were freed; idempotent."""

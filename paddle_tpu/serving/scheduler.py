@@ -3,9 +3,20 @@
 The host-side half of the serving runtime. A request's life:
 
     submit -> admission control (queue bound + load-aware shed + tenant
-    quota) -> waiting -> [step boundary] slot + KV pages reserved, prefill
-    -> decoding -> EOS / token budget -> retired (pages recycled, handle
-    completed)
+    quota) -> waiting -> [step boundary] slot + the prompt's KV pages,
+    prefill -> decoding, a page more whenever a write crosses into one
+    -> EOS / token budget -> retired (pages recycled, handle completed)
+
+What a request HOLDS (ISSUE 34) is the pages its written tokens need plus
+the page its next write needs, never its whole life's. So the pool can run
+dry under running requests, and then the request admitted LAST is preempted
+(`Scheduler.grow`): its pages are released, it goes back to the FRONT of the
+queue with its tokens kept, and when it is admitted again its K/V is rebuilt
+(the prompt's prefill, then its known tokens through ordinary decode lanes:
+the same programs, so bitwise) and it goes on from the token it had. The
+oldest request in a slot is never the victim, so the engine always makes
+progress. A client sees nothing of a preemption but time: no token is taken
+back or streamed twice, and deadline, cancel and quota see ONE request.
 
 and since ISSUE 10 every exit from that pipeline is *named*: a request that
 cannot make its deadline is shed at the front door (`overload`, with a
@@ -163,11 +174,36 @@ class RequestHandle:
 
 
 class _Waiting:
-    __slots__ = ("handle", "prompt")
+    """A queued request. One that was PREEMPTED (Scheduler.grow) waits here
+    too, at the front, and carries what its next ActiveSeq takes over: the
+    first admission's stamp, when it lost its slot, and how long it has
+    already spent preempted."""
 
-    def __init__(self, handle: RequestHandle, prompt: List[int]):
+    __slots__ = ("handle", "prompt", "t_started", "t_preempted",
+                 "preempted_s")
+
+    def __init__(self, handle: RequestHandle, prompt: List[int],
+                 t_started: Optional[float] = None, t_preempted: float = 0.0,
+                 preempted_s: float = 0.0):
         self.handle = handle
         self.prompt = prompt
+        self.t_started = t_started  # None: never admitted
+        self.t_preempted = t_preempted
+        self.preempted_s = preempted_s
+
+    @property
+    def written_len(self) -> int:
+        """Tokens whose K/V an admission (re)writes before the request
+        decodes on: the prompt, and for a preempted request its tokens."""
+        return self.handle.prompt_len + len(self.handle.tokens)
+
+    def refund(self) -> int:
+        """Quota tokens to give back when the request leaves from the queue:
+        all of them if it never ran, else what a running one gets."""
+        h = self.handle
+        if self.t_started is None:
+            return h.prompt_len + h.max_new_tokens
+        return max(0, h.max_new_tokens - len(h.tokens))
 
 
 class ActiveSeq:
@@ -179,10 +215,17 @@ class ActiveSeq:
     with prefill_pos=0 and advances one chunk per engine step; a slot is
     `prefilling` until the whole prompt is committed and joins decode steps
     only after — so a long prompt never steals a decode step from the
-    already-decoding slots."""
+    already-decoding slots.
+
+    A request admitted again after a preemption REPLAYS: `handle.tokens`
+    is ahead of `generated`, and until they meet `append` takes the known
+    token in place of the sampled one (they are equal: the replay runs the
+    same programs on the same inputs), so the slot's K/V is rebuilt position
+    by position and nothing reaches the handle twice."""
 
     __slots__ = ("handle", "prompt", "last_token", "next_pos", "generated",
-                 "t_started", "prefill_pos", "engine_steps", "prefix_hit")
+                 "t_started", "prefill_pos", "engine_steps", "prefix_hit",
+                 "admit_seq", "preempted_s")
 
     def __init__(self, handle: RequestHandle, prompt: List[int]):
         self.handle = handle
@@ -190,7 +233,12 @@ class ActiveSeq:
         self.last_token: int = -1  # set by prefill
         self.next_pos: int = len(prompt)  # position the last token occupies
         self.generated: int = 0
-        self.t_started: Optional[float] = None  # set at admission
+        # the FIRST admission's stamp (a readmission keeps it); the time
+        # spent preempted since is preempted_s, which retire() leaves out
+        self.t_started: Optional[float] = None
+        self.preempted_s = 0.0
+        # admission order: the slot admitted last is the preemption victim
+        self.admit_seq = 0
         self.prefill_pos: int = len(prompt)  # chunked path resets to 0
         # prompt tokens aliased from the prefix cache at reservation
         # (ISSUE 19): the session starts this slot's chunked prefill HERE —
@@ -207,15 +255,30 @@ class ActiveSeq:
     def prefilling(self) -> bool:
         return self.prefill_pos < len(self.prompt)
 
-    def append(self, token: int) -> None:
-        self.handle.tokens.append(int(token))
-        self.generated += 1
-        if self.generated == 1:
-            # clock-ok: once per REQUEST (not per token) — the TTFT stamp
-            self.handle.t_first_token = time.monotonic()
-        else:
+    @property
+    def replaying(self) -> bool:
+        return self.generated < len(self.handle.tokens)
+
+    @property
+    def written(self) -> int:
+        """Tokens whose K/V sits in the slot's pages."""
+        return self.prefill_pos if self.prefilling else self.next_pos
+
+    def append(self, token: int) -> bool:
+        """Take the step's token; True when it is NEW to the handle, False
+        when it only rebuilt the K/V of one the handle already has."""
+        tokens = self.handle.tokens
+        new = self.generated == len(tokens)
+        if new:
+            tokens.append(int(token))
+            if not self.generated:
+                # clock-ok: once per REQUEST (not per token) — the TTFT stamp
+                self.handle.t_first_token = time.monotonic()
+        if self.generated:
             self.next_pos += 1
-        self.last_token = int(token)
+        self.last_token = tokens[self.generated]
+        self.generated += 1
+        return new
 
     def finished(self, eos_id: int) -> Optional[str]:
         if self.generated and self.last_token == eos_id:
@@ -244,12 +307,13 @@ class Scheduler:
         self.cache = cache
         self.max_queue = max_queue
         self.quotas = quotas
-        # speculative decoding (ISSUE 16): admission reserves K extra
-        # tokens of page headroom per request so a verify chunk's K+1
-        # scatter always has pages behind it; the session trims the surplus
-        # back to the free list once a request's remaining budget can no
-        # longer use it (kv_cache.trim). 0 = today's exact reservation.
+        # speculative decoding (ISSUE 16): a verify chunk scatters K+1
+        # positions, so a request's whole life is K tokens longer than
+        # prompt + max_new (what submit refuses by); the pages themselves
+        # come when a round grows to them and go when kv_cache.trim gives
+        # back what a rejection leaves over.
         self.speculate_k = max(0, int(speculate_k))
+        self._admit_seq = itertools.count(1)
         # chunked-prefill geometry (None = whole-prompt prefill): the load
         # estimator charges each chunk one engine step, so a flood of long
         # prompts raises the wait estimate the way it raises real TTFT;
@@ -276,6 +340,7 @@ class Scheduler:
         self.shed = 0
         self.deadline_misses = 0
         self.pages_recycled_on_cancel = 0
+        self.preemptions = 0
 
     # -- intake -------------------------------------------------------------
     def submit(
@@ -397,6 +462,17 @@ class Scheduler:
             return 0
         return -(-int(prompt_len - cached) // c)
 
+    def _fits_now(self, written_len: int, total_len: int) -> bool:
+        """The one admission predicate (under self.lock), which
+        pop_admissions admits by and the load estimate prices by: the pool
+        can give the pages of `written_len` tokens plus one and still leave
+        a free page for every slot already holding a request
+        (kv_cache.can_admit). The headroom is read here, off the slots."""
+        live = sum(a is not None for a in self.slots)
+        return self.cache.can_admit(
+            written_len, total_len + self.speculate_k, live
+        )
+
     def _estimate_wait_s(self, total_len: int, prompt_len: int = 0,
                          cached: int = 0) -> float:
         """Expected time for a request of `total_len` tokens to COMPLETE
@@ -414,9 +490,7 @@ class Scheduler:
         if svc is None:
             return 0.0
         free_slot = any(a is None for a in self.slots)
-        fits_now = free_slot and self.cache.can_reserve(
-            total_len + self.speculate_k
-        )
+        fits_now = free_slot and self._fits_now(prompt_len, total_len)
         depth = len(self.waiting)
         step_s = self._ewma_step_s or 0.0
         c = self.prefill_chunk
@@ -499,10 +573,10 @@ class Scheduler:
 
     def cancel(self, request_id: int,
                reason: str = FinishReason.CANCELLED) -> bool:
-        """Cancel one request by id. Queued → completed CANCELLED now (quota
-        refunded, nothing was reserved); running → marked, retired with its
-        pages recycled at the next decode-step boundary (reap). False when
-        unknown or already finished."""
+        """Cancel one request by id. Queued (a preempted one too) →
+        completed CANCELLED now (quota refunded, it holds no page); running
+        → marked, retired with its pages recycled at the next decode-step
+        boundary (reap). False when unknown or already finished."""
         victim: Optional[_Waiting] = None
         with self.lock:
             for w in self.waiting:
@@ -518,8 +592,7 @@ class Scheduler:
                         self._cancel_req[request_id] = reason
                         return True
                 return False
-        h = victim.handle
-        self._finalize(h, reason, h.prompt_len + h.max_new_tokens, 0)
+        self._finalize(victim.handle, reason, victim.refund(), 0)
         return True
 
     def reap(self, now: Optional[float] = None) -> int:
@@ -542,8 +615,7 @@ class Scheduler:
                         self.cancelled += 1
                         self.deadline_misses += 1
                         removed.append(
-                            (h, FinishReason.DEADLINE,
-                             h.prompt_len + h.max_new_tokens, 0)
+                            (h, FinishReason.DEADLINE, w.refund(), 0)
                         )
                     else:
                         keep.append(w)
@@ -578,7 +650,12 @@ class Scheduler:
     ) -> List[Tuple[int, ActiveSeq]]:
         """Move waiting requests into free slots while KV pages allow —
         called once per engine step, so joins land exactly at step
-        boundaries. A queued request whose remaining deadline budget no
+        boundaries. The head is admitted when `_fits_now` says the pool can
+        give it its prompt's pages plus one (a preempted request's: those of
+        what it has to rebuild) beside a free page for every live slot, and
+        it is given the PROMPT's pages, no more: the rest come as it writes
+        (`grow`). FIFO: nothing is admitted past a head that does not fit.
+        A queued request whose remaining deadline budget no
         longer covers one service time is DOOMED: it is failed here
         ('deadline') instead of being handed a slot it would die holding —
         under overload that one check is most of what keeps goodput flat
@@ -588,7 +665,7 @@ class Scheduler:
         # single per-step timestamp
         now = time.monotonic() if now is None else now
         admitted: List[Tuple[int, ActiveSeq]] = []
-        doomed: List[RequestHandle] = []
+        doomed: List[_Waiting] = []
         with self.lock:
             svc = self._ewma_service_s
             for slot in range(len(self.slots)):
@@ -600,7 +677,7 @@ class Scheduler:
                         self.waiting.popleft()
                         self.cancelled += 1
                         self.deadline_misses += 1
-                        doomed.append(h)
+                        doomed.append(w)
                         continue
                     break
                 if not self.waiting:
@@ -608,11 +685,10 @@ class Scheduler:
                 if self.slots[slot] is not None:
                     continue
                 w = self.waiting[0]
-                # +K speculative headroom (0 when speculation is off, so
-                # the reservation is bitwise today's)
-                total = (w.handle.prompt_len + w.handle.max_new_tokens
-                         + self.speculate_k)
-                if not self.cache.can_reserve(total):
+                h = w.handle
+                if not self._fits_now(
+                    w.written_len, h.prompt_len + h.max_new_tokens
+                ):
                     break  # FIFO: do not starve the head by skipping it
                 self.waiting.popleft()
                 # tenant+prompt let the cache alias this prompt's cached
@@ -620,18 +696,59 @@ class Scheduler:
                 # the AUTHORITATIVE hit lands on the ActiveSeq — the session
                 # starts chunked prefill at exactly this offset
                 self.cache.reserve(
-                    slot, total, tenant=w.handle.tenant, prompt=w.prompt
+                    slot, h.prompt_len, tenant=h.tenant, prompt=w.prompt
                 )
-                act = ActiveSeq(w.handle, w.prompt)
+                act = ActiveSeq(h, w.prompt)
                 act.prefix_hit = self.cache.hit_tokens(slot)
-                act.t_started = now
+                act.admit_seq = next(self._admit_seq)
+                if w.t_started is None:
+                    act.t_started = now
+                else:
+                    act.t_started = w.t_started
+                    act.preempted_s = w.preempted_s + (now - w.t_preempted)
                 act.handle.status = RequestHandle.RUNNING
                 self.slots[slot] = act
                 admitted.append((slot, act))
-        for h in doomed:
-            self._finalize(h, FinishReason.DEADLINE,
-                           h.prompt_len + h.max_new_tokens, 0)
+        for w in doomed:
+            self._finalize(w.handle, FinishReason.DEADLINE, w.refund(), 0)
         return admitted
+
+    def grow(self, wants: Sequence[Tuple[int, int]],
+             now: float) -> List[Tuple[int, ActiveSeq, int]]:
+        """Before a step writes: give every slot of `wants` [(slot, tokens
+        its pages must cover)] the pages its write lands in, the request
+        admitted first served first. When the pool is dry (the prefix index
+        has given up what it could), the request admitted LAST among those
+        in slots is PREEMPTED: its pages are released and it returns to the
+        FRONT of the queue with its tokens, to be rebuilt at its next
+        admission. It may be the asking slot itself; it is never the oldest
+        while another is live, and `submit` refused any request that could
+        not finish alone, so the oldest always gets its page. Returns
+        [(slot, the ActiveSeq preempted, pages freed)], youngest first.
+        Host ints only; `now` is the engine step's one timestamp."""
+        preempted: List[Tuple[int, ActiveSeq, int]] = []
+        with self.lock:
+            slots = self.slots
+            for slot, total in sorted(
+                wants, key=lambda w: slots[w[0]].admit_seq
+            ):
+                while slots[slot] is not None and not self.cache.grow(
+                    slot, total
+                ):
+                    victim = max(
+                        (i for i, a in enumerate(slots) if a is not None),
+                        key=lambda i: slots[i].admit_seq,
+                    )
+                    act = slots[victim]
+                    slots[victim] = None
+                    freed = self.cache.release(victim)
+                    self.waiting.appendleft(_Waiting(
+                        act.handle, act.prompt, t_started=act.t_started,
+                        t_preempted=now, preempted_s=act.preempted_s,
+                    ))
+                    self.preemptions += 1
+                    preempted.append((victim, act, freed))
+        return preempted
 
     def retire(self, slot: int, reason: str) -> None:
         act = self.slots[slot]
@@ -646,7 +763,10 @@ class Scheduler:
             self.quotas.release(act.handle.tenant, max(0, unused))
         act.handle._complete(RequestHandle.DONE, reason)
         REQUEST_HISTOGRAM.observe(act.handle.t_done - act.handle.t_submit)
-        svc = act.handle.t_done - (act.t_started or act.handle.t_submit)
+        # service is the time in a slot: what the request spent preempted,
+        # back in the queue, is queue wait and must not price a service
+        svc = (act.handle.t_done - (act.t_started or act.handle.t_submit)
+               - act.preempted_s)
         # engine steps this request actually occupied: its decode steps plus
         # its extra prefill chunks — prices one chunk for the load estimate.
         # With speculation on, `generated` over-counts steps (a verify round
@@ -726,7 +846,8 @@ class Scheduler:
     def cancel_tenant(self, tenant: str) -> int:
         """Drop a (evicted/deregistered) tenant's QUEUED requests; running
         sequences finish — their pages are already committed and retiring
-        them early would waste the work. Returns how many were cancelled."""
+        them early would waste the work (a preempted one holds no page and
+        is dropped with the queue). Returns how many were cancelled."""
         n = 0
         with self.lock:
             keep: Deque[_Waiting] = collections.deque()
@@ -734,10 +855,7 @@ class Scheduler:
                 if w.handle.tenant == tenant:
                     n += 1
                     if self.quotas is not None:
-                        self.quotas.release(
-                            tenant,
-                            w.handle.prompt_len + w.handle.max_new_tokens,
-                        )
+                        self.quotas.release(tenant, w.refund())
                     w.handle._complete(
                         RequestHandle.CANCELLED, FinishReason.CANCELLED
                     )

@@ -38,6 +38,18 @@ stream results — and, since ISSUE 10, exactly ONE wall-clock read per step
 check). tests/test_lint_hotloop.py lints this loop body the same way it
 lints the train loop.
 
+KV pages (ISSUE 34) are handed out as tokens are written: an admission gets
+its prompt's pages, and before each decode step or verify round every slot
+that writes is grown by the page its write crosses into (`_ensure_pages`: a
+few host ints a step, no clock, no fetch). When the pool is dry the request
+admitted last is preempted (scheduler.Scheduler.grow) and, admitted again,
+REPLAYS: its prompt is prefilled again and its known tokens ride ordinary
+decode lanes until its K/V is rebuilt. Every program is the one it ran the
+first time on the same inputs, so the rebuilt K/V and every later token are
+bitwise what they would have been, greedy and sampled; `stats()` counts
+`preemptions` and `replayed_tokens`, and a flight span `serve.preempt`
+names the engine step that paid for one.
+
 Resilience (ISSUE 10): in server mode the engine thread runs under a
 SUPERVISOR. When the engine faults (seeded sites `decode_raise` /
 `page_exhaust`) or stalls past `engine_stall_timeout_s` without a step
@@ -238,6 +250,9 @@ class ServingSession:
         self.spec_tokens_drafted = 0
         self.spec_tokens_accepted = 0
         self.spec_pages_trimmed = 0
+        # dry-pool telemetry (ISSUE 34): requests that lost their slot to an
+        # older one's page, and the token-steps spent rebuilding their K/V
+        self.replayed_tokens = 0
         # adaptive-K telemetry: sum of the effective draft length actually
         # used per round — spec_effective_k = sum / rounds
         self.spec_k_eff_sum = 0
@@ -431,14 +446,17 @@ class ServingSession:
         for slot, act in self.scheduler.pop_admissions(now):
             h = act.handle
             ctx = h.trace_ctx
-            # queue-wait: submit → this admission boundary, under the
-            # request's own trace id (measured on the scheduler's monotonic
-            # clock, re-anchored to wall-clock for the export)
-            trace.span_from_monotonic(
-                "serving.queue_wait", h.t_submit,
-                trace_id=ctx and ctx.get("t"), parent_id=ctx and ctx.get("s"),
-                attrs={"request_id": h.request_id},
-            )
+            if not act.preempted_s:
+                # queue-wait: submit → this admission boundary, under the
+                # request's own trace id (measured on the scheduler's
+                # monotonic clock, re-anchored to wall-clock for the export);
+                # a preempted request's readmission is no second queue wait
+                trace.span_from_monotonic(
+                    "serving.queue_wait", h.t_submit,
+                    trace_id=ctx and ctx.get("t"),
+                    parent_id=ctx and ctx.get("s"),
+                    attrs={"request_id": h.request_id},
+                )
             if act.prefix_hit or self._chunked_prompt(act.prompt):
                 # chunked path: _prefill_chunks advances this slot one chunk
                 # per engine step from here on. A prefix-cache hit ALWAYS
@@ -471,15 +489,17 @@ class ServingSession:
                         jnp.zeros((1,), jnp.int32),
                     )
                     # one tiny host fetch per ADMISSION (not per decode step):
-                    # the prompt's first token — sampled on device
-                    act.append(int(first_tok[0]))
+                    # the prompt's first token — sampled on device (a replay
+                    # re-derives the one its handle already has)
+                    fresh = act.append(int(first_tok[0]))
             # the whole prompt is committed: register its full pages into
             # the tenant's prefix chain (no-op with the cache off)
             self.cache.commit_prefix(slot, h.tenant, act.prompt,
                                      len(act.prompt))
             # time-to-first-token: prefill emits the first sampled token, so
             # TTFT completes here — span under the request trace + histogram
-            self._observe_ttft(h, ctx)
+            if fresh:
+                self._observe_ttft(h, ctx)
             SERVING_EVENTS.incr("serving_prefills")
             obs_metrics.observe_layer_passes(
                 "prefill", len(act.prompt) * self.layer_passes
@@ -554,12 +574,31 @@ class ServingSession:
                 # step) — the FINAL chunk's sampled first token, which the
                 # autoregressive loop needs on host; intermediate chunks
                 # never fetch (their `tok` stays device-resident and unused)
-                act.append(int(tok[0]))
-                self._observe_ttft(h, h.trace_ctx)
+                if act.append(int(tok[0])):
+                    self._observe_ttft(h, h.trace_ctx)
                 SERVING_EVENTS.incr("serving_prefills")
                 reason = act.finished(self.cfg.eos_id)
                 if reason is not None:
                     self.scheduler.retire(slot, reason)
+
+    def _ensure_pages(self, wants) -> set:
+        """Grow every slot of `wants` [(slot, tokens its pages must cover)]
+        to the pages the step about to run writes into (ISSUE 34); returns
+        the slots that were PREEMPTED to find them (empty on a pool with
+        room, which is every step but a few), which sit this step out.
+        Host ints only: the step's timestamp is the one step() took."""
+        preempted = self.scheduler.grow(wants, self._last_progress)
+        for slot, act, freed in preempted:
+            # span-ok: the flight recorder's one ring write a PREEMPTION
+            # (not a step), int attrs: the victim's written tokens and the
+            # pages it gave back, so a trace names the step that paid
+            with trace.flight(
+                "serve.preempt", written=act.written, pages=freed
+            ):
+                self._drafters.pop(slot, None)
+                SERVING_EVENTS.incr("serving_preemptions")
+                obs_metrics.observe_preemption()
+        return {slot for slot, _, _ in preempted}
 
     def _drafter_for(self, slot: int, act):
         """This slot's (drafter, adaptive-K cell), rebuilt when the slot was
@@ -598,9 +637,10 @@ class ServingSession:
         advanced: set = set()
         if not self.speculate_k:
             return advanced
+        # a replaying slot rebuilds its K/V through the decode lanes first
         candidates = [
             (slot, act) for slot, act in self.scheduler.active_slots()
-            if not act.prefilling
+            if not act.prefilling and not act.replaying
         ]
         if candidates and _faults.get().active:
             # chaos site (spec_replay): the engine faults mid-speculation —
@@ -611,14 +651,8 @@ class ServingSession:
         for slot, act in candidates:
             h = act.handle
             remaining = h.max_new_tokens - act.generated
-            if remaining <= 1:
-                # the +K page headroom is no longer reachable (every future
-                # write lands inside the base reservation): recycle it now
-                # instead of riding it to retirement
-                self.spec_pages_trimmed += self.cache.trim(
-                    slot, h.prompt_len + h.max_new_tokens
-                )
-                continue
+            if remaining <= 1 or self.scheduler.slots[slot] is not act:
+                continue  # nothing left to draft for; or preempted this step
             drafter, kcell = self._drafter_for(slot, act)
             drafter.sync(act.prompt, h.tokens)
             # adaptive K (ROADMAP 1a): draft up to this request's CURRENT
@@ -627,6 +661,10 @@ class ServingSession:
             # [1, K_max+1] (short drafts zero-pad, signature stays 1)
             draft = drafter.draft(min(k, kcell[0]))
             if not draft:
+                continue
+            # the round scatters K+1 positions: grow to them first (a dry
+            # pool may preempt this very slot, the youngest)
+            if slot in self._ensure_pages([(slot, act.next_pos + k + 1)]):
                 continue
             toks = np.zeros((1, k + 1), np.int32)
             toks[0, 0] = act.last_token
@@ -691,6 +729,13 @@ class ServingSession:
             if reason is not None:
                 self._drafters.pop(slot, None)
                 self.scheduler.retire(slot, reason)
+            else:
+                # what the rejection left past the accepted frontier goes
+                # back: the slot keeps its written tokens' pages and the
+                # page of its next write
+                self.spec_pages_trimmed += self.cache.trim(
+                    slot, act.next_pos + 1
+                )
         return advanced
 
     def _decode_once(self, skip: frozenset = frozenset()) -> None:
@@ -710,6 +755,14 @@ class ServingSession:
             # restart it, re-init the page pool and replay in-flight work;
             # gated on live slots so step=N counts real decode attempts
             _faults.get().maybe_raise("decode_raise")
+        # every lane writes position next_pos: the page it lands in first
+        lost = self._ensure_pages(
+            [(slot, act.next_pos + 1) for slot, act in active]
+        )
+        if lost:
+            active = [sa for sa in active if sa[0] not in lost]
+            if not active:
+                return
         s = self.cache.max_slots
         tokens = np.zeros(s, np.int32)
         positions = np.zeros(s, np.int32)
@@ -760,14 +813,21 @@ class ServingSession:
         self.decode_steps += 1
         SERVING_EVENTS.incr("serving_decode_steps")
         obs_metrics.observe_decode_step(len(active), self.layer_passes)
+        replayed = 0
         for slot, act in active:
-            act.append(toks[slot])
+            if act.append(toks[slot]):
+                self.tokens_generated += 1
+            else:
+                replayed += 1  # a preempted request's K/V, rebuilt
             act.engine_steps += 1
-            self.tokens_generated += 1
             reason = act.finished(self.cfg.eos_id)
             if reason is not None:
                 self._drafters.pop(slot, None)
                 self.scheduler.retire(slot, reason)
+        if replayed:
+            self.replayed_tokens += replayed
+            SERVING_EVENTS.incr("serving_replayed_tokens", replayed)
+            obs_metrics.observe_replayed_tokens(replayed)
 
     def step(self, now: Optional[float] = None) -> bool:
         """One engine iteration: reap expired/cancelled requests, then
@@ -790,6 +850,7 @@ class ServingSession:
         spec_before = self.spec_rounds
         advanced = self._speculate()
         self._decode_once(advanced)
+        obs_metrics.set_kv_pages_in_use(self.cache.pages_in_use)
         self._notify_streams()
         # auto EWMA reset (ISSUE 17): a step that compiled an executable
         # retired requests with second-scale service times; the first CLEAN
@@ -1102,8 +1163,14 @@ class ServingSession:
             "queue_depth": sch.queue_depth(),
             "active_slots": len(sch.active_slots()),
             "max_slots": self.cache.max_slots,
+            # pages free NOW and pages some slot or the prefix index holds
+            # NOW (ISSUE 34): a request holds what it has written plus the
+            # page of its next write, so neither counts what live requests
+            # will still ask for
             "free_pages": self.cache.free_pages,
             "pages_in_use": self.cache.pages_in_use,
+            "preemptions": sch.preemptions,
+            "replayed_tokens": self.replayed_tokens,
             "completed": sch.completed,
             "rejected": sch.rejected,
             "cancelled": sch.cancelled,

@@ -37,6 +37,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRAINER_PY = os.path.join(_REPO, "paddle_tpu", "trainer", "trainer.py")
 SERVING_PY = os.path.join(_REPO, "paddle_tpu", "serving", "session.py")
 SCHEDULER_PY = os.path.join(_REPO, "paddle_tpu", "serving", "scheduler.py")
+KV_CACHE_PY = os.path.join(_REPO, "paddle_tpu", "serving", "kv_cache.py")
 ROUTER_PY = os.path.join(_REPO, "paddle_tpu", "serving", "router.py")
 SERVER_PY = os.path.join(_REPO, "paddle_tpu", "serving", "server.py")
 
@@ -62,11 +63,19 @@ SERVING_SYNC_CALL = re.compile(
 # ISSUE 16 added _speculate: its ONE sanctioned fetch is the verify round's
 # K+1 sampled tokens (per ROUND per slot — acceptance runs on host), so the
 # verify loop obeys the same budget discipline as the decode loop.
+# ISSUE 34 added _ensure_pages, which the decode step and every verify round
+# call before they write: growing a slot's page list is host ints (its
+# scheduler half, Scheduler.grow, is pinned clock-free below), so it fetches
+# nothing and the budget of three stands.
 HOT_LOOPS = [
     (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), SYNC_CALL, 3),
     (SERVING_PY, "ServingSession",
-     ("_decode_once", "step", "_prefill_chunks", "_speculate"),
+     ("_decode_once", "step", "_prefill_chunks", "_speculate",
+      "_ensure_pages"),
      SERVING_SYNC_CALL, 3),
+    (SCHEDULER_PY, "Scheduler", ("grow",), SERVING_SYNC_CALL, 0),
+    (KV_CACHE_PY, "PagedKVCache", ("grow", "trim", "can_admit"),
+     SERVING_SYNC_CALL, 0),
 ]
 
 # a tag on the offending line or in the contiguous comment block above it
@@ -108,9 +117,12 @@ SPAN_HOT_LOOPS = [
     # ISSUE 33: `serve.decode`, a flight span around the decode dispatch and
     # its one fetch (one ring write a decode step, two int attrs), beside
     # the gated `serving.decode_step` it wraps: four sites.
+    # ISSUE 34: `serve.preempt` in _ensure_pages, a flight span a PREEMPTION
+    # (none on a pool with room, a few a minute on one that binds), two int
+    # attrs: five sites.
     (SERVING_PY, "ServingSession",
      ("_decode_once", "step", "_prefill_chunks", "_speculate",
-      "_notify_streams"), 4),
+      "_notify_streams", "_ensure_pages"), 5),
     (ROUTER_PY, "Router",
      ("_forward", "_failover_requests", "_reap_once", "_pump_once"), 3),
 ]
@@ -294,12 +306,15 @@ CLOCK_CALL = re.compile(
 CLOCK_TAG = "clock-ok"
 # (file, class, methods on the request path, max clock-ok tags)
 CLOCK_HOT_LOOPS = [
+    # ISSUE 34: page growth and preemption (_ensure_pages, Scheduler.grow)
+    # stamp a victim with the step's own timestamp, handed in: no new read.
     (SERVING_PY, "ServingSession",
      ("step", "_admit", "_prefill_chunks", "_observe_ttft", "_decode_once",
-      "_speculate", "_notify_streams", "_engine_loop", "_supervise",
-      "_recover"), 4),
+      "_speculate", "_ensure_pages", "_notify_streams", "_engine_loop",
+      "_supervise", "_recover"), 4),
     (SCHEDULER_PY, "Scheduler",
-     ("reap", "pop_admissions", "requeue_active", "retire"), 3),
+     ("reap", "pop_admissions", "grow", "requeue_active", "retire"), 3),
+    (KV_CACHE_PY, "PagedKVCache", ("grow", "trim", "can_admit"), 0),
     (SCHEDULER_PY, "ActiveSeq", ("append", "finished"), 1),
     # router dispatch path (ISSUE 15): one read per submit (the admission
     # stamp deadlines/hedge/park all derive from), one per pump cycle, one
@@ -368,7 +383,8 @@ PUT_TAG = "tp-ok"
 # (file, class, engine-loop methods, max tp-ok tags)
 PUT_HOT_LOOPS = [
     (SERVING_PY, "ServingSession",
-     ("step", "_admit", "_prefill_chunks", "_decode_once", "_speculate"), 1),
+     ("step", "_admit", "_prefill_chunks", "_decode_once", "_speculate",
+      "_ensure_pages"), 1),
 ]
 
 
@@ -591,7 +607,7 @@ STREAM_EMIT = re.compile(
 STREAM_SEAM = [
     (SERVING_PY, "ServingSession",
      ("_notify_streams", "stream_wait", "step", "_decode_once",
-      "_speculate")),
+      "_speculate", "_ensure_pages")),
     (ROUTER_PY, "Router",
      ("_notify_streams", "stream_wait", "_on_result", "_pump_once")),
 ]

@@ -156,7 +156,7 @@ def test_peek_is_pure():
 
 
 def test_lru_eviction_under_pool_pressure():
-    """Unreferenced cached pages are capacity, not occupancy: can_reserve
+    """Unreferenced cached pages are capacity, not occupancy: can_admit
     counts them, reserve LRU-evicts them when the free list runs short, and
     a just-matched prefix can never evict itself (its refs go up first)."""
     c = make_cache(num_pages=12)
@@ -166,7 +166,13 @@ def test_lru_eviction_under_pool_pressure():
     c.release(0)
     assert c.free_pages == 8 and c.prefix_stats()["prefix_pages_cached"] == 3
     c.reserve(1, 24, tenant="b", prompt=list(range(50, 56)))  # 6 fresh
-    assert c.can_reserve(20), "2 free + 3 evictable must admit 5 pages"
+    # the admission predicate (ISSUE 34): the pages of what is to be
+    # written plus one, never more than the whole life's, and a free page
+    # for every live slot besides
+    assert c.can_admit(20, 20, 0), "2 free + 3 evictable must admit 5 pages"
+    assert c.can_admit(13, 32, 0), "4 pages to write + 1, of a life of 8"
+    assert not c.can_admit(13, 32, 1), "a live slot's page must stay free"
+    assert not c.can_admit(20, 20, 1)
     c.reserve(2, 20, tenant="b", prompt=list(range(60, 66)))
     s = c.prefix_stats()
     assert s["prefix_evictions"] == 3 and s["prefix_pages_cached"] == 0

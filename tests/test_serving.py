@@ -9,7 +9,7 @@ The load-bearing claims, each tested directly:
   * one decode program — a mixed-length request stream records exactly one
     decode-step shape signature (the PR-1 RecompileStats zero-recompile
     assertion);
-  * KV paging — pages are reserved at admission, recycled at retirement,
+  * KV paging — pages are handed out as tokens are written, recycled at retirement,
     and reused by later requests;
   * admission control — queue bounds, per-tenant token quotas and
     concurrency caps reject at the front door;
@@ -139,23 +139,38 @@ def test_midstream_join_and_retire(model_and_params):
 
 
 def test_kv_page_recycling(model_and_params):
+    """An admission holds its PROMPT's pages and no more (ISSUE 34); the
+    slot grows by a page whenever a write crosses into one, never past the
+    pages of what is written plus the next write; retirement returns all."""
     s = make_session(model_and_params)
-    total_free = s.cache.free_pages
+    cache, total_free = s.cache, s.cache.free_pages
     h = s.submit(PROMPTS[0], 8)
     s._admit()
-    used_first = s.cache.slot_pages(0)
-    assert used_first and s.cache.free_pages == total_free - len(used_first)
-    s.run_until_idle()
+    used_first = cache.slot_pages(0)
+    assert len(used_first) == cache.pages_needed(len(PROMPTS[0]))
+    assert cache.free_pages == total_free - len(used_first)
+    held = set(used_first)
+    while s.scheduler.has_work():
+        s.step()
+        act = s.scheduler.slots[0]
+        if act is not None:
+            pages = cache.slot_pages(0)
+            held |= set(pages)
+            # written tokens' pages, plus at most the page of the next write
+            assert cache.pages_needed(act.next_pos) <= len(pages) \
+                <= cache.pages_needed(act.next_pos + 1)
+            assert cache.free_pages == total_free - len(pages)
     assert h.done
-    assert s.cache.free_pages == total_free, "retirement must return pages"
+    assert len(held) == cache.pages_needed(len(PROMPTS[0]) + 8 - 1)
+    assert cache.free_pages == total_free, "retirement must return pages"
 
     # a later request must REUSE the recycled physical pages
     s.submit(PROMPTS[1], 8)
     s._admit()
-    reused = s.cache.slot_pages(0)
-    assert set(reused) <= set(used_first)
+    reused = cache.slot_pages(0)
+    assert set(reused) <= held
     s.run_until_idle()
-    assert s.cache.free_pages == total_free
+    assert cache.free_pages == total_free
 
 
 def test_zero_decode_recompiles_on_mixed_stream(model_and_params):

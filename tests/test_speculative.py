@@ -14,8 +14,9 @@ The load-bearing claims, each tested directly:
   * one verify program — every speculative round, whatever the draft
     length or request mix, records exactly ONE [1, K+1] verify_chunk shape
     signature, and the decode loop stays at its one signature;
-  * paging — the +K reservation headroom is trimmed back to the pool when
-    speculation can no longer reach it, and retirement returns everything;
+  * paging — a verify round grows the slot to its K+1 positions and what
+    a rejection leaves over is trimmed back to the pool, and retirement
+    returns everything;
   * the drafter — pure function of the committed tokens: indexes n-grams
     incrementally, drafts the continuation after the PREVIOUS occurrence
     (never self-matching the live suffix), slides its window so cyclic
@@ -138,7 +139,7 @@ def test_one_verify_signature_and_decode_stays_compiled(model_and_params):
 
 def test_speculate_k0_is_todays_engine(model_and_params):
     """`speculate_k=0` must recover the pre-ISSUE-16 engine exactly: no
-    drafter state, no verify executable, no +K page reservation."""
+    drafter state, no verify executable, no page grown for K+1 positions."""
     s = make_session(model_and_params, speculate_k=0)
     got = _run_all(s, RANDOM, 8)
     assert all(len(t) > 0 for t in got)
@@ -150,22 +151,22 @@ def test_speculate_k0_is_todays_engine(model_and_params):
 
 
 def test_spec_pages_reserved_trimmed_and_recycled(model_and_params, monkeypatch):
-    """The +K page headroom reserved at admission is trimmed back to the
-    pool once unreachable and fully returned at retirement — later
-    requests reuse the same pool with nothing leaked."""
+    """A verify round GROWS the slot to its K+1 positions first and trims
+    back what a rejection leaves past the accepted frontier (ISSUE 34: no
+    +K headroom is reserved at admission); everything returns at
+    retirement — later requests reuse the same pool with nothing leaked."""
     from paddle_tpu.serving.speculation import PromptLookupDrafter
 
     s = make_session(model_and_params, speculate_k=8, page_size=8)
     free0 = s.cache.free_pages
     want = _run_all(s, REPETITIVE, 16)[0]
     assert s.cache.free_pages == free0, "pages leaked across retirement"
-    # The headroom is trimmed only for a slot that ENTERS a speculation round
-    # with one token left; a request whose last verify round commits its
-    # final tokens keeps the page until release, so whether the three above
-    # trimmed anything is their drafts' luck. Build the case: a request whose
-    # every draft provably misses (one token that is NOT the model's next:
-    # `want` is its greedy continuation, speculation being result-transparent)
-    # commits exactly one token a round and must pass the trim.
+    # Whether the three above trimmed anything is their drafts' luck: an
+    # accepted draft keeps the pages its round grew to. Build the case: a
+    # request whose every draft provably misses (one token that is NOT the
+    # model's next: `want` is its greedy continuation, speculation being
+    # result-transparent) commits exactly one token a round, so every round
+    # grows to K+1 positions and gives back all but the next write's page.
     prompt = REPETITIVE[0]
     assert len(prompt) == 16 and len(want) == 16, "case needs 4 full pages"
     monkeypatch.setattr(
@@ -191,14 +192,19 @@ def test_spec_pages_reserved_trimmed_and_recycled(model_and_params, monkeypatch)
     # its draft, so the slot entered the next round with exactly one left
     assert s.spec_rounds - rounds0 == 14
     assert s.spec_tokens_accepted == accepted0
-    # ... where the trim found prompt 16 + new 16 + K 8 = 5 pages reserved,
-    # 4 reachable, and gave the 5th back while the request was in flight
-    assert trims and trims[0] == (5, 32, 1, True), trims
+    # ... the first with its last token at position 16: the round grew the
+    # slot to positions 16..24 (25 tokens, 4 pages), one token was accepted,
+    # and the trim kept the pages of 18 tokens (17 written + the next
+    # write) and gave the 4th back while the request was in flight
+    assert len(trims) == 14 and trims[0] == (4, 18, 1, True), trims
+    for i, (held, asked, freed, active) in enumerate(trims):
+        assert active and asked == 18 + i
+        assert held == s.cache.pages_needed(16 + i + 8 + 1)
+        assert freed == held - s.cache.pages_needed(asked)
     assert s.cache.free_pages == free0
-    # the trim counter moves when the reservation crossed a page boundary
-    # the base length alone wouldn't have: prompt 16 + new 16 fills exactly
-    # 4 pages, so +8 headroom adds a 5th that must come back mid-flight
-    assert s.spec_pages_trimmed >= 1
+    # 13 of the 14 rounds reached into a page the accepted token did not
+    trimmed0 = sum(t[2] for t in trims)
+    assert trimmed0 == 13 and s.spec_pages_trimmed >= trimmed0
     # pool still serves follow-up work after trim/release churn
     h = s.submit(REPETITIVE[0], 8)
     s.run_until_idle()
