@@ -16,6 +16,9 @@ One process, three phases, through the entry points a user calls:
            session under PADDLE_TPU_PALLAS=0 on the same chip; then one
            request through a tiny LoopedLM (layers run four times, bfloat16
            pool, the kernel's layer a traced scalar), checked the same way;
+           then requests through a small HybridMoELM (Mamba-2 and grouped-
+           head attention layers, routed experts of which half are held,
+           recurrent state beside the pages), checked the same way;
   train    ResNet-50 at full width (224x224, 1000 classes, bf16 policy,
            batch 256) through SGDTrainer.train over a DataParallel mesh, a
            few single-step dispatches and a few K-step ones, cost finite and
@@ -53,6 +56,10 @@ LOGIT_TIE_TOL = 5e-2
 # The same for the bfloat16 LoopedLM leg, whose logits (std about 16) carry
 # bfloat16's 2**-8 of their size through every layer.
 LOOPED_TIE_TOL = 0.5
+# The bfloat16 HybridMoELM leg: its logits' std is about 0.15 (a tied head
+# at 1/sqrt(vocab), over logits_scaling), and a near tie in a router flips
+# an expert as well as a token.
+HYBRID_TIE_TOL = 0.05
 
 FULL = dict(
     gru=[(50, 128, 512)],
@@ -367,6 +374,65 @@ def phase_serve(size, on_chip: bool, n_dev: int) -> None:
         del session
         gc.collect()
     serve_looped(on_chip)
+    serve_hybrid(on_chip)
+
+
+def serve_hybrid(on_chip: bool) -> None:
+    """The third served architecture: three requests (one admitted into a
+    slot another left) through ServingSession over a HybridMoELM (m m a m,
+    8 Mamba heads of 64 with state 128, 8 query heads over 2 K/V heads of
+    128, 8 experts top-3 of which 4 are held, bfloat16): the grouped-head
+    kernel, the grouped expert products and the carried recurrent state on
+    the chip, against the same session under PADDLE_TPU_PALLAS=0; and the
+    session's two refusals."""
+    import jax
+
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoEConfig, HybridMoELM
+    from paddle_tpu.serving.session import ServingSession
+
+    model = HybridMoELM(HybridMoEConfig(
+        vocab=512, layer_types=("mamba", "mamba", "attention", "mamba"), d_model=256,
+        n_heads=8, n_kv_heads=2, head_dim=128, mamba_heads=8, mamba_head_dim=64,
+        mamba_state=128, mamba_chunk=16, num_experts_routed=8, experts_held=(0, 1, 2, 3),
+        top_k=3, expert_width=128, shared_width=256, embedding_multiplier=1.0,
+        attention_multiplier=1.0 / 128, max_len=96, dtype="bfloat16",
+    ))
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompts = [[1, 17, 201, 5, 88, 140, 9, 33, 250, 61, 7], [1, 9, 9, 200, 13],
+               [1] + list(range(40, 70))]
+    kw = dict(max_slots=2, page_size=16, prefill_buckets=(16, 32), max_new_limit=24)
+
+    def serve(flag):
+        with pallas_flag(flag):
+            session = ServingSession(model, params, **kw)
+            handles = [session.submit(p, 12) for p in prompts]
+            session.run_until_idle()
+            mosaic = "tpu_custom_call" in session.decode_step_hlo()
+        assert session.k_pages.shape[0] == 1 and session.layer_passes == 4
+        assert session.decode_shape_signatures() == 1
+        counted = session.read_counters()["moe_assignments"]
+        assert (counted.sum(1) == 3 * (sum(map(len, prompts)) + 3 * 11)).all(), counted
+        return [[int(t) for t in h.tokens] for h in handles], mosaic, session
+
+    t0 = time.perf_counter()
+    got, mosaic, session = serve("auto" if on_chip else "interpret")
+    assert mosaic == on_chip, "Mosaic call expected on the chip only"
+    want, _, _ = serve("0")
+    for prompt, g, w in zip(prompts, got, want):
+        assert len(g) == 12 and g[0] == w[0], (g, w)
+        assert g == w or first_divergence_is_a_tie(session, prompt, g, w, HYBRID_TIE_TOL), (
+            f"kernel tokens {g} != oracle tokens {w}")
+    for refused in (dict(prefix_cache=True, prefill_chunk=16), dict(speculate_k=2)):
+        try:
+            ServingSession(model, params, **kw, **refused)
+        except ValueError as e:
+            assert "recurrence" in str(e)
+        else:
+            raise AssertionError(f"a session with {refused} was built over a recurrence")
+    say(f"  HybridMoELM m m a m, 8 heads over 2 K/V heads, 4 of 8 experts held, bfloat16: "
+        f"3 requests served, Mosaic call in the decode step: {on_chip}, "
+        f"{sum(g == w for g, w in zip(got, want))}/3 token-identical to PADDLE_TPU_PALLAS=0 "
+        f"(the rest tied at the first divergence); info: {time.perf_counter() - t0:.1f} s")
 
 
 def serve_looped(on_chip: bool) -> None:

@@ -403,6 +403,41 @@ def set_kv_bytes_per_token(n: int) -> None:
     ).set(n)
 
 
+def set_recurrent_state_bytes_per_slot(n: int) -> None:
+    """What one slot holds beside its pages: the recurrent state a model
+    declares (0 for a model whose requests live in pages alone)."""
+    REGISTRY.gauge(
+        "paddle_tpu_serving_recurrent_state_bytes_per_slot",
+        "bytes of per-request state outside the page pool that one slot holds",
+    ).set(n)
+
+
+def observe_moe_counters(name: str, delta) -> None:
+    """What the device counted since the last read (ServingSession.
+    read_counters; never a decode step's fetch). `moe_expert_tokens`
+    [layers, held experts]: tokens assigned, by MoE layer and by the
+    expert's place among those this chip holds; `moe_assignments`
+    [layers, 2]: assignments that landed on a held expert and that went to
+    an absent one."""
+    if name == "moe_expert_tokens":
+        counter = REGISTRY.counter(
+            "paddle_tpu_serving_moe_expert_tokens_total",
+            "tokens assigned to each expert this chip holds, by MoE layer",
+        )
+        for layer, row in enumerate(delta):
+            for expert, n in enumerate(row):
+                if n:
+                    counter.inc(int(n), layer=layer, expert=expert)
+    elif name == "moe_assignments":
+        counter = REGISTRY.counter(
+            "paddle_tpu_serving_moe_assignments_total",
+            "router assignments, by whether the expert is held here or absent",
+        )
+        for where, n in zip(("here", "absent"), delta.sum(0)):
+            if n:
+                counter.inc(int(n), where=where)
+
+
 def observe_prefix_hit(pages: int) -> None:
     """An admission aliased `pages` cached prefix pages into a new slot's
     block table (ISSUE 19) — each page is prefill work the request skipped."""

@@ -131,6 +131,7 @@ def _paged_decode_kernel(
     head_dim: int,
     pages_per_block: int,
     pmax: int,
+    group: int,
 ):
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
@@ -187,9 +188,14 @@ def _paged_decode_kernel(
     head = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (q_scr.shape[0], kd), 1)
     own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
-    q_scr[...] = jnp.where(
-        own, q_ref[0].astype(jnp.float32) * scale, 0.0
-    ).astype(q_scr.dtype)
+    if group == 1:
+        q_scr[...] = jnp.where(
+            own, q_ref[0].astype(jnp.float32) * scale, 0.0
+        ).astype(q_scr.dtype)
+    else:
+        # grouped heads: the caller laid q out block-diagonal already, a row
+        # a QUERY head over the lanes of the K/V head it reads
+        q_scr[...] = (q_ref[0].astype(jnp.float32) * scale).astype(q_scr.dtype)
     # the products' operands are of the pool's type (module docstring)
     precision = _PRECISION if k_buf.dtype == jnp.float32 else None
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
@@ -242,7 +248,10 @@ def _paged_decode_kernel(
 
     # l >= exp(0 - m) > 0 always: logical index 0 is <= every position
     ctx = acc_scr[...] / l_scr[:, :1]  # [H, KD]
-    out_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0, keepdims=True)
+    if group == 1:
+        out_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0, keepdims=True)
+    else:
+        out_ref[0] = ctx  # the caller keeps each query head's own K/V lanes
 
 
 def paged_attention_decode(
@@ -255,6 +264,7 @@ def paged_attention_decode(
     layer,               # int, or a traced int32 scalar (a scanned stack)
     scale: float,
     n_heads: int,
+    group: int = 1,
 ) -> Array:
     """One decode step of ragged paged attention for all slots over layer
     `layer` of the pool: [S, KD] f32 context, numerically equivalent to the
@@ -266,7 +276,19 @@ def paged_attention_decode(
 
     The pool rides in whole, in HBM, and `layer` picks the page inside the
     kernel's own copies (`_decode_layer`): slicing `k_pages[layer]` outside would make XLA
-    copy one layer of the pool per layer per step to feed the custom call."""
+    copy one layer of the pool per layer per step to feed the custom call.
+
+    `group` query heads read each K/V head (query head j reads K/V head
+    j // group): q is [S, n_heads * hd] over a pool `n_heads // group` heads
+    wide. The kernel's block-diagonal query then has a row a QUERY head over
+    the pool's lanes, laid out here (a few MB a call), and each row's own
+    K/V lanes are picked out of the kernel's [S, n_heads, KD] here too. With
+    a group of 1 the call is what it was."""
+    if group > 1:
+        return _grouped_decode(
+            q, k_pages, v_pages, block_table, positions,
+            layer=layer, scale=scale, n_heads=n_heads, group=group,
+        )
     s, kd_model = q.shape
     head_dim = kd_model // n_heads
     if kd_model % _LANES:
@@ -297,35 +319,67 @@ def paged_attention_decode(
     return out[:, 0, :kd_model]
 
 
+def _grouped_decode(q, k_pages, v_pages, block_table, positions, *, layer,
+                    scale, n_heads, group):
+    s = q.shape[0]
+    n_kv = n_heads // group
+    head_dim = q.shape[1] // n_heads
+    kd = n_kv * head_dim
+    if kd % _LANES:
+        # as above: a pool too narrow for whole lanes pays a padded copy of
+        # one layer a call
+        lanes = [(0, 0)] * 3 + [(0, -kd % _LANES)]
+        k_pages = jnp.pad(jax.lax.dynamic_slice_in_dim(k_pages, layer, 1), lanes)
+        v_pages = jnp.pad(jax.lax.dynamic_slice_in_dim(v_pages, layer, 1), lanes)
+        layer = 0
+    kd_pool = k_pages.shape[3]
+    # row (c, g) holds query head c * group + g in K/V head c's lanes
+    eye = jnp.eye(n_kv, dtype=q.dtype)
+    qd = jnp.einsum("scgd,ce->scged", q.reshape(s, n_kv, group, head_dim), eye)
+    qd = jnp.pad(qd.reshape(s, n_heads, kd), [(0, 0), (0, 0), (0, kd_pool - kd)])
+    b = _pages_per_block(
+        k_pages.shape[2], kd_pool, block_table.shape[1], k_pages.dtype.itemsize,
+    )
+    out = _decode_layer(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
+        qd, k_pages, v_pages,
+        scale=scale, n_heads=n_heads, head_dim=head_dim, pages_per_block=b,
+        interpret=interpret_mode(), group=group,
+    )
+    own = out[..., :kd].reshape(s, n_kv, group, n_kv, head_dim)
+    return jnp.einsum("scged,ce->scgd", own, jnp.eye(n_kv, dtype=out.dtype)).reshape(s, -1)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "n_heads", "head_dim", "pages_per_block", "interpret"
+        "scale", "n_heads", "head_dim", "pages_per_block", "interpret", "group"
     ),
 )
 def _decode_layer(
     layer, table, positions, q, k_pages, v_pages,
-    *, scale, n_heads, head_dim, pages_per_block, interpret,
+    *, scale, n_heads, head_dim, pages_per_block, interpret, group=1,
 ):
     """The kernel's call. The layer is DATA (a third prefetched scalar) and
     the call is jitted, so the L calls of one decode step share one trace
     and one lowering of the kernel: traced a layer at a time its unrolled
     page copies cost the served cell 9 s of warm-up (chip run, PR 30)."""
-    s, _, kd = q.shape
+    s, q_rows, kd = q.shape  # q_rows: 1, or a row a query head (grouped)
     ps = k_pages.shape[2]
     b = pages_per_block
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(s,),
         in_specs=[
-            pl.BlockSpec((1, 1, kd), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, q_rows, kd), lambda i, *_: (i, 0, 0)),
             # the ragged gather is the kernel's own: the block table
             # (prefetched to SMEM before the body runs) names the physical
             # page each of its copies fetches
             pl.BlockSpec(memory_space=pltpu.HBM),
             pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec((1, 1, kd), lambda i, *_: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, q_rows, kd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, b * ps, kd), k_pages.dtype),
             pltpu.VMEM((2, b * ps, kd), v_pages.dtype),
@@ -341,10 +395,10 @@ def _decode_layer(
         functools.partial(
             _paged_decode_kernel, scale=scale, page_size=ps,
             head_dim=head_dim, pages_per_block=b,
-            pmax=table.shape[0] // s,
+            pmax=table.shape[0] // s, group=group,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, 1, kd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s, q_rows, kd), jnp.float32),
         # sequential: the double-buffer chain runs from slot to slot
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)

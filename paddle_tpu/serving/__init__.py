@@ -15,6 +15,7 @@ master's line-JSON request-routing plane.
 
 CLI: `python -m paddle_tpu serve` (README "Serving")."""
 
+from paddle_tpu.serving.hybrid_moe_lm import HybridMoEConfig, HybridMoELM
 from paddle_tpu.serving.kv_cache import PagedKVCache
 from paddle_tpu.serving.looped_lm import LoopedLM, LoopedLMConfig, load_checkpoint
 from paddle_tpu.serving.model import LMConfig, PagedLM, ServableLM
@@ -34,6 +35,8 @@ from paddle_tpu.serving.router import Router, RouterHandle, RouterServer
 
 __all__ = [
     "PagedKVCache",
+    "HybridMoEConfig",
+    "HybridMoELM",
     "LMConfig",
     "LoopedLM",
     "LoopedLMConfig",

@@ -375,7 +375,10 @@ def load_checkpoint(path: str, mesh=None, rules=None):
     ServableLM's, as every checkpoint was before there were two."""
     with np.load(path) as z:
         arch = str(z["__arch__"]) if "__arch__" in z.files else "servable_lm"
-    classes = {"servable_lm": ServableLM, "looped_lm": LoopedLM}
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
+
+    classes = {"servable_lm": ServableLM, "looped_lm": LoopedLM,
+               "hybrid_moe_lm": HybridMoELM}
     if arch not in classes:
         raise ValueError(f"{path}: unknown served architecture {arch!r}; known: {sorted(classes)}")
     return classes[arch].load(path, mesh=mesh, rules=rules)

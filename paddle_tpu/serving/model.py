@@ -216,6 +216,32 @@ class PagedLM:
     def cache_dtype(self):
         return jnp.float32
 
+    @property
+    def kv_group(self) -> int:
+        """Query heads that read one K/V head: the pool is `n_heads //
+        kv_group` heads wide under a query of `n_heads`."""
+        return 1
+
+    @property
+    def layer_passes(self) -> int:
+        """Layer applications a token costs. Every one leaves a cache entry
+        unless the model says otherwise (a layer that keeps no K/V)."""
+        return self.cache_layers
+
+    # -- what a request holds beside K/V (the session allocates and carries it)
+    def state_spec(self) -> Dict[str, Tuple[Tuple[int, ...], object]]:
+        """name -> (shape a SLOT, dtype) of per-request state that lives in
+        no page (a recurrence's). None here: a model that declares some takes
+        and returns it, as `state` behind `v_pages`, in `decode_step` and
+        `prefill_chunk`, returns a prompt's from `prefill`, and writes that
+        into a slot in `commit_prefill_state`."""
+        return {}
+
+    def counter_spec(self) -> Dict[str, Tuple[Tuple[int, ...], object]]:
+        """name -> (shape, dtype) of counters the programs accumulate on the
+        device, carried with the state and fetched only when read."""
+        return {}
+
     # -- on-device sampling -------------------------------------------------
     def _sample(
         self,
@@ -449,11 +475,14 @@ class PagedLM:
         positions: Array,    # [S]
         layer,               # int, or a traced scalar inside a scanned stack
         n_heads: int,
+        group: int = 1,
     ) -> Array:
         """Ragged paged attention over `n_heads` heads of layer `layer` (the
         FULL head count on one chip; the LOCAL slice per shard under TP —
         heads are batched-independent, so the per-shard math is bitwise the
-        single-chip math for those heads).
+        single-chip math for those heads). `group` query heads read each K/V
+        head (query head j reads K/V head j // group): q is then `group`
+        times as wide as the pool.
 
         Two numerically-equivalent paths behind one seam: the Pallas kernel
         (ops/pallas/paged_attention.py — the block table names the pages its
@@ -473,9 +502,14 @@ class PagedLM:
 
             return paged_attention_decode(
                 q, k_pages, v_pages, block_table, positions,
-                layer=layer, scale=self.scale, n_heads=h_,
+                layer=layer, scale=self.scale, n_heads=h_, group=group,
             ).astype(q.dtype)
         ps = k_pages.shape[2]
+        if group > 1:
+            return self._grouped_attention_oracle(
+                q, k_pages[layer][block_table], v_pages[layer][block_table],
+                positions, h_ // group, group,
+            )
         qh = q.reshape(s, h_, hd)
         # dense gather: [S, P, PS, KD] -> [S, T_ctx, H, hd]
         k_seq = k_pages[layer][block_table].reshape(s, -1, h_, hd)
@@ -490,6 +524,23 @@ class PagedLM:
         w = jax.nn.softmax(sc.astype(jnp.float32), -1).astype(q.dtype)
         return jnp.einsum(
             "sht,sthd->shd", w, v_seq, preferred_element_type=jnp.float32
+        ).astype(q.dtype).reshape(s, -1)
+
+    def _grouped_attention_oracle(self, q, k_seq, v_seq, positions, n_kv, group):
+        """The dense gather path with `group` query heads a K/V head: k_seq,
+        v_seq [S, P, PS, n_kv * hd] the slot's gathered pages."""
+        s, hd = q.shape[0], self.cfg.head_dim
+        qh = q.reshape(s, n_kv, group, hd)
+        k_seq = k_seq.reshape(s, -1, n_kv, hd)
+        v_seq = v_seq.reshape(s, -1, n_kv, hd)
+        seen = jnp.arange(k_seq.shape[1])[None, :] <= positions[:, None]
+        sc = jnp.einsum(
+            "scgd,stcd->scgt", qh, k_seq, preferred_element_type=jnp.float32
+        ) * self.scale
+        sc = jnp.where(seen[:, None, None, :], sc, NEG_INF)
+        w = jax.nn.softmax(sc, -1).astype(q.dtype)
+        return jnp.einsum(
+            "scgt,stcd->scgd", w, v_seq, preferred_element_type=jnp.float32
         ).astype(q.dtype).reshape(s, -1)
 
     def _paged_attention(
@@ -515,7 +566,7 @@ class PagedLM:
         if self.mesh is None:
             return self._paged_attention_local(
                 q, k_pages, v_pages, block_table, positions,
-                layer=layer, n_heads=self.cfg.n_heads,
+                layer=layer, n_heads=self.cfg.n_heads, group=self.kv_group,
             )
         local = functools.partial(
             self._paged_attention_local,

@@ -380,6 +380,9 @@ class ServingServer:
                 out["master"] = self._master_health()
             return out
         if method == "metrics":
+            if self.session is not None:
+                # a scrape is when the model's device counters are fetched
+                self.session.read_counters()
             return {"text": obs_metrics.to_prometheus_text()}
         if method == "trace_export":
             return {"chrome_trace": obs_trace.export_chrome()}

@@ -166,6 +166,20 @@ class ServingSession:
                 "prefix_cache requires prefill_chunk: cache hits resume "
                 "prefill mid-prompt, which only the chunked path can do"
             )
+        # per-request state that lives in no page (a recurrence's): the model
+        # declares it, and what cannot work through it without snapshots of
+        # it is refused here, not built
+        declared = model.state_spec()
+        if declared and (self.prefix_cache or self.speculate_k):
+            raise ValueError(
+                f"{type(model).__name__} carries a recurrence's state "
+                f"({', '.join(sorted(declared))}) from token to "
+                "token, and the session keeps no snapshot of it: "
+                + ("prefix_cache would alias pages whose tokens the "
+                   "recurrence never ran over" if self.prefix_cache else
+                   "speculate_k rolls a rejected draft back by trimming "
+                   "pages, and the recurrence cannot be rolled back")
+            )
         # per-seq page budget covers the verify chunk's K-token overshoot
         pages_per_seq = -(-(max_ctx + self.speculate_k) // page_size)
         if num_pages is None:
@@ -194,11 +208,22 @@ class ServingSession:
             speculate_k=self.speculate_k,
         )
         self.k_pages, self.v_pages = self.cache.make_pools()
-        # layer applications a token costs (every one leaves a cache entry)
-        self.layer_passes = int(model.cache_layers)
+        # the state and the counters the model declares, [max_slots, ...] a
+        # slot's and whole the counters', carried (donated) through its
+        # programs beside the pools; None for a model that declares neither
+        self.state = self._make_state()
+        self._counters_read: Dict = {}
+        # layer applications a token costs, and the K/V entries it leaves:
+        # two declarations (a layer that keeps a recurrence leaves no K/V)
+        self.layer_passes = int(model.layer_passes)
         obs_metrics.set_kv_bytes_per_token(
-            2 * self.layer_passes * model.cache_width * self.k_pages.dtype.itemsize
+            2 * int(model.cache_layers) * model.cache_width
+            * self.k_pages.dtype.itemsize
         )
+        obs_metrics.set_recurrent_state_bytes_per_slot(sum(
+            int(np.prod(shape)) * np.dtype(dtype).itemsize
+            for shape, dtype in model.state_spec().values()
+        ))
 
         # warmup detection (ISSUE 17): each wrapped body runs ONLY while jax
         # traces it — exactly once per new input signature per executable,
@@ -218,13 +243,20 @@ class ServingSession:
         # the executables; jit's shape cache turns the bucket list into
         # "a few padded lengths" -> a few compiles, decode into exactly one,
         # and the chunk program ([1, C] fixed shape) into exactly one more
+        # (a model with state takes and returns it behind the pools, donated
+        # with them, and commits a prompt's through commit_prefill_state)
+        held = (3,) if self.state is not None else ()
         self._decode = jax.jit(_traced(model.decode_step),
-                               donate_argnums=(1, 2))
+                               donate_argnums=(1, 2) + held)
         self._prefill = jax.jit(_traced(model.prefill))
-        self._commit = jax.jit(_traced(model.commit_prefill),
-                               donate_argnums=(0, 1))
+        self._commit = (
+            jax.jit(_traced(model.commit_prefill), donate_argnums=(0, 1))
+            if self.state is None else
+            jax.jit(_traced(model.commit_prefill_state),
+                    donate_argnums=(0, 1, 2))
+        )
         self._prefill_chunk = jax.jit(_traced(model.prefill_chunk),
-                                      donate_argnums=(1, 2))
+                                      donate_argnums=(1, 2) + held)
         # the verify executable only exists when speculation is on: K=0
         # compiles nothing and the engine step never calls _speculate's body
         self._verify = (
@@ -476,18 +508,28 @@ class ServingSession:
                     toks = np.zeros((1, bucket), np.int32)
                     toks[0, : len(act.prompt)] = act.prompt
                     lengths = np.array([len(act.prompt)], np.int32)
-                    first_tok, kc, vc = self._prefill(
+                    first_tok, *kept = self._prefill(
                         self.params, toks, lengths, seeds, temps, top_ks
                     )
                     rows = self.cache.slot_row(slot)
                     # tp-ok: per-ADMISSION placement of one request's commit
                     # operands (never per decode step); the block table the
                     # decode loop uses rides the jit dispatch untouched
-                    self.k_pages, self.v_pages = self._commit(
-                        self.k_pages, self.v_pages, kc, vc,
+                    where = (
                         jnp.asarray(lengths), jnp.asarray(rows),
                         jnp.zeros((1,), jnp.int32),
                     )
+                    if self.state is None:
+                        self.k_pages, self.v_pages = self._commit(
+                            self.k_pages, self.v_pages, *kept, *where
+                        )
+                    else:
+                        # the prompt's final state goes WHOLE into the slot:
+                        # nothing of its last tenant's survives an admission
+                        self.k_pages, self.v_pages, self.state = self._commit(
+                            self.k_pages, self.v_pages, self.state, *kept,
+                            *where, np.array([slot], np.int32),
+                        )
                     # one tiny host fetch per ADMISSION (not per decode step):
                     # the prompt's first token — sampled on device (a replay
                     # re-derives the one its handle already has)
@@ -552,9 +594,8 @@ class ServingSession:
                 ):
                     # ONE dispatch per chunk: forward + commit fused, pages
                     # donated through (see model.prefill_chunk docstring)
-                    self.k_pages, self.v_pages, tok = self._prefill_chunk(
-                        self.params, self.k_pages, self.v_pages, toks,
-                        starts, lengths, rows, seeds, temps, top_ks,
+                    tok = self._dispatch_chunk(
+                        slot, toks, starts, lengths, rows, seeds, temps, top_ks
                     )
             act.prefill_pos = min(start + c, len(act.prompt))
             # incremental registration (ISSUE 19): every full prompt page
@@ -599,6 +640,77 @@ class ServingSession:
                 SERVING_EVENTS.incr("serving_preemptions")
                 obs_metrics.observe_preemption()
         return {slot for slot, _, _ in preempted}
+
+    def _make_state(self):
+        """Zeroed per-slot state [max_slots, ...] and counters, as the model
+        declares them; None where it declares neither. Rebuilt with the
+        pools on an engine restart (the replayed prompts rewrite it)."""
+        import jax.numpy as jnp
+
+        slots = self.cache.max_slots
+        state = {k: jnp.zeros((slots,) + tuple(shape), dtype)
+                 for k, (shape, dtype) in self.model.state_spec().items()}
+        state.update({k: jnp.zeros(shape, dtype)
+                      for k, (shape, dtype) in self.model.counter_spec().items()})
+        return state or None
+
+    def _dispatch_decode(self, *lanes):
+        """The decode executable over the carried pools (and state): the
+        sampled tokens, still on the device."""
+        if self.state is None:
+            self.k_pages, self.v_pages, tok = self._decode(
+                self.params, self.k_pages, self.v_pages, *lanes
+            )
+        else:
+            self.k_pages, self.v_pages, self.state, tok = self._decode(
+                self.params, self.k_pages, self.v_pages, self.state, *lanes
+            )
+        return tok
+
+    def _dispatch_chunk(self, slot, toks, starts, lengths, rows, *sampling):
+        """The chunk executable, likewise; a model with state continues the
+        slot's own (from the empty state at a prompt's first chunk)."""
+        if self.state is None:
+            self.k_pages, self.v_pages, tok = self._prefill_chunk(
+                self.params, self.k_pages, self.v_pages, toks, starts,
+                lengths, rows, *sampling,
+            )
+        else:
+            self.k_pages, self.v_pages, self.state, tok = self._prefill_chunk(
+                self.params, self.k_pages, self.v_pages, self.state, toks,
+                starts, lengths, rows, np.array([slot], np.int32), *sampling,
+            )
+        return tok
+
+    def read_counters(self) -> Dict[str, np.ndarray]:
+        """The model's device counters, fetched NOW (never by a step), as
+        totals since the session began: each read adds what the device
+        counted since the last one, modulo the counter's 2**32, to a host
+        total, and feeds the difference to obs.metrics. Off the hot loop:
+        a scrape's, a benchmark reader's, a test's."""
+        state = self.state
+        if state is None:
+            return {}
+        out = {}
+        for name in self.model.counter_spec():
+            try:
+                now = np.asarray(state[name])
+            except RuntimeError:
+                # read from another thread than the engine's while a step
+                # donated the buffer: this read adds nothing, the next one
+                # finds what was counted meanwhile
+                now = None
+            last, total = self._counters_read.get(name, (None, None))
+            if last is None and now is not None:
+                last, total = np.zeros_like(now), np.zeros(now.shape, np.int64)
+            if now is not None:
+                delta = (now - last).astype(np.int64)  # unsigned: wraps as the device's
+                total = total + delta
+                self._counters_read[name] = (now, total)
+                obs_metrics.observe_moe_counters(name, delta)
+            if total is not None:
+                out[name] = total
+        return out
 
     def _drafter_for(self, slot: int, act):
         """This slot's (drafter, adaptive-K cell), rebuilt when the slot was
@@ -801,9 +913,8 @@ class ServingSession:
         with trace.flight(
             "serve.decode", slots=len(active), layer_passes=self.layer_passes
         ), trace.span("serving.decode_step", active=len(active)):
-            self.k_pages, self.v_pages, next_tok = self._decode(
-                self.params, self.k_pages, self.v_pages,
-                tokens, positions, act_mask, bt, seeds, steps, temps, top_ks,
+            next_tok = self._dispatch_decode(
+                tokens, positions, act_mask, bt, seeds, steps, temps, top_ks
             )
             # sync-ok: the ONE sanctioned fetch in the serving hot loop — the
             # sampled token ids, which the autoregressive loop needs on host to
@@ -1048,6 +1159,14 @@ class ServingSession:
         requeued, expired = self.scheduler.requeue_active(t0)
         self.cache.reset()
         self.k_pages, self.v_pages = self.cache.make_pools()
+        if self.state is not None:
+            # what was read so far stays counted; what the dead engine's
+            # (consumed) buffers counted since the last read is lost
+            self.state = self._make_state()
+            self._counters_read = {
+                k: (np.zeros_like(last), total)
+                for k, (last, total) in self._counters_read.items()
+            }
         # drafters are derived state: replayed requests regrow them from
         # the prompt (deterministically — same drafts, same acceptances)
         self._drafters.clear()
@@ -1135,9 +1254,10 @@ class ServingSession:
 
         s = self.cache.max_slots
         i32, f32 = np.zeros(s, np.int32), np.zeros(s, np.float32)
+        held = () if self.state is None else (jax.tree.map(aval, self.state),)
         return self._decode.lower(
             jax.tree.map(aval, self.params), aval(self.k_pages),
-            aval(self.v_pages), i32, i32, np.zeros(s, bool),
+            aval(self.v_pages), *held, i32, i32, np.zeros(s, bool),
             self.cache.block_table(), np.zeros(s, np.uint32), i32, f32, i32,
         ).compile().as_text()
 
@@ -1158,6 +1278,8 @@ class ServingSession:
             "pool_bytes_per_chip": stats.per_chip_tree_bytes(
                 [self.k_pages, self.v_pages]
             ),
+            # what the model's declared state holds beside the pools
+            "state_bytes_per_chip": stats.per_chip_tree_bytes(self.state or []),
             "tokens_generated": self.tokens_generated,
             "decode_shape_signatures": self.decode_shape_signatures(),
             "queue_depth": sch.queue_depth(),

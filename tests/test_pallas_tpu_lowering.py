@@ -19,6 +19,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import paged_attention, rnn_kernels
@@ -289,3 +290,60 @@ def test_the_looped_decode_step_is_one_layer_body_scanned_in_place(on_chip, monk
     pool_bytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
     assert memory.alias_size_in_bytes == 2 * pool_bytes
     assert memory.temp_size_in_bytes < 64e6
+
+
+# -- the hybrid decoder's decode step, at granite-4.0-h-small's cut ------------
+
+def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, monkeypatch):
+    """HybridMoELM.decode_step at granite_4_0_h_small's shapes (nine Mamba-2
+    layers in two scanned runs round one attention layer, 36 of 72 experts a
+    layer, bfloat16, 64 slots of float32 state) compiled for the described
+    v5e: the grouped-head paged-attention kernel passes Mosaic, the grouped
+    expert products take the WHOLE expert stacks (no instruction of a
+    layer's experts' shape or of the state's is a copy or a slice cut for
+    them: either is a third of the step), pools and state are the outputs'
+    buffers, and the temporaries are megabytes."""
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoEConfig, HybridMoELM
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
+    model = HybridMoELM(HybridMoEConfig(
+        vocab=50176, layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+        d_model=4096, n_heads=32, n_kv_heads=8, head_dim=128, mamba_heads=128,
+        mamba_head_dim=64, mamba_state=128, num_experts_routed=72,
+        experts_held=tuple(range(36)), top_k=10, expert_width=768, shared_width=1536,
+        attention_multiplier=1.0 / 128, max_len=2048,
+    ))
+    slots = 64
+    pool = (model.cache_layers, 8193, 16, model.cache_width)
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    params = jax.tree.map(
+        lambda a: aval(a.shape, a.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+    )
+    state = {k: aval((slots,) + s, d) for k, (s, d) in model.state_spec().items()}
+    state.update({k: aval(s, d) for k, (s, d) in model.counter_spec().items()})
+    s = (slots,)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1, 2, 3)).lower(
+        params, aval(pool, jnp.bfloat16), aval(pool, jnp.bfloat16), state,
+        aval(s, jnp.int32), aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval((slots, 128), jnp.int32), aval(s, jnp.uint32), aval(s, jnp.int32),
+        aval(s, jnp.float32), aval(s, jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert pool == (1, 8193, 16, 1024) and 'paged_attention_decode' in text
+    cut = [
+        line.strip()[:140] for line in text.splitlines()
+        if re.search(r"= (bf16\[36,(4096,1536|768,4096)\]|f32\[64,(9,)?128,64,128\])\S* "
+                     r"(copy|fusion|dynamic-slice|slice)\(", line)
+        and "dynamic-update-slice" not in line.split("=")[0]
+    ]
+    assert not cut, cut
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in state.values())
+    # (the tail's three rows are laid out padded: at least, and within a tenth)
+    expected = 2 * 2 * int(np.prod(pool)) + held
+    assert expected <= memory.alias_size_in_bytes < 1.1 * expected
+    assert memory.argument_size_in_bytes < 12.6e9 and memory.temp_size_in_bytes < 64e6
