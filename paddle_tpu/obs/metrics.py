@@ -367,6 +367,26 @@ def observe_replayed_tokens(n: int) -> None:
     ).inc(n)
 
 
+def observe_decode_overlapped() -> None:
+    """A decode step was dispatched while the step before it was still
+    unfetched (ISSUE 36): beside `serving_decode_steps` the share of steps
+    the device ran under the host's work and not beside it."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_decode_overlapped_steps_total",
+        "decode steps dispatched before the previous step's tokens were fetched",
+    ).inc()
+
+
+def observe_wasted_lanes(n: int) -> None:
+    """`n` decode lanes' tokens were dropped at the fetch: their requests had
+    left their slots (an EOS a step earlier, a cancel, an expiry) after the
+    step was dispatched. One lane-step each, never a token."""
+    REGISTRY.counter(
+        "paddle_tpu_serving_wasted_lane_steps_total",
+        "decode lanes dispatched for a request that had already finished or left",
+    ).inc(n)
+
+
 def set_kv_pages_in_use(n: int) -> None:
     """Pages of the KV pool some slot or the prefix index holds right now,
     as of the last engine step."""

@@ -346,6 +346,17 @@ class PagedKVCache:
         self._table[slot, have:have + need] = pages[have:]
         return True
 
+    def can_grow(self, wants) -> bool:
+        """Would `grow` give every slot of `wants` [(slot, tokens its pages
+        must cover)] its pages with no preemption? The free list answers on
+        every step but a few; the prefix index is asked what it could evict
+        only when the free list is short. Host ints, nothing changed."""
+        need = sum(
+            max(0, self.pages_needed(total) - len(self._slot_pages[slot]))
+            for slot, total in wants
+        )
+        return need <= len(self._free) or need <= self._available()
+
     def trim(self, slot: int, total_len: int) -> int:
         """Release the slot's surplus tail pages beyond what `total_len`
         tokens need (speculative-decode rollback, ISSUE 16): a verify round
@@ -451,14 +462,18 @@ class PagedKVCache:
         return d
 
     def block_table(self) -> np.ndarray:
-        """The [max_slots, max_pages_per_seq] int32 table (live view — copy
-        is taken by the device transfer itself). On TPU this same table is
+        """The [max_slots, max_pages_per_seq] int32 table, as a SNAPSHOT: the
+        engine releases and regrows rows while the step it dispatched with
+        this table may still be waiting for its operands (ISSUE 36), and a
+        host array handed to a dispatch must not change under it. On TPU
+        this same table is
         the SCALAR-PREFETCH operand of the ragged paged-attention kernel
         (ops/pallas/paged_attention.py): its rows name the physical page of
         each copy the kernel issues, a block of pages at a time."""
-        return self._table
+        return self._table.copy()
 
     def slot_row(self, slot: int) -> np.ndarray:
         """One slot's [1, max_pages_per_seq] block-table row — the shape the
-        per-slot prefill/commit/chunk executables take (live view)."""
-        return self._table[slot : slot + 1]
+        per-slot prefill/commit/chunk executables take (a snapshot, as
+        `block_table` is)."""
+        return self._table[slot : slot + 1].copy()

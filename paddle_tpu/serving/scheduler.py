@@ -221,11 +221,18 @@ class ActiveSeq:
     is ahead of `generated`, and until they meet `append` takes the known
     token in place of the sampled one (they are equal: the replay runs the
     same programs on the same inputs), so the slot's K/V is rebuilt position
-    by position and nothing reaches the handle twice."""
+    by position and nothing reaches the handle twice.
+
+    The engine keeps ONE decode step in flight (ISSUE 36): `in_flight` is 1
+    while a step this sequence rides has been dispatched and its sampled
+    token not yet fetched. `generated`, `next_pos` and `last_token` then lag
+    that step by one; the session builds the next step's lanes from them
+    plus `in_flight` (host integers that advance by one whatever was
+    sampled) and `append` catches them up when the token is fetched."""
 
     __slots__ = ("handle", "prompt", "last_token", "next_pos", "generated",
                  "t_started", "prefill_pos", "engine_steps", "prefix_hit",
-                 "admit_seq", "preempted_s")
+                 "admit_seq", "preempted_s", "in_flight")
 
     def __init__(self, handle: RequestHandle, prompt: List[int]):
         self.handle = handle
@@ -250,6 +257,9 @@ class ActiveSeq:
         # >1 token per step, `generated` stops being a step count — the
         # retire-time EWMA prices steps off THIS when speculation is on
         self.engine_steps: int = 0
+        # decode steps dispatched for this sequence and not yet fetched:
+        # 0 or 1 between two engine steps
+        self.in_flight: int = 0
 
     @property
     def prefilling(self) -> bool:

@@ -38,6 +38,20 @@ stream results — and, since ISSUE 10, exactly ONE wall-clock read per step
 check). tests/test_lint_hotloop.py lints this loop body the same way it
 lints the train loop.
 
+The engine keeps ONE decode step in flight (ISSUE 36): `step()` dispatches
+decode step N while step N-1's sampled tokens are still on the device and
+fetches N-1's AFTER that dispatch (`_decode_once`, `_collect`), so the
+fetch, the per-slot bookkeeping, the stream wake-up, the caller's work
+between two `step()` calls, the reap and the next lanes' building run UNDER
+the device's step. A lane whose token the host does not know yet takes it on
+the device (`decode_step_in_flight`); its position, sampling index, budget
+and pages are host integers that advance by one whatever was sampled. What
+is learned a step late (an EOS, a cancel, an expiry) costs one lane-step,
+`stats()["wasted_lanes"]`, never a token. What needs the VALUES drains the
+step first, which is the old order: a speculation round, a dry pool about to
+preempt, an engine restart, `stop()`. `stats()["overlapped_steps"]` beside
+`decode_steps` says how often the new order engaged.
+
 KV pages (ISSUE 34) are handed out as tokens are written: an admission gets
 its prompt's pages, and before each decode step or verify round every slot
 that writes is grown by the page its write crosses into (`_ensure_pages`: a
@@ -97,6 +111,27 @@ def _bucket_for(buckets: Sequence[int], n: int) -> int:
         if n <= b:
             return b
     raise ValueError(f"prompt of {n} tokens exceeds largest bucket {buckets[-1]}")
+
+
+def decode_step_in_flight(model: PagedLM):
+    """`model.decode_step` behind the one thing the SESSION adds to it (ISSUE
+    36), as the function the session jits: after the params, the pools (and
+    the state the model declares) come `tokens`, `prev_tok`, `from_prev`,
+    then the model's other lanes. A lane whose last token is still on the
+    device takes it from `prev_tok`, the array the step before returned; the
+    others take the host's. Two [max_slots] operands, data like the rest:
+    one signature, and the models' `decode_step` signatures unchanged."""
+    carried = 3 + bool(model.state_spec() or model.counter_spec())
+
+    def decode_step(*operands):
+        import jax.numpy as jnp
+
+        tokens, prev_tok, from_prev, *lanes = operands[carried:]
+        return model.decode_step(
+            *operands[:carried], jnp.where(from_prev, prev_tok, tokens), *lanes
+        )
+
+    return decode_step
 
 
 class ServingSession:
@@ -246,7 +281,7 @@ class ServingSession:
         # (a model with state takes and returns it behind the pools, donated
         # with them, and commits a prompt's through commit_prefill_state)
         held = (3,) if self.state is not None else ()
-        self._decode = jax.jit(_traced(model.decode_step),
+        self._decode = jax.jit(_traced(decode_step_in_flight(model)),
                                donate_argnums=(1, 2) + held)
         self._prefill = jax.jit(_traced(model.prefill))
         self._commit = (
@@ -274,6 +309,18 @@ class ServingSession:
         # drafts, starts and sampling identity are data, never shape)
         self.verify_recompiles = stats.RecompileStats(warn_threshold=2)
         self.decode_steps = 0
+        # the decode step in flight (ISSUE 36): (its sampled tokens, still
+        # on the device; the [(slot, ActiveSeq)] lanes it ran), or None.
+        # `_prev_tok` is the token array the last decode dispatch returned,
+        # fetched or not: the next dispatch's `prev_tok` operand
+        self._in_flight: Optional[tuple] = None
+        self._prev_tok = self._no_tokens()
+        # decode dispatches made with the previous step's tokens unfetched,
+        # and lanes whose token was dropped at the fetch because the
+        # request had gone since the dispatch (EOS a step earlier, a cancel,
+        # an expiry): one lane-step each, never a token
+        self.overlapped_steps = 0
+        self.wasted_lanes = 0
         self.tokens_generated = 0
         self.prefill_chunks_committed = 0
         self._chunk_rr_slot = -1  # round-robin cursor over prefilling slots
@@ -532,8 +579,11 @@ class ServingSession:
                         )
                     # one tiny host fetch per ADMISSION (not per decode step):
                     # the prompt's first token — sampled on device (a replay
-                    # re-derives the one its handle already has)
-                    fresh = act.append(int(first_tok[0]))
+                    # re-derives the one its handle already has). The ARRAY
+                    # is fetched, as the prefill left it: indexing it on the
+                    # device first would queue an op behind the commit, and
+                    # the host would sleep through the commit it can work under
+                    fresh = act.append(int(np.asarray(first_tok)[0]))
             # the whole prompt is committed: register its full pages into
             # the tenant's prefix chain (no-op with the cache off)
             self.cache.commit_prefix(slot, h.tenant, act.prompt,
@@ -615,7 +665,7 @@ class ServingSession:
                 # step) — the FINAL chunk's sampled first token, which the
                 # autoregressive loop needs on host; intermediate chunks
                 # never fetch (their `tok` stays device-resident and unused)
-                if act.append(int(tok[0])):
+                if act.append(int(np.asarray(tok)[0])):
                     self._observe_ttft(h, h.trace_ctx)
                 SERVING_EVENTS.incr("serving_prefills")
                 reason = act.finished(self.cfg.eos_id)
@@ -641,6 +691,21 @@ class ServingSession:
                 obs_metrics.observe_preemption()
         return {slot for slot, _, _ in preempted}
 
+    def _no_tokens(self):
+        """`prev_tok` for a decode dispatch no step went before (the first,
+        an engine restart's): zeros no lane reads, placed as a decode step's
+        tokens come out (replicated over a TP mesh, plainly on one chip), so
+        that dispatch runs the one executable and no second lowering of it."""
+        import jax
+        import jax.numpy as jnp
+
+        zeros = jnp.zeros((self.cache.max_slots,), jnp.int32)
+        if self.model.mesh is None:
+            return zeros
+        return jax.device_put(zeros, jax.sharding.NamedSharding(
+            self.model.mesh, jax.sharding.PartitionSpec()
+        ))
+
     def _make_state(self):
         """Zeroed per-slot state [max_slots, ...] and counters, as the model
         declares them; None where it declares neither. Rebuilt with the
@@ -656,7 +721,8 @@ class ServingSession:
 
     def _dispatch_decode(self, *lanes):
         """The decode executable over the carried pools (and state): the
-        sampled tokens, still on the device."""
+        sampled tokens, still on the device, where `_collect` finds them
+        one dispatch later."""
         if self.state is None:
             self.k_pages, self.v_pages, tok = self._decode(
                 self.params, self.k_pages, self.v_pages, *lanes
@@ -749,6 +815,9 @@ class ServingSession:
         advanced: set = set()
         if not self.speculate_k:
             return advanced
+        # the drafters read the handles' tokens on the host: the step in
+        # flight ends before the round
+        self._drain()
         # a replaying slot rebuilds its K/V through the decode lanes first
         candidates = [
             (slot, act) for slot, act in self.scheduler.active_slots()
@@ -850,33 +919,63 @@ class ServingSession:
                 )
         return advanced
 
+    def _decode_lanes(self, skip: frozenset) -> list:
+        """[(slot, ActiveSeq)] the next decode step runs: every occupied,
+        fully-prefilled slot outside `skip` that still has a token to draw.
+        A lane whose step in flight reaches its budget is not dispatched
+        again, so a length finish wastes nothing."""
+        return [
+            (slot, act) for slot, act in self.scheduler.active_slots()
+            if not act.prefilling and slot not in skip
+            and act.generated + act.in_flight < act.handle.max_new_tokens
+        ]
+
     def _decode_once(self, skip: frozenset = frozenset()) -> None:
         """One continuous-batching decode step: every active, fully-prefilled
         slot advances by one token inside the single fixed-shape executable
         (slots mid-chunked-prefill sit this one out as inactive lanes — their
         KV is still being committed; slots in `skip` already advanced through
-        a speculative verify round this step)."""
-        active = [
-            (slot, act) for slot, act in self.scheduler.active_slots()
-            if not act.prefilling and slot not in skip
-        ]
+        a speculative verify round this step).
+
+        The step is DISPATCHED, and the tokens fetched are those of the step
+        dispatched before it (`_collect`), so the device runs this step
+        under everything the host does until the next dispatch. A lane with
+        a step in flight is built from what the host knows without that
+        step's token: position, sampling index and pages one further, the
+        token itself taken on the device."""
+        def writes(lanes):
+            # every lane writes the position behind its last token (the one
+            # in flight counted): the page it lands in first
+            return [(slot, act.next_pos + act.in_flight + 1)
+                    for slot, act in lanes]
+
+        active = self._decode_lanes(skip)
+        if self._in_flight is not None and not self.cache.can_grow(
+            writes(active)
+        ):
+            # the pool is about to preempt: the step in flight ends first, so
+            # that the victim's last token is on its handle before it
+            # replays, and an EOS among those tokens gives its pages back
+            # before anybody is preempted for want of them
+            self._drain()
+            active = self._decode_lanes(skip)
         if not active:
+            # nothing to run ahead of: the step in flight, if any, ends here
+            self._drain()
             return
         if _faults.get().active:
             # chaos site: the engine faults mid-decode — the supervisor must
             # restart it, re-init the page pool and replay in-flight work;
             # gated on live slots so step=N counts real decode attempts
             _faults.get().maybe_raise("decode_raise")
-        # every lane writes position next_pos: the page it lands in first
-        lost = self._ensure_pages(
-            [(slot, act.next_pos + 1) for slot, act in active]
-        )
+        lost = self._ensure_pages(writes(active))
         if lost:
             active = [sa for sa in active if sa[0] not in lost]
             if not active:
                 return
         s = self.cache.max_slots
         tokens = np.zeros(s, np.int32)
+        from_prev = np.zeros(s, bool)
         positions = np.zeros(s, np.int32)
         act_mask = np.zeros(s, bool)
         seeds = np.zeros(s, np.uint32)
@@ -884,22 +983,31 @@ class ServingSession:
         temps = np.zeros(s, np.float32)
         top_ks = np.zeros(s, np.int32)
         for slot, act in active:
-            tokens[slot] = act.last_token
-            positions[slot] = act.next_pos
+            ahead = act.in_flight
+            if not ahead:
+                tokens[slot] = act.last_token
+            elif act.replaying:
+                # the token the step in flight rebuilds: the handle has it
+                tokens[slot] = act.handle.tokens[act.generated]
+            else:
+                from_prev[slot] = True  # sampled by the step in flight
+            positions[slot] = act.next_pos + ahead
             act_mask[slot] = True
             # sampling identity rides as DATA: the token this step emits for
             # the slot is draw `generated` of request `seed` — exactly what a
             # crash replay re-draws (bitwise), and still one decode signature
             seeds[slot] = act.handle.seed
-            steps[slot] = act.generated
+            steps[slot] = act.generated + ahead
             temps[slot] = act.handle.temperature
             top_ks[slot] = act.handle.top_k
         bt = self.cache.block_table()
+        prev_tok = self._prev_tok
         # zero-recompile assertion data: the decode signature must be the
         # same every step no matter the request mix (fixed [max_slots] shape)
         self.recompiles.record(
             stats.batch_signature(
-                {"tokens": tokens, "positions": positions, "active": act_mask,
+                {"tokens": tokens, "prev_tok": prev_tok, "from_prev": from_prev,
+                 "positions": positions, "active": act_mask,
                  "block_table": bt, "seeds": seeds, "steps": steps,
                  "temps": temps, "top_ks": top_ks}
             )
@@ -908,44 +1016,100 @@ class ServingSession:
         # I/O or string formatting on the decode hot path; a no-op truth
         # test when PADDLE_TPU_TRACE is off (tests/test_lint_hotloop.py)
         # span-ok: the flight recorder's one ring write a decode step, int
-        # attrs: the dispatch and the fetch, and the batch the step's weight
-        # traffic is shared over (perfbench reads `slots`)
+        # attrs: the dispatch and the fetch of the step BEFORE it, and the
+        # batch the step's weight traffic is shared over (perfbench: `slots`)
         with trace.flight(
             "serve.decode", slots=len(active), layer_passes=self.layer_passes
         ), trace.span("serving.decode_step", active=len(active)):
             next_tok = self._dispatch_decode(
-                tokens, positions, act_mask, bt, seeds, steps, temps, top_ks
+                tokens, prev_tok, from_prev, positions, act_mask, bt, seeds,
+                steps, temps, top_ks,
             )
+            for _, act in active:
+                act.in_flight += 1
+            before, self._in_flight = self._in_flight, (next_tok, active)
+            self._prev_tok = next_tok
+            self.decode_steps += 1
+            SERVING_EVENTS.incr("serving_decode_steps")
+            obs_metrics.observe_decode_step(len(active), self.layer_passes)
+            if before is not None:
+                self.overlapped_steps += 1
+                SERVING_EVENTS.incr("serving_decode_overlapped_steps")
+                obs_metrics.observe_decode_overlapped()
+                self._collect(before)
+
+    def _drain(self) -> None:
+        """End the step in flight now: what reads the sampled VALUES on the
+        host calls this before it dispatches anything more, and a step that
+        found nothing to dispatch ends here. No-op with nothing in flight."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            self._collect(flight)
+
+    def _collect(self, flight: tuple) -> None:
+        """Fetch one decode step's sampled tokens and hand each lane's to its
+        request: `append`, `finished`, `retire`. A lane whose request left
+        its slot since the dispatch (an EOS in the step before, a cancel or
+        an expiry reaped meanwhile) is DROPPED: its token never reaches a
+        handle. That lane's K/V write, and in a model with state its state
+        update, landed in a page and a slot the request still held when the
+        step was dispatched, with the block table of that moment; whoever
+        is given the page or the slot next writes it by a commit or a step
+        dispatched LATER on the same device stream, and reads nothing there
+        that it did not write itself, so the order is safe."""
+        next_tok, lanes = flight
+        slots = self.scheduler.slots
+        live = [(slot, act) for slot, act in lanes if slots[slot] is act]
+        for _, act in lanes:
+            act.in_flight -= 1
+        wasted = len(lanes) - len(live)
+        replayed = 0
+        if live:
             # sync-ok: the ONE sanctioned fetch in the serving hot loop — the
             # sampled token ids, which the autoregressive loop needs on host to
             # detect EOS/budget and stream tokens; everything else stays device-
             # resident (pages are donated through, logits never leave the device)
             toks = np.asarray(next_tok)
-        self.decode_steps += 1
-        SERVING_EVENTS.incr("serving_decode_steps")
-        obs_metrics.observe_decode_step(len(active), self.layer_passes)
-        replayed = 0
-        for slot, act in active:
-            if act.append(toks[slot]):
-                self.tokens_generated += 1
-            else:
-                replayed += 1  # a preempted request's K/V, rebuilt
-            act.engine_steps += 1
-            reason = act.finished(self.cfg.eos_id)
-            if reason is not None:
-                self._drafters.pop(slot, None)
-                self.scheduler.retire(slot, reason)
+            for slot, act in live:
+                if act.append(toks[slot]):
+                    self.tokens_generated += 1
+                else:
+                    replayed += 1  # a preempted request's K/V, rebuilt
+                act.engine_steps += 1
+                reason = act.finished(self.cfg.eos_id)
+                if reason is not None:
+                    self._drafters.pop(slot, None)
+                    self.scheduler.retire(slot, reason)
+        behind = self._in_flight
+        if behind is not None and not any(
+            slots[slot] is act for slot, act in behind[1]
+        ):
+            # the step dispatched behind this one has lost its every lane to
+            # what was just learned: nobody waits for it, so it is not
+            # fetched, and nothing stays in flight on an idle engine
+            self._in_flight = None
+            for _, act in behind[1]:
+                act.in_flight -= 1
+            wasted += len(behind[1])
         if replayed:
             self.replayed_tokens += replayed
             SERVING_EVENTS.incr("serving_replayed_tokens", replayed)
             obs_metrics.observe_replayed_tokens(replayed)
+        if wasted:
+            self.wasted_lanes += wasted
+            SERVING_EVENTS.incr("serving_wasted_lane_steps", wasted)
+            obs_metrics.observe_wasted_lanes(wasted)
 
     def step(self, now: Optional[float] = None) -> bool:
         """One engine iteration: reap expired/cancelled requests, then
         retire/admit at the boundary, then one prefill chunk per prefilling
         slot, then one decode step — chunked prefill and decode INTERLEAVE
-        inside every engine step rather than alternate across them. Returns
-        True when any work was done."""
+        inside every engine step rather than alternate across them. The
+        decode step is dispatched and left in flight; the tokens this call
+        hands to the handles are those of the step the call BEFORE it
+        dispatched (an admission's prefill and a chunk are dispatched behind
+        the step in flight, and their own first-token fetch waits for both).
+        Returns True when any work was done."""
         if now is None:
             # clock-ok: the ONE sanctioned wall-clock read per engine step —
             # deadline expiry, cancellation reaping and admission stamps all
@@ -954,6 +1118,7 @@ class ServingSession:
             now = time.monotonic()
         self._last_progress = now  # supervisor stall-watchdog heartbeat
         traces_before = self._jit_traces
+        in_flight = self._in_flight is not None  # dispatched on, or collected
         self.scheduler.reap(now)
         self._admit(now)
         self._prefill_chunks()
@@ -976,6 +1141,7 @@ class ServingSession:
         return (
             self.decode_steps != before
             or self.spec_rounds != spec_before
+            or in_flight
             or bool(self.scheduler.active_slots())
         )
 
@@ -1156,6 +1322,14 @@ class ServingSession:
         self.engine_restarts += 1
         SERVING_EVENTS.incr("serving_engine_restarts")
         obs_metrics.observe_engine_restart(cause)
+        # the step in flight ends first, as far as the dead engine left its
+        # tokens readable: a request it finished is complete and is not
+        # replayed; the others replay from their prompts whatever it sampled
+        try:
+            self._drain()
+        except Exception:  # noqa: BLE001 — the step died with the engine,
+            pass           # and _drain had let go of it before it fetched
+        self._prev_tok = self._no_tokens()
         requeued, expired = self.scheduler.requeue_active(t0)
         self.cache.reset()
         self.k_pages, self.v_pages = self.cache.make_pools()
@@ -1187,6 +1361,7 @@ class ServingSession:
         so result() raises instead of timing out; pages are released for
         accounting hygiene even though the engine is done."""
         sch = self.scheduler
+        self._in_flight = None  # nobody is left to take its tokens
         with sch.lock:
             waiting = list(sch.waiting)
             sch.waiting.clear()
@@ -1211,6 +1386,13 @@ class ServingSession:
             self._work.notify_all()
         if self._thread is not None:
             self._thread.join(timeout=10.0)
+        if self._thread is None or not self._thread.is_alive():
+            # nothing stays in flight behind a stopped engine: the last
+            # step's tokens reach their handles
+            try:
+                self._drain()
+            except Exception:  # noqa: BLE001 — the engine died holding it,
+                pass           # and _drain had let go of it before it fetched
 
     def cancel_tenant(self, tenant: str) -> int:
         return self.scheduler.cancel_tenant(tenant)
@@ -1254,11 +1436,13 @@ class ServingSession:
 
         s = self.cache.max_slots
         i32, f32 = np.zeros(s, np.int32), np.zeros(s, np.float32)
+        lane = np.zeros(s, bool)
         held = () if self.state is None else (jax.tree.map(aval, self.state),)
         return self._decode.lower(
             jax.tree.map(aval, self.params), aval(self.k_pages),
-            aval(self.v_pages), *held, i32, i32, np.zeros(s, bool),
-            self.cache.block_table(), np.zeros(s, np.uint32), i32, f32, i32,
+            aval(self.v_pages), *held, i32, aval(self._prev_tok), lane, i32,
+            lane, self.cache.block_table(), np.zeros(s, np.uint32), i32, f32,
+            i32,
         ).compile().as_text()
 
     def verify_shape_signatures(self) -> int:
@@ -1271,6 +1455,12 @@ class ServingSession:
         sch = self.scheduler
         return {
             "decode_steps": self.decode_steps,
+            # ISSUE 36: decode steps dispatched with the step before still
+            # unfetched (over decode_steps: the share the device ran under
+            # the host's work), and lanes dropped at a fetch because their
+            # request had gone since the dispatch
+            "overlapped_steps": self.overlapped_steps,
+            "wasted_lanes": self.wasted_lanes,
             # TP accounting from SHARDING METADATA, not trust: what one chip
             # actually holds (replicated leaves count fully, sharded 1/N)
             "tp": self.model.tp_size,

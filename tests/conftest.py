@@ -38,6 +38,20 @@ def pytest_terminal_summary(terminalreporter):
     )
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _span_ring_starts_empty():
+    """The flight recorder's ring (obs/trace.py, 32,768 spans) is the
+    PROCESS's, and an xdist worker runs many test files in one process: a
+    few of them record 12,000-20,000 spans each, and a file that READS the
+    ring (perfbench/spans.py refuses one that has dropped spans) then fails
+    or passes by which files its worker ran first. Every test module starts
+    with an empty ring, as a process of its own would."""
+    from paddle_tpu.obs import trace
+
+    trace.reset()
+    yield
+
+
 @pytest.fixture
 def rng():
     import jax

@@ -355,7 +355,8 @@ def test_no_decode_step_skipped_during_chunked_prefill(model_and_params):
     the decode stream never waits for the prefill."""
     s = make_session(model_and_params, prefill_chunk=8)
     short = s.submit(PROMPTS[0], 16)
-    s.step()  # admit + prefill (first token) + decode (second token)
+    s.step()  # admit + prefill (first token) + decode dispatched
+    s.step()  # the second token fetched behind the next dispatch (ISSUE 36)
     assert len(short.tokens) == 2
     long_prompt = [1] + list(range(3, 60))
     long = s.submit(long_prompt, 4)

@@ -15,8 +15,10 @@ naming its justification:
   * the deferred log line (value copied to host asynchronously a dispatch
     earlier).
 
-  serving (ServingSession._decode_once / step):
-  * the sampled-token fetch after the decode dispatch.
+  serving (ServingSession._decode_once / _collect / step):
+  * the sampled-token fetch, ONE a decode step: since ISSUE 36 of the step
+    dispatched BEFORE the one just dispatched (`_collect`), so the device
+    runs a step under the fetch and the bookkeeping and not beside them.
 
 This test fails the build if a sync-forcing call — float(...),
 np.isfinite(...), .item(...), jax.device_get(...), block_until_ready(...),
@@ -67,14 +69,19 @@ SERVING_SYNC_CALL = re.compile(
 # call before they write: growing a slot's page list is host ints (its
 # scheduler half, Scheduler.grow, is pinned clock-free below), so it fetches
 # nothing and the budget of three stands.
+# ISSUE 36 moved the decode step's one fetch out of _decode_once into
+# _collect (the step dispatched before the one just dispatched; _drain is
+# its fetch-then-dispatch form) and added _decode_lanes and
+# PagedKVCache.can_grow, host ints asked once a step: the fetch MOVED, so
+# the budget of three stands (_collect, _prefill_chunks, _speculate).
 HOT_LOOPS = [
     (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), SYNC_CALL, 3),
     (SERVING_PY, "ServingSession",
-     ("_decode_once", "step", "_prefill_chunks", "_speculate",
-      "_ensure_pages"),
+     ("_decode_once", "_decode_lanes", "_collect", "_drain", "step",
+      "_prefill_chunks", "_speculate", "_ensure_pages"),
      SERVING_SYNC_CALL, 3),
     (SCHEDULER_PY, "Scheduler", ("grow",), SERVING_SYNC_CALL, 0),
-    (KV_CACHE_PY, "PagedKVCache", ("grow", "trim", "can_admit"),
+    (KV_CACHE_PY, "PagedKVCache", ("grow", "can_grow", "trim", "can_admit"),
      SERVING_SYNC_CALL, 0),
 ]
 
@@ -120,9 +127,13 @@ SPAN_HOT_LOOPS = [
     # ISSUE 34: `serve.preempt` in _ensure_pages, a flight span a PREEMPTION
     # (none on a pool with room, a few a minute on one that binds), two int
     # attrs: five sites.
+    # ISSUE 36: _collect and _drain (the fetch behind the dispatch) are hot
+    # bodies too and record nothing of their own: `serve.decode` still opens
+    # once a decode dispatch, around it and the fetch of the step before.
     (SERVING_PY, "ServingSession",
-     ("_decode_once", "step", "_prefill_chunks", "_speculate",
-      "_notify_streams", "_ensure_pages"), 5),
+     ("_decode_once", "_decode_lanes", "_collect", "_drain", "step",
+      "_prefill_chunks", "_speculate", "_notify_streams", "_ensure_pages"),
+     5),
     (ROUTER_PY, "Router",
      ("_forward", "_failover_requests", "_reap_once", "_pump_once"), 3),
 ]
@@ -310,11 +321,12 @@ CLOCK_HOT_LOOPS = [
     # stamp a victim with the step's own timestamp, handed in: no new read.
     (SERVING_PY, "ServingSession",
      ("step", "_admit", "_prefill_chunks", "_observe_ttft", "_decode_once",
-      "_speculate", "_ensure_pages", "_notify_streams", "_engine_loop",
-      "_supervise", "_recover"), 4),
+      "_decode_lanes", "_collect", "_drain", "_speculate", "_ensure_pages",
+      "_notify_streams", "_engine_loop", "_supervise", "_recover"), 4),
     (SCHEDULER_PY, "Scheduler",
      ("reap", "pop_admissions", "grow", "requeue_active", "retire"), 3),
-    (KV_CACHE_PY, "PagedKVCache", ("grow", "trim", "can_admit"), 0),
+    (KV_CACHE_PY, "PagedKVCache", ("grow", "can_grow", "trim", "can_admit"),
+     0),
     (SCHEDULER_PY, "ActiveSeq", ("append", "finished"), 1),
     # router dispatch path (ISSUE 15): one read per submit (the admission
     # stamp deadlines/hedge/park all derive from), one per pump cycle, one
@@ -383,8 +395,8 @@ PUT_TAG = "tp-ok"
 # (file, class, engine-loop methods, max tp-ok tags)
 PUT_HOT_LOOPS = [
     (SERVING_PY, "ServingSession",
-     ("step", "_admit", "_prefill_chunks", "_decode_once", "_speculate",
-      "_ensure_pages"), 1),
+     ("step", "_admit", "_prefill_chunks", "_decode_once", "_collect",
+      "_drain", "_speculate", "_ensure_pages"), 1),
 ]
 
 
@@ -606,8 +618,8 @@ STREAM_EMIT = re.compile(
 # (file, class, engine-side stream-seam methods)
 STREAM_SEAM = [
     (SERVING_PY, "ServingSession",
-     ("_notify_streams", "stream_wait", "step", "_decode_once",
-      "_speculate", "_ensure_pages")),
+     ("_notify_streams", "stream_wait", "step", "_decode_once", "_collect",
+      "_drain", "_speculate", "_ensure_pages")),
     (ROUTER_PY, "Router",
      ("_notify_streams", "stream_wait", "_on_result", "_pump_once")),
 ]
@@ -922,7 +934,8 @@ def test_decode_hot_bodies_stay_prefix_free():
         source = f.read()
     spans = _hot_spans(
         ast.parse(source), "ServingSession",
-        ("step", "_decode_once", "_speculate"),
+        ("step", "_decode_once", "_decode_lanes", "_collect", "_drain",
+         "_speculate"),
     )
     lines = source.splitlines()
     offenders = []

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import paged_attention, rnn_kernels
+from paddle_tpu.serving.session import decode_step_in_flight
 
 
 @pytest.fixture(autouse=True)
@@ -271,9 +272,12 @@ def test_the_looped_decode_step_is_one_layer_body_scanned_in_place(on_chip, monk
         jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
     )
     s = (slots,)
-    compiled = jax.jit(model.decode_step, donate_argnums=(1, 2)).lower(
+    # the SESSION's decode step (ISSUE 36): the model's, with each lane's
+    # token taken from the host's or from the step before's, on the device
+    compiled = jax.jit(decode_step_in_flight(model), donate_argnums=(1, 2)).lower(
         params, aval(pool, jnp.bfloat16), aval(pool, jnp.bfloat16),
         aval(s, jnp.int32), aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval(s, jnp.int32), aval(s, jnp.bool_),
         aval((slots, 80), jnp.int32), aval(s, jnp.uint32), aval(s, jnp.int32),
         aval(s, jnp.float32), aval(s, jnp.int32),
     ).compile()
@@ -326,9 +330,11 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
     state = {k: aval((slots,) + s, d) for k, (s, d) in model.state_spec().items()}
     state.update({k: aval(s, d) for k, (s, d) in model.counter_spec().items()})
     s = (slots,)
-    compiled = jax.jit(model.decode_step, donate_argnums=(1, 2, 3)).lower(
+    # the SESSION's decode step (ISSUE 36), as the looped test above
+    compiled = jax.jit(decode_step_in_flight(model), donate_argnums=(1, 2, 3)).lower(
         params, aval(pool, jnp.bfloat16), aval(pool, jnp.bfloat16), state,
         aval(s, jnp.int32), aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval(s, jnp.int32), aval(s, jnp.bool_),
         aval((slots, 128), jnp.int32), aval(s, jnp.uint32), aval(s, jnp.int32),
         aval(s, jnp.float32), aval(s, jnp.int32),
     ).compile()

@@ -405,3 +405,250 @@ def test_generation_session_builds_once(monkeypatch):
     sess2.generate(batch)
     sess2.generate(batch)
     assert calls["n"] <= 1
+
+
+# -- one decode step in flight (ISSUE 36) --------------------------------------------
+#
+# The engine dispatches decode step N before it fetches step N-1's tokens.
+# What that may never change is a token: over the three served models, a
+# session driven by step() serves what the full-context reference gives
+# across everything that is learned one step late (an EOS, a cancel, an
+# expiry), a dry pool that preempts, the shortest budgets, and both ways an
+# engine runs to idleness. One parametrised test over the models and the runs.
+
+FLIGHT_PROMPTS = [
+    [1, 17, 61, 5, 88, 40, 9, 33, 50, 61, 7],
+    [1, 5, 9, 11],
+    [1, 7, 70, 23, 8, 3],
+    [1, 40, 41, 42, 43, 44, 45, 46, 47],
+    [1, 90, 12, 90, 31],
+    [1, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14],
+]
+FLIGHT_NEW = 12
+
+
+def _tiny_model(kind):
+    """(model, params) at the tiny configuration its own test file serves."""
+    import jax
+
+    if kind == "servable":
+        from paddle_tpu.serving.model import LMConfig, ServableLM
+
+        model = ServableLM(LMConfig(vocab=VOCAB, n_layers=2, d_model=32,
+                                    n_heads=2, max_len=96))
+        return model, model.init_params(jax.random.PRNGKey(0))
+    if kind == "looped":
+        from test_looped_lm import tiny
+    else:
+        from test_hybrid_moe_lm import tiny
+    return tiny()
+
+
+@pytest.fixture(scope="module", params=["servable", "looped", "hybrid"])
+def flight_model(request):
+    """The model with its EOS set to a token that ONE prompt's greedy answer
+    reaches at its third draw or later (and, where the tiny weights allow,
+    no other answer at all), so that a request samples `eos_id` in the
+    middle of a run while others go on: (model, params, the prompts with
+    that one first, what the full-context reference gives for each)."""
+    import dataclasses
+
+    model, params = _tiny_model(request.param)
+    endless = type(model)(dataclasses.replace(model.cfg, eos_id=-1))
+    full = [greedy_reference(endless, params, p, FLIGHT_NEW) for p in FLIGHT_PROMPTS]
+    found = [(sum(w[k] in other for other in full), a, k)
+             for a, w in enumerate(full) for k in range(2, 7)
+             if w[k] not in w[:k]]
+    _, a, k = min(found)
+    eos = full[a][k]
+    model = type(model)(dataclasses.replace(model.cfg, eos_id=eos))
+    prompts = [FLIGHT_PROMPTS[a]] + FLIGHT_PROMPTS[:a] + FLIGHT_PROMPTS[a + 1:]
+    want = [greedy_reference(model, params, p, FLIGHT_NEW) for p in prompts]
+    assert want[0] == full[a][:k + 1]
+    return model, params, prompts, want
+
+
+def _flight_session(flight_model, **kw):
+    from paddle_tpu.serving.session import ServingSession
+
+    model, params, _, _ = flight_model
+    kw = dict(dict(max_slots=4, page_size=4, prefill_buckets=(8, 16),
+                   max_new_limit=24), **kw)
+    return ServingSession(model, params, **kw)
+
+
+def _wasted_by_eos(want, budgets, eos):
+    """Requests whose EOS is found with their next lane already dispatched:
+    every EOS but a first token's (retired at the admission) and a budget's
+    last (its lane was never dispatched again)."""
+    return sum(1 for w, n in zip(want, budgets)
+               if w[-1] == eos and 1 < len(w) < n)
+
+
+def _nothing_in_flight(s):
+    assert s._in_flight is None
+    assert not s.scheduler.has_work()
+    assert s.stats()["pages_in_use"] == 0
+
+
+def _run_eos(fm):
+    """(a) a request samples `eos_id` while others continue: its handle ends
+    AT the EOS, the lane dispatched behind it is dropped and counted, and
+    the next tenant of its slot (and of its slot's state) is exact."""
+    model, _, prompts, want = fm
+    s = _flight_session(fm)
+    hs = [s.submit(p, FLIGHT_NEW) for p in prompts]  # 6 requests, 4 slots
+    slot_of = {}
+    while s.scheduler.has_work():
+        s.step()
+        for slot, act in s.scheduler.active_slots():
+            slot_of.setdefault(act.handle.request_id, slot)
+    assert [h.tokens for h in hs] == want
+    eos = model.cfg.eos_id
+    assert hs[0].tokens[-1] == eos and hs[0].finish_reason == "eos"
+    assert eos not in hs[0].tokens[:-1]
+    # the first to finish: a queued request took its slot, and is exact
+    assert slot_of[hs[0].request_id] in (slot_of[hs[4].request_id],
+                                         slot_of[hs[5].request_id])
+    st = s.stats()
+    assert st["wasted_lanes"] == _wasted_by_eos(want, [FLIGHT_NEW] * 6, eos) >= 1
+    assert st["decode_shape_signatures"] == 1
+    _nothing_in_flight(s)
+
+
+def _run_gone(fm):
+    """(b) a cancel() and a deadline expiry, each with a lane in flight: the
+    lanes are dropped, no token is wrong or extra, the slots' next tenants
+    are exact."""
+    _, _, prompts, want = fm
+    s = _flight_session(fm)
+    cancelled = s.submit(prompts[1], FLIGHT_NEW)
+    expired = s.submit(prompts[2], FLIGHT_NEW, deadline_s=1000.0)
+    kept = s.submit(prompts[3], FLIGHT_NEW)
+    for _ in range(3):
+        s.step()
+    flying = {act.handle.request_id for _, act in s._in_flight[1]}
+    assert {cancelled.request_id, expired.request_id} <= flying
+    before = s.stats()["wasted_lanes"]
+    assert cancelled.cancel()
+    s.step(now=time.monotonic() + 2000.0)  # past the deadline: both are reaped
+    assert cancelled.done and cancelled.status == "cancelled"
+    assert expired.done and expired.finish_reason == "deadline"
+    later = [s.submit(p, FLIGHT_NEW) for p in prompts[4:]]
+    s.run_until_idle()
+    assert s.stats()["wasted_lanes"] - before >= 2
+    for h, w in ((cancelled, want[1]), (expired, want[2])):
+        assert 0 < len(h.tokens) < len(w) and h.tokens == w[:len(h.tokens)]
+    assert [kept.tokens] + [h.tokens for h in later] == want[3:]
+    _nothing_in_flight(s)
+
+
+def _run_dry_pool(fm):
+    """(c) a dry pool preempts and replays (tests/test_kv_paging.py's set-up):
+    the victim's last token is on its handle before it replays."""
+    _, _, prompts, want = fm
+    s = _flight_session(fm, num_pages=9)
+    hs = [s.submit(p, FLIGHT_NEW) for p in prompts]
+    seen = [0] * len(hs)
+    while s.scheduler.has_work():
+        s.step()
+        for i, h in enumerate(hs):
+            assert len(h.tokens) >= seen[i], "handle.tokens shrank"
+            seen[i] = len(h.tokens)
+    st = s.stats()
+    assert st["preemptions"] > 0 and st["replayed_tokens"] > 0
+    assert [h.tokens for h in hs] == want
+    assert st["decode_shape_signatures"] == 1
+    _nothing_in_flight(s)
+
+
+def _run_short_budgets(fm):
+    """(d) max_new_tokens 1 and 2: no decode step, one decode step."""
+    _, _, prompts, want = fm
+    s = _flight_session(fm)
+    one = s.submit(prompts[1], 1)
+    s.run_until_idle()
+    assert one.tokens == want[1][:1] and s.stats()["decode_steps"] == 0
+    two = s.submit(prompts[2], 2)
+    s.run_until_idle()
+    assert two.tokens == want[2][:2] and s.stats()["decode_steps"] == 1
+    # beside a request that goes on
+    hs = [s.submit(prompts[3], FLIGHT_NEW), s.submit(prompts[1], 1),
+          s.submit(prompts[2], 2)]
+    s.run_until_idle()
+    assert [h.tokens for h in hs] == [want[3], want[1][:1], want[2][:2]]
+    assert all(h.finish_reason in ("length", "eos") for h in hs)
+    assert s.stats()["wasted_lanes"] == 0
+    _nothing_in_flight(s)
+
+
+def _run_to_idleness(fm):
+    """(e) run_until_idle and the supervised serve_forever both deliver the
+    last token and leave nothing in flight."""
+    _, _, prompts, want = fm
+    s = _flight_session(fm)
+    hs = [s.submit(p, FLIGHT_NEW) for p in prompts[:3]]
+    s.run_until_idle()
+    assert [h.tokens for h in hs] == want[:3]
+    _nothing_in_flight(s)
+    s.serve_forever()
+    try:
+        hs = [s.submit(p, FLIGHT_NEW) for p in prompts[3:]]
+        assert [h.result(timeout=120.0) for h in hs] == want[3:]
+        deadline = time.monotonic() + 30.0
+        while s._in_flight is not None and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        s.stop()
+    _nothing_in_flight(s)
+
+
+@pytest.mark.parametrize("run", [_run_eos, _run_gone, _run_dry_pool,
+                                 _run_short_budgets, _run_to_idleness],
+                         ids=lambda f: f.__name__[5:])
+def test_one_step_in_flight_serves_the_references_tokens(flight_model, run):
+    run(flight_model)
+
+
+def test_overlapped_steps_and_fetches_are_counted(model_and_params, monkeypatch):
+    """Over K consecutive decode-only steps `overlapped_steps` rises by K-1
+    and the host fetches ONE token array a step; with speculation on every
+    step drains, so it stays 0; one decode signature and one executable."""
+    from paddle_tpu.serving import session as session_mod
+
+    fetched = []
+
+    class CountingNumpy:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(a, *args, **kw):
+            fetched.append(a)
+            return np.asarray(a, *args, **kw)
+
+    s = make_session(model_and_params)
+    hs = [s.submit(p, 12) for p in PROMPTS[:3]]
+    s.step()  # the admissions and the first decode dispatch: nothing to fetch yet
+    assert s.stats()["decode_steps"] == 1 and s.stats()["overlapped_steps"] == 0
+    monkeypatch.setattr(session_mod, "np", CountingNumpy())
+    k = 6
+    for _ in range(k):
+        s.step()
+    st = s.stats()
+    assert st["decode_steps"] == 1 + k and st["overlapped_steps"] == k
+    assert len(fetched) == k and all(a.shape == (4,) for a in fetched)
+    monkeypatch.undo()
+    s.run_until_idle()
+    st = s.stats()
+    assert st["overlapped_steps"] == st["decode_steps"] - 1
+    assert st["decode_shape_signatures"] == 1 and s._decode._cache_size() == 1
+    assert all(len(h.tokens) == 12 or h.finish_reason == "eos" for h in hs)
+
+    spec = make_session(model_and_params, speculate_k=4)
+    for p in PROMPTS[:3]:
+        spec.submit(p, 12)
+    spec.run_until_idle()
+    st = spec.stats()
+    assert st["decode_steps"] > 0 and st["overlapped_steps"] == 0
+    assert st["wasted_lanes"] == 0 and spec._in_flight is None

@@ -349,6 +349,47 @@ def test_engine_recovery_result_transparent_zero_leak(
     assert s.cache.free_pages == total_free, "zero page leak after recovery"
 
 
+def test_a_fault_with_a_step_in_flight_recovers_token_exact(model_and_params):
+    """ISSUE 36: the engine faults with a decode step dispatched and its
+    tokens unfetched. The recovery ends that step first (a request it
+    finished is complete and is not replayed), replays the others from
+    their prompts, sampled ones through the same draws, and every handle
+    ends token-exact with nothing left in flight and no page leaked."""
+    import logging
+
+    plan = [
+        (PROMPTS[0], 8, {}),
+        (PROMPTS[1], 3, {}),  # prefill + two decode steps: done by the step in flight
+        (PROMPTS[2], 8, dict(temperature=0.8, top_k=20, seed=7)),
+        (PROMPTS[3], 8, dict(temperature=0.8, top_k=20, seed=11)),
+    ]
+    clean = make_session(model_and_params)
+    ref_handles = [clean.submit(p, n, **kw) for p, n, kw in plan]
+    clean.run_until_idle()
+    ref = [h.tokens for h in ref_handles]
+
+    s = make_session(model_and_params)
+    total_free = s.cache.free_pages
+    handles = [s.submit(p, n, **kw) for p, n, kw in plan]
+    s.step()
+    s.step()  # the second decode step is dispatched, its tokens unfetched
+    assert s._in_flight is not None and len(handles[1].tokens) == 2
+    with faults.inject("decode_raise:1.0", seed=0):
+        with pytest.raises(Exception) as fault:
+            s.step()
+    assert s._in_flight is not None, "the fault left the step in flight"
+    s._recover("fault", fault.value, logging.getLogger("paddle_tpu.serving"))
+    assert s._in_flight is None
+    assert handles[1].done and handles[1].tokens == ref[1], (
+        "what the step in flight finished is complete, not replayed"
+    )
+    assert all(h.tokens == [] for h in handles if not h.done)  # replayed whole
+    s.run_until_idle()
+    assert [h.tokens for h in handles] == ref
+    assert s.engine_restarts == 1 and s._in_flight is None
+    assert s.cache.free_pages == total_free, "zero page leak after recovery"
+
+
 @pytest.mark.timeout(60)
 def test_restart_budget_exhausted_fails_engine_error(model_and_params):
     """Past engine_restart_max the supervisor gives up LOUDLY: outstanding

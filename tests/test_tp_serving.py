@@ -182,6 +182,22 @@ def test_tp_one_decode_signature(greedy_runs, sampled_runs):
             assert st["decode_shape_signatures"] == 1, (tp, st)
 
 
+def test_tp_step_in_flight_is_the_one_executable():
+    """ISSUE 36 under TP: the token array a decode step returns is
+    replicated over the mesh and feeds the next dispatch unfetched; the
+    session's first dispatch (no step before it) runs the SAME executable,
+    not a second lowering of it for a differently placed `prev_tok`."""
+    session = make_demo_session(prefill_buckets=(16, 32), tp=2, **_DEMO)
+    for p in make_prompts(3, lengths=(5, 11), vocab=64, bos_id=1, seed=0):
+        session.submit(p, 8)
+    session.run_until_idle()
+    st = session.stats()
+    assert st["overlapped_steps"] == st["decode_steps"] - 1 > 0
+    assert st["decode_shape_signatures"] == 1
+    assert session._decode._cache_size() == 1
+    assert session._prev_tok.sharding.is_fully_replicated
+
+
 def test_tp_param_and_pool_bytes_shrink(greedy_runs):
     """~N× per-chip shrink from sharding METADATA: the pool is fully
     kv_heads-sharded (exactly N×); params keep small replicated leaves
