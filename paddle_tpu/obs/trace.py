@@ -10,16 +10,19 @@ trace-event JSON loadable in Perfetto (chrome://tracing).
 Two kinds of site, one ring:
 
   * flight-recorder spans (`flight()`, `record_flight()`): ALWAYS recorded.
-    The train loop (`train.*`), the prefetch worker (`pipeline.*`) and the
-    compile listener (`compile.*`, core/stats.py) use them: a fixed number
-    per dispatch and per compile, a few microseconds each, bounded by the
-    ring. They are what the benchmark's per-layer readers read
-    (perfbench/spans.py), and nobody has to switch them on.
+    The train loop (`train.*`), the prefetch worker (`pipeline.*`), the
+    compile listener (`compile.*`, core/stats.py) and the serving engine's
+    step (`serve.*`, serving/session.py) use them: a fixed number per
+    dispatch, per compile and per engine step, a few microseconds each,
+    bounded by the ring. They are what the benchmark's per-layer readers
+    read (perfbench/spans.py, perfbench/serve_spans.py), and nobody has to
+    switch them on.
   * gated spans (`span()`, `record_span()`, `span_from_monotonic()`,
     `server_span()`, the wire context): off unless PADDLE_TPU_TRACE is set /
     enable_tracing() is called; a disabled site costs one attribute lookup +
-    a truth test, builds no strings and takes no locks. RPC, serving and
-    router sites are per request or per decode step, so they stay gated.
+    a truth test, builds no strings and takes no locks. RPC and router sites
+    and a serving request's queue wait and first token are per request and
+    stitch under the request's trace, so they stay gated.
 
 The lint in tests/test_lint_hotloop.py pins both kinds of site in the hot
 loops and bans file I/O and string formatting inside them.
@@ -190,7 +193,9 @@ _NULL_SPAN = _NullSpan()
 class _LiveSpan:
     """An open span. `attrs` may be set until the span closes (the train
     loop learns a batch's index only once the pull returned); `dur_ns` is
-    there after it closed, for the counter kept at the same boundary."""
+    there after it closed, for the counter kept at the same boundary.
+    `drop()` before the close makes it close without a ring write (an
+    engine step learns only at its end whether it found anything to do)."""
 
     __slots__ = (
         "name", "attrs", "trace_id", "span_id", "parent_id", "_t0", "dur_ns",
@@ -199,6 +204,9 @@ class _LiveSpan:
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]]):
         self.name = name
         self.attrs = attrs
+
+    def drop(self) -> None:
+        self.name = None
 
     def __enter__(self) -> "_LiveSpan":
         parent = TRACER.current()
@@ -220,10 +228,11 @@ class _LiveSpan:
         while st:
             if st.pop() == want:
                 break
-        TRACER.record(
-            self.name, self._t0, self.dur_ns, self.trace_id, self.span_id,
-            self.parent_id, self.attrs,
-        )
+        if self.name is not None:
+            TRACER.record(
+                self.name, self._t0, self.dur_ns, self.trace_id,
+                self.span_id, self.parent_id, self.attrs,
+            )
         return False
 
 

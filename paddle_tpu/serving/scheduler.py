@@ -78,7 +78,7 @@ class RequestHandle:
     expiry also CANCELS the request server-side — the pre-ISSUE-10 behavior
     (client times out, request keeps decoding and holding KV pages) leaked
     work nobody would collect. Timing fields feed the latency bench
-    (t_submit/t_first_token/t_done, all time.monotonic); t_deadline /
+    (t_submit/t_admitted/t_first_token/t_done, all time.monotonic); t_deadline /
     t_ttft_deadline are absolute monotonic deadlines (None = none)."""
 
     QUEUED = "queued"
@@ -109,6 +109,10 @@ class RequestHandle:
         self.tokens: List[int] = []
         self.finish_reason: Optional[str] = None
         self.t_submit = time.monotonic()
+        # the engine's stamp of the admission to a slot: the one clock read
+        # of the step that admitted it (a preempted request keeps its first;
+        # an engine restart's replay is admitted, and stamped, again)
+        self.t_admitted: Optional[float] = None
         self.t_first_token: Optional[float] = None
         self.t_done: Optional[float] = None
         self.t_deadline = (
@@ -716,7 +720,8 @@ class Scheduler:
                 else:
                     act.t_started = w.t_started
                     act.preempted_s = w.preempted_s + (now - w.t_preempted)
-                act.handle.status = RequestHandle.RUNNING
+                h.t_admitted = act.t_started
+                h.status = RequestHandle.RUNNING
                 self.slots[slot] = act
                 admitted.append((slot, act))
         for w in doomed:

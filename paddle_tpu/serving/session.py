@@ -64,6 +64,21 @@ bitwise what they would have been, greedy and sampled; `stats()` counts
 `preemptions` and `replayed_tokens`, and a flight span `serve.preempt`
 names the engine step that paid for one.
 
+The step accounts for its own time (ISSUE 37) on the always-on span ring
+(obs/trace.py `flight`): one `serve.step` a call that did work, whose int
+attrs say what it ran (`admitted`, `chunks`, `decoded`, `slots`,
+`preempted`) and `wait_ns`, the ns it spent blocked in `_fetch`, the one
+helper every device->host fetch of the engine goes through. That is the host
+WAITING for the device; the rest of the span is the host WORKING, which no
+device trace shows while the device is never idle. Its children: `serve.admit`
+(a whole-prompt prefill, dispatch to first token: `bucket`, `prompt`,
+`queued_ms`, `replay`, `wait_ns`), `serve.chunk` (`start`, `tokens`,
+`wait_ns`), `serve.preempt` (`written`, `pages`) and `serve.decode` (the
+dispatch and the fetch of the step before: `slots`, `layer_passes`,
+`replaying`, `wait_ns`). A fetch outside a child (a `_drain` before a
+preemption, at the end of the work or before a speculation round) shows as
+the step's `wait_ns` beyond its children's.
+
 Resilience (ISSUE 10): in server mode the engine thread runs under a
 SUPERVISOR. When the engine faults (seeded sites `decode_raise` /
 `page_exhaust`) or stalls past `engine_stall_timeout_s` without a step
@@ -321,6 +336,10 @@ class ServingSession:
         # an expiry): one lane-step each, never a token
         self.overlapped_steps = 0
         self.wasted_lanes = 0
+        # ns the engine step under way has spent blocked in `_fetch`: the
+        # host WAITING for the device; the rest of the step is the host
+        # working. step() zeroes it, `serve.step` and its children report it
+        self._wait_ns = 0
         self.tokens_generated = 0
         self.prefill_chunks_committed = 0
         self._chunk_rr_slot = -1  # round-robin cursor over prefilling slots
@@ -508,8 +527,23 @@ class ServingSession:
             attrs={"request_id": h.request_id},
         )
 
-    def _admit(self, now: Optional[float] = None) -> None:
-        """Run prefill for every request joining at this step boundary.
+    def _fetch(self, on_device) -> np.ndarray:
+        """Every blocking device->host fetch of the engine goes through
+        here (tests/test_lint_hotloop.py pins it): the value, and the ns the
+        host waited for it added to the step's `_wait_ns`, on the span
+        ring's clock. What a step's duration holds beyond that is the
+        host's own work, which the device trace cannot see once the device
+        is never idle."""
+        t0 = time.time_ns()
+        # sync-ok: the ONE np.asarray of a device value in the engine; each
+        # caller names why it may block where it does
+        out = np.asarray(on_device)
+        self._wait_ns += time.time_ns() - t0
+        return out
+
+    def _admit(self, now: Optional[float] = None) -> int:
+        """Run prefill for every request joining at this step boundary;
+        returns how many whole-prompt prefills ran.
         Prompts longer than `prefill_chunk` (when set) only MARK the slot
         as prefilling here — their K/V commits one chunk per engine step in
         _prefill_chunks, interleaved with decode, so a long prompt joining
@@ -522,6 +556,7 @@ class ServingSession:
             # replay; gated on queued work so step=N counts admission
             # ATTEMPTS, not idle engine spins
             _faults.get().maybe_raise("page_exhaust")
+        admitted = 0
         for slot, act in self.scheduler.pop_admissions(now):
             h = act.handle
             ctx = h.trace_ctx
@@ -530,6 +565,7 @@ class ServingSession:
                 # request's own trace id (measured on the scheduler's
                 # monotonic clock, re-anchored to wall-clock for the export);
                 # a preempted request's readmission is no second queue wait
+                # span-ok: gated, one a REQUEST, under the request's trace
                 trace.span_from_monotonic(
                     "serving.queue_wait", h.t_submit,
                     trace_id=ctx and ctx.get("t"),
@@ -548,42 +584,48 @@ class ServingSession:
                 continue
             bucket = _bucket_for(self.buckets, len(act.prompt))
             seeds, temps, top_ks = self._sampling_row(h)
-            with trace.activate(ctx):
-                with trace.span(
-                    "serving.prefill", request_id=h.request_id, bucket=bucket
-                ):
-                    toks = np.zeros((1, bucket), np.int32)
-                    toks[0, : len(act.prompt)] = act.prompt
-                    lengths = np.array([len(act.prompt)], np.int32)
-                    first_tok, *kept = self._prefill(
-                        self.params, toks, lengths, seeds, temps, top_ks
+            waited = self._wait_ns
+            # span-ok: the flight recorder's one ring write an ADMISSION, int
+            # attrs: from the prefill's dispatch to the first token on the
+            # handle, under the request's own trace where it brought one
+            with trace.activate(ctx), trace.flight(
+                "serve.admit", request_id=h.request_id, bucket=bucket,
+                prompt=len(act.prompt), replay=int(act.replaying),
+                queued_ms=int(1e3 * (h.t_admitted - h.t_submit)),
+            ) as sp:
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, : len(act.prompt)] = act.prompt
+                lengths = np.array([len(act.prompt)], np.int32)
+                first_tok, *kept = self._prefill(
+                    self.params, toks, lengths, seeds, temps, top_ks
+                )
+                rows = self.cache.slot_row(slot)
+                # tp-ok: per-ADMISSION placement of one request's commit
+                # operands (never per decode step); the block table the
+                # decode loop uses rides the jit dispatch untouched
+                where = (
+                    jnp.asarray(lengths), jnp.asarray(rows),
+                    jnp.zeros((1,), jnp.int32),
+                )
+                if self.state is None:
+                    self.k_pages, self.v_pages = self._commit(
+                        self.k_pages, self.v_pages, *kept, *where
                     )
-                    rows = self.cache.slot_row(slot)
-                    # tp-ok: per-ADMISSION placement of one request's commit
-                    # operands (never per decode step); the block table the
-                    # decode loop uses rides the jit dispatch untouched
-                    where = (
-                        jnp.asarray(lengths), jnp.asarray(rows),
-                        jnp.zeros((1,), jnp.int32),
+                else:
+                    # the prompt's final state goes WHOLE into the slot:
+                    # nothing of its last tenant's survives an admission
+                    self.k_pages, self.v_pages, self.state = self._commit(
+                        self.k_pages, self.v_pages, self.state, *kept,
+                        *where, np.array([slot], np.int32),
                     )
-                    if self.state is None:
-                        self.k_pages, self.v_pages = self._commit(
-                            self.k_pages, self.v_pages, *kept, *where
-                        )
-                    else:
-                        # the prompt's final state goes WHOLE into the slot:
-                        # nothing of its last tenant's survives an admission
-                        self.k_pages, self.v_pages, self.state = self._commit(
-                            self.k_pages, self.v_pages, self.state, *kept,
-                            *where, np.array([slot], np.int32),
-                        )
-                    # one tiny host fetch per ADMISSION (not per decode step):
-                    # the prompt's first token — sampled on device (a replay
-                    # re-derives the one its handle already has). The ARRAY
-                    # is fetched, as the prefill left it: indexing it on the
-                    # device first would queue an op behind the commit, and
-                    # the host would sleep through the commit it can work under
-                    fresh = act.append(int(np.asarray(first_tok)[0]))
+                # sync-ok: one tiny fetch per ADMISSION (not per decode step):
+                # the prompt's first token, sampled on device (a replay
+                # re-derives the one its handle has). The ARRAY is fetched, as
+                # the prefill left it: indexing it on the device first would
+                # queue an op behind the commit the host can work under
+                fresh = act.append(int(self._fetch(first_tok)[0]))
+                sp.attrs["wait_ns"] = self._wait_ns - waited
+            admitted += 1
             # the whole prompt is committed: register its full pages into
             # the tenant's prefix chain (no-op with the cache off)
             self.cache.commit_prefix(slot, h.tenant, act.prompt,
@@ -599,6 +641,7 @@ class ServingSession:
             reason = act.finished(self.cfg.eos_id)
             if reason is not None:
                 self.scheduler.retire(slot, reason)
+        return admitted
 
     def _prefill_chunks(self) -> None:
         """Advance ONE prefilling slot by exactly one [1, C] chunk — the
@@ -635,42 +678,45 @@ class ServingSession:
             starts = np.array([start], np.int32)
             seeds, temps, top_ks = self._sampling_row(h)
             rows = self.cache.slot_row(slot)
-            # span-ok: ring-buffer write only, constant name, int attrs — the
-            # chunk loop is hot-path like the decode loop (lint-pinned)
-            with trace.activate(h.trace_ctx):
-                with trace.span(
-                    "serving.prefill_chunk", request_id=h.request_id,
-                    start=start,
-                ):
-                    # ONE dispatch per chunk: forward + commit fused, pages
-                    # donated through (see model.prefill_chunk docstring)
-                    tok = self._dispatch_chunk(
-                        slot, toks, starts, lengths, rows, seeds, temps, top_ks
-                    )
-            act.prefill_pos = min(start + c, len(act.prompt))
-            # incremental registration (ISSUE 19): every full prompt page
-            # this chunk just committed enters the tenant's prefix chain NOW
-            # — a concurrent same-prefix admission aliases it one step later
-            # (only COMMITTED pages ever register, so an alias can never see
-            # half-written KV). No-op with the cache off.
-            self.cache.commit_prefix(slot, h.tenant, act.prompt,
-                                     act.prefill_pos)
-            self.prefill_chunks_committed += 1
-            SERVING_EVENTS.incr("serving_prefill_chunks")
-            obs_metrics.observe_layer_passes(
-                "prefill", (act.prefill_pos - start) * self.layer_passes
-            )
-            if not act.prefilling:
-                # sync-ok: one host fetch per REQUEST (not per chunk, not per
-                # step) — the FINAL chunk's sampled first token, which the
-                # autoregressive loop needs on host; intermediate chunks
-                # never fetch (their `tok` stays device-resident and unused)
-                if act.append(int(np.asarray(tok)[0])):
-                    self._observe_ttft(h, h.trace_ctx)
-                SERVING_EVENTS.incr("serving_prefills")
-                reason = act.finished(self.cfg.eos_id)
-                if reason is not None:
-                    self.scheduler.retire(slot, reason)
+            waited = self._wait_ns
+            # span-ok: the flight recorder's one ring write a CHUNK, int
+            # attrs: the chunk's dispatch and, behind the prompt's last, the
+            # first token's fetch; under the request's trace where it has one
+            with trace.activate(h.trace_ctx), trace.flight(
+                "serve.chunk", request_id=h.request_id, start=start,
+                tokens=len(piece),
+            ) as sp:
+                # ONE dispatch per chunk: forward + commit fused, pages
+                # donated through (see model.prefill_chunk docstring)
+                tok = self._dispatch_chunk(
+                    slot, toks, starts, lengths, rows, seeds, temps, top_ks
+                )
+                act.prefill_pos = min(start + c, len(act.prompt))
+                # incremental registration (ISSUE 19): every full prompt page
+                # this chunk just committed enters the tenant's prefix chain
+                # NOW — a concurrent same-prefix admission aliases it one
+                # step later (only COMMITTED pages ever register, so an alias
+                # can never see half-written KV). No-op with the cache off.
+                self.cache.commit_prefix(slot, h.tenant, act.prompt,
+                                         act.prefill_pos)
+                self.prefill_chunks_committed += 1
+                SERVING_EVENTS.incr("serving_prefill_chunks")
+                obs_metrics.observe_layer_passes(
+                    "prefill", (act.prefill_pos - start) * self.layer_passes
+                )
+                if not act.prefilling:
+                    # sync-ok: one host fetch per REQUEST (not per chunk, not
+                    # per step) — the FINAL chunk's sampled first token,
+                    # which the autoregressive loop needs on host;
+                    # intermediate chunks never fetch (their `tok` stays
+                    # device-resident and unused)
+                    if act.append(int(self._fetch(tok)[0])):
+                        self._observe_ttft(h, h.trace_ctx)
+                    SERVING_EVENTS.incr("serving_prefills")
+                    reason = act.finished(self.cfg.eos_id)
+                    if reason is not None:
+                        self.scheduler.retire(slot, reason)
+                sp.attrs["wait_ns"] = self._wait_ns - waited
 
     def _ensure_pages(self, wants) -> set:
         """Grow every slot of `wants` [(slot, tokens its pages must cover)]
@@ -687,7 +733,6 @@ class ServingSession:
                 "serve.preempt", written=act.written, pages=freed
             ):
                 self._drafters.pop(slot, None)
-                SERVING_EVENTS.incr("serving_preemptions")
                 obs_metrics.observe_preemption()
         return {slot for slot, _, _ in preempted}
 
@@ -877,7 +922,7 @@ class ServingSession:
                 # tokens, which the host needs to run acceptance (the
                 # autoregressive loop's EOS/budget checks ride the same
                 # fetch); pages stay donated through, logits never land
-                out = np.asarray(sampled)
+                out = self._fetch(sampled)
             act.engine_steps += 1
             obs_metrics.observe_layer_passes(
                 "decode", (k + 1) * self.layer_passes
@@ -930,7 +975,7 @@ class ServingSession:
             and act.generated + act.in_flight < act.handle.max_new_tokens
         ]
 
-    def _decode_once(self, skip: frozenset = frozenset()) -> None:
+    def _decode_once(self, skip: frozenset = frozenset()) -> int:
         """One continuous-batching decode step: every active, fully-prefilled
         slot advances by one token inside the single fixed-shape executable
         (slots mid-chunked-prefill sit this one out as inactive lanes — their
@@ -942,7 +987,8 @@ class ServingSession:
         under everything the host does until the next dispatch. A lane with
         a step in flight is built from what the host knows without that
         step's token: position, sampling index and pages one further, the
-        token itself taken on the device."""
+        token itself taken on the device. Returns the lanes dispatched (0
+        where nothing was)."""
         def writes(lanes):
             # every lane writes the position behind its last token (the one
             # in flight counted): the page it lands in first
@@ -962,7 +1008,7 @@ class ServingSession:
         if not active:
             # nothing to run ahead of: the step in flight, if any, ends here
             self._drain()
-            return
+            return 0
         if _faults.get().active:
             # chaos site: the engine faults mid-decode — the supervisor must
             # restart it, re-init the page pool and replay in-flight work;
@@ -972,7 +1018,7 @@ class ServingSession:
         if lost:
             active = [sa for sa in active if sa[0] not in lost]
             if not active:
-                return
+                return 0
         s = self.cache.max_slots
         tokens = np.zeros(s, np.int32)
         from_prev = np.zeros(s, bool)
@@ -982,8 +1028,10 @@ class ServingSession:
         steps = np.zeros(s, np.int32)
         temps = np.zeros(s, np.float32)
         top_ks = np.zeros(s, np.int32)
+        replaying = 0  # lanes that rebuild K/V and can complete no token
         for slot, act in active:
             ahead = act.in_flight
+            replaying += act.generated + ahead < len(act.handle.tokens)
             if not ahead:
                 tokens[slot] = act.last_token
             elif act.replaying:
@@ -1012,15 +1060,15 @@ class ServingSession:
                  "temps": temps, "top_ks": top_ks}
             )
         )
-        # span-ok: ring-buffer write only, constant name, int attr — no file
-        # I/O or string formatting on the decode hot path; a no-op truth
-        # test when PADDLE_TPU_TRACE is off (tests/test_lint_hotloop.py)
+        waited = self._wait_ns
         # span-ok: the flight recorder's one ring write a decode step, int
-        # attrs: the dispatch and the fetch of the step BEFORE it, and the
-        # batch the step's weight traffic is shared over (perfbench: `slots`)
+        # attrs: the dispatch and the fetch of the step BEFORE it (its
+        # `wait_ns`), the batch the step's weight traffic is shared over
+        # (perfbench: `slots`) and how much of it only rebuilds K/V
         with trace.flight(
-            "serve.decode", slots=len(active), layer_passes=self.layer_passes
-        ), trace.span("serving.decode_step", active=len(active)):
+            "serve.decode", slots=len(active), layer_passes=self.layer_passes,
+            replaying=replaying,
+        ) as sp:
             next_tok = self._dispatch_decode(
                 tokens, prev_tok, from_prev, positions, act_mask, bt, seeds,
                 steps, temps, top_ks,
@@ -1034,9 +1082,10 @@ class ServingSession:
             obs_metrics.observe_decode_step(len(active), self.layer_passes)
             if before is not None:
                 self.overlapped_steps += 1
-                SERVING_EVENTS.incr("serving_decode_overlapped_steps")
                 obs_metrics.observe_decode_overlapped()
                 self._collect(before)
+            sp.attrs["wait_ns"] = self._wait_ns - waited
+        return len(active)
 
     def _drain(self) -> None:
         """End the step in flight now: what reads the sampled VALUES on the
@@ -1069,7 +1118,7 @@ class ServingSession:
             # sampled token ids, which the autoregressive loop needs on host to
             # detect EOS/budget and stream tokens; everything else stays device-
             # resident (pages are donated through, logits never leave the device)
-            toks = np.asarray(next_tok)
+            toks = self._fetch(next_tok)
             for slot, act in live:
                 if act.append(toks[slot]):
                     self.tokens_generated += 1
@@ -1093,11 +1142,9 @@ class ServingSession:
             wasted += len(behind[1])
         if replayed:
             self.replayed_tokens += replayed
-            SERVING_EVENTS.incr("serving_replayed_tokens", replayed)
             obs_metrics.observe_replayed_tokens(replayed)
         if wasted:
             self.wasted_lanes += wasted
-            SERVING_EVENTS.incr("serving_wasted_lane_steps", wasted)
             obs_metrics.observe_wasted_lanes(wasted)
 
     def step(self, now: Optional[float] = None) -> bool:
@@ -1110,34 +1157,56 @@ class ServingSession:
         dispatched (an admission's prefill and a chunk are dispatched behind
         the step in flight, and their own first-token fetch waits for both).
         Returns True when any work was done."""
-        if now is None:
-            # clock-ok: the ONE sanctioned wall-clock read per engine step —
-            # deadline expiry, cancellation reaping and admission stamps all
-            # batch off this single timestamp (a per-request read would scale
-            # with occupancy; tests/test_lint_hotloop.py pins this site)
-            now = time.monotonic()
-        self._last_progress = now  # supervisor stall-watchdog heartbeat
-        traces_before = self._jit_traces
-        in_flight = self._in_flight is not None  # dispatched on, or collected
-        self.scheduler.reap(now)
-        self._admit(now)
-        self._prefill_chunks()
-        before = self.decode_steps
-        spec_before = self.spec_rounds
-        advanced = self._speculate()
-        self._decode_once(advanced)
-        obs_metrics.set_kv_pages_in_use(self.cache.pages_in_use)
-        self._notify_streams()
-        # auto EWMA reset (ISSUE 17): a step that compiled an executable
-        # retired requests with second-scale service times; the first CLEAN
-        # step afterwards forgets the poisoned estimate and lets
-        # steady-state retirements re-seed it — a later first-hit bucket
-        # compile re-arms the same healing
-        if self._jit_traces != traces_before:
-            self._load_est_dirty = True
-        elif self._load_est_dirty:
-            self._load_est_dirty = False
-            self.scheduler.reset_load_estimate()
+        # span-ok: the flight recorder's one ring write an engine step that
+        # did work, int attrs: what the step ran and `wait_ns`, the part of
+        # it the host spent blocked in `_fetch` (the rest is the host's own
+        # work). `serve.admit`, `serve.chunk`, `serve.preempt` and
+        # `serve.decode` are its children. An idle call records nothing
+        with trace.flight("serve.step") as sp:
+            if now is None:
+                # clock-ok: the ONE sanctioned wall-clock read per engine
+                # step — deadline expiry, cancellation reaping and admission
+                # stamps all batch off this single timestamp (a per-request
+                # read would scale with occupancy;
+                # tests/test_lint_hotloop.py pins this site)
+                now = time.monotonic()
+            self._last_progress = now  # supervisor stall-watchdog heartbeat
+            self._wait_ns = 0
+            traces_before = self._jit_traces
+            in_flight = self._in_flight is not None  # dispatched on, or collected
+            chunks_before = self.prefill_chunks_committed
+            preempted_before = self.scheduler.preemptions
+            self.scheduler.reap(now)
+            admitted = self._admit(now)
+            self._prefill_chunks()
+            before = self.decode_steps
+            spec_before = self.spec_rounds
+            advanced = self._speculate()
+            slots = self._decode_once(advanced)
+            obs_metrics.set_kv_pages_in_use(self.cache.pages_in_use)
+            self._notify_streams()
+            # auto EWMA reset (ISSUE 17): a step that compiled an executable
+            # retired requests with second-scale service times; the first
+            # CLEAN step afterwards forgets the poisoned estimate and lets
+            # steady-state retirements re-seed it — a later first-hit bucket
+            # compile re-arms the same healing
+            if self._jit_traces != traces_before:
+                self._load_est_dirty = True
+            elif self._load_est_dirty:
+                self._load_est_dirty = False
+                self.scheduler.reset_load_estimate()
+            did = {
+                "admitted": admitted,
+                "chunks": self.prefill_chunks_committed - chunks_before,
+                "decoded": self.decode_steps - before,
+                "slots": slots,
+                "preempted": self.scheduler.preemptions - preempted_before,
+                "wait_ns": self._wait_ns,
+            }
+            if any(did.values()):
+                sp.attrs = did
+            else:
+                sp.drop()
         return (
             self.decode_steps != before
             or self.spec_rounds != spec_before

@@ -468,8 +468,9 @@ def test_preemptions_are_counted_and_each_leaves_a_span(servable):
                  "paddle_tpu_serving_replayed_tokens_total",
                  "paddle_tpu_serving_kv_pages_in_use"):
         assert name in text
+    # stats() and the registry's counters (asserted above) are the record:
+    # no third copy of the same events beside them (ISSUE 37)
     from paddle_tpu.serving.session import SERVING_EVENTS
 
-    events = SERVING_EVENTS.as_dict()
-    assert events["serving_preemptions"] >= st["preemptions"]
-    assert events["serving_replayed_tokens"] >= st["replayed_tokens"]
+    assert not {"serving_preemptions", "serving_replayed_tokens"} & set(
+        SERVING_EVENTS.as_dict())

@@ -19,6 +19,10 @@ naming its justification:
   * the sampled-token fetch, ONE a decode step: since ISSUE 36 of the step
     dispatched BEFORE the one just dispatched (`_collect`), so the device
     runs a step under the fetch and the bookkeeping and not beside them.
+  * since ISSUE 37 every fetch of the engine is a `self._fetch(...)` call:
+    the one helper that holds the `np.asarray` and times the host's wait
+    for the device into the step's `wait_ns`. A call site is tagged and
+    counted as the `np.asarray` it replaced was.
 
 This test fails the build if a sync-forcing call — float(...),
 np.isfinite(...), .item(...), jax.device_get(...), block_until_ready(...),
@@ -53,7 +57,7 @@ SYNC_CALL = re.compile(
 # sanctioned fetch uses exactly that idiom, so an unreviewed second one
 # must trip the lint
 SERVING_SYNC_CALL = re.compile(
-    SYNC_CALL.pattern + r"|(?<![\w.])np\.asarray\("
+    SYNC_CALL.pattern + r"|(?<![\w.])np\.asarray\(|self\._fetch\("
 )
 
 # (file, class, hot methods, pattern, max sync-ok tags)
@@ -74,12 +78,17 @@ SERVING_SYNC_CALL = re.compile(
 # its fetch-then-dispatch form) and added _decode_lanes and
 # PagedKVCache.can_grow, host ints asked once a step: the fetch MOVED, so
 # the budget of three stands (_collect, _prefill_chunks, _speculate).
+# ISSUE 37 routed the three, and _admit's first-token fetch, through
+# ServingSession._fetch: the sites are `self._fetch(` calls now, tagged as
+# before: three in the per-step bodies; _admit's, one an ADMISSION, and the
+# helper's own np.asarray are the second entry's two.
 HOT_LOOPS = [
     (TRAINER_PY, "SGDTrainer", ("train", "_train_one_pass"), SYNC_CALL, 3),
     (SERVING_PY, "ServingSession",
      ("_decode_once", "_decode_lanes", "_collect", "_drain", "step",
       "_prefill_chunks", "_speculate", "_ensure_pages"),
      SERVING_SYNC_CALL, 3),
+    (SERVING_PY, "ServingSession", ("_admit", "_fetch"), SERVING_SYNC_CALL, 2),
     (SCHEDULER_PY, "Scheduler", ("grow",), SERVING_SYNC_CALL, 0),
     (KV_CACHE_PY, "PagedKVCache", ("grow", "can_grow", "trim", "can_admit"),
      SERVING_SYNC_CALL, 0),
@@ -130,10 +139,19 @@ SPAN_HOT_LOOPS = [
     # ISSUE 36: _collect and _drain (the fetch behind the dispatch) are hot
     # bodies too and record nothing of their own: `serve.decode` still opens
     # once a decode dispatch, around it and the fetch of the step before.
+    # ISSUE 37: `serve.step` in step (a flight span an engine step that did
+    # work), `serve.chunk` in _prefill_chunks where the gated
+    # `serving.prefill_chunk` was (a flight span now), and the gated
+    # `serving.decode_step` beside `serve.decode` gone: five sites still
+    # (step, _prefill_chunks, _ensure_pages, _speculate, _decode_once), at
+    # most two ring writes a decode step. _admit's sites are one an
+    # ADMISSION: `serve.admit` (flight, where the gated `serving.prefill`
+    # was) and the gated `serving.queue_wait`.
     (SERVING_PY, "ServingSession",
      ("_decode_once", "_decode_lanes", "_collect", "_drain", "step",
       "_prefill_chunks", "_speculate", "_notify_streams", "_ensure_pages"),
      5),
+    (SERVING_PY, "ServingSession", ("_admit",), 2),
     (ROUTER_PY, "Router",
      ("_forward", "_failover_requests", "_reap_once", "_pump_once"), 3),
 ]
@@ -205,6 +223,28 @@ def test_sanctioned_sync_sites_stay_rare():
             f"{budget}): a new sanctioned sync site was added — confirm it "
             "is not per-step and bump this bound deliberately"
         )
+
+
+# every body of the engine that may touch a device value
+SERVING_ENGINE_BODIES = (
+    "step", "_admit", "_prefill_chunks", "_speculate", "_decode_once",
+    "_decode_lanes", "_collect", "_drain", "_ensure_pages",
+)
+RAW_FETCH = re.compile(r"(?<![\w.])np\.asarray\(")
+
+
+def test_serving_fetches_go_through_the_timing_helper():
+    """ISSUE 37: the engine's bodies hold no `np.asarray(` of their own,
+    tagged or not: a device value reaches the host through
+    ServingSession._fetch, which holds the one (HOT_LOOPS pins it) and adds
+    the wait to the step's `wait_ns`. A fetch beside it would be host time
+    that `serve.step` books as the host's own work."""
+    raw, _ = _scan(SERVING_PY, "ServingSession", SERVING_ENGINE_BODIES,
+                   RAW_FETCH, tag=None)
+    assert not raw, (
+        "np.asarray( in an engine body: fetch through self._fetch(...) so "
+        "the wait is timed:\n  " + "\n  ".join(raw)
+    )
 
 
 def test_span_sites_in_hot_loops_tagged_and_pinned():

@@ -328,11 +328,11 @@ def test_serving_request_spans_share_one_trace_id(tiny_session):
         e["name"] for e in events if e["args"].get("trace_id") == tid
     }
     assert {
-        "rpc.submit", "serving.queue_wait", "serving.prefill", "serving.ttft",
+        "rpc.submit", "serving.queue_wait", "serve.admit", "serving.ttft",
     } <= by_trace, by_trace
     # batch-level decode steps ran too (their own trace — they serve many
     # requests at once) and TTFT landed in the histogram
-    assert any(e["name"] == "serving.decode_step" for e in events)
+    assert any(e["name"] == "serve.decode" for e in events)
     from paddle_tpu.serving.session import TTFT_HISTOGRAM
 
     assert TTFT_HISTOGRAM._n > 0
