@@ -7,7 +7,8 @@ One process, three phases, through the entry points a user calls:
 
   kernels  every pallas_call in ops/pallas/ compiled by Mosaic, forward and
            backward, against its jnp / lax.scan oracle at the shapes the
-           repo's own models use;
+           repo's own models use (the Mamba-2 decode kernel at granite's
+           cell, its new state bit for bit the oracle's);
   serve    a ServingServer over the session `serve --demo` builds, a
            ServingClient over TCP, eight mixed-length greedy requests plus
            one streamed — at the CLI's demo geometry and at one lane-aligned
@@ -60,6 +61,10 @@ LOOPED_TIE_TOL = 0.5
 # at 1/sqrt(vocab), over logits_scaling), and a near tie in a router flips
 # an expert as well as a token.
 HYBRID_TIE_TOL = 0.05
+# The Mamba-2 decode kernel's y against its oracle's, over the largest |y|:
+# the same float32 products summed in another order (the new state must be
+# the oracle's bit for bit)
+SSM_Y_TOL = 1e-5
 
 FULL = dict(
     gru=[(50, 128, 512)],
@@ -67,6 +72,8 @@ FULL = dict(
     attn=[(16, 128, 128, 128)],
     # (slots, heads, head_dim, page_size, pages_per_seq)
     paged=[(8, 2, 16, 16, 8), (16, 16, 128, 16, 8)],
+    # (slots, Mamba layers, heads, head_dim, state): granite's cell
+    ssm=[(64, 9, 128, 64, 128)],
     serve=[
         ["--demo"],
         ["--demo", "--d_model=2048", "--n_heads=16", "--n_layers=2",
@@ -80,6 +87,7 @@ REHEARSAL = dict(
     lstm=[(6, 8, 128)],
     attn=[(2, 16, 16, 32)],
     paged=[(4, 2, 16, 8, 3), (4, 2, 128, 8, 3)],
+    ssm=[(2, 3, 8, 64, 128)],
     serve=[
         ["--demo", "--prefill_buckets=16,32", "--max_new_limit=16"],
         ["--demo", "--d_model=512", "--n_heads=4", "--n_layers=1",
@@ -256,6 +264,53 @@ def phase_kernels(size, on_chip: bool) -> None:
              jnp.asarray(table), jnp.asarray(positions)),
             flag, on_chip,
         )
+
+    for shape in size["ssm"]:
+        check_ssm_decode(shape, flag, on_chip)
+
+
+def check_ssm_decode(shape, kernel_flag, on_chip):
+    """The Mamba-2 decode kernel against `mamba2.ssm_step` on the layer's
+    slice (HybridMoELM._ssm_decode's two paths), the layer traced, a lane
+    inactive and some heads at dt = 0: the new stack bit for bit, y within
+    SSM_Y_TOL of the largest |y|."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.serving.hybrid_moe_lm import HybridMoELM
+
+    s, m, h, p, n = shape
+    ks = jax.random.split(jax.random.PRNGKey(38), 6)
+    active = jnp.arange(s) % 5 != 1
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (s, h)) - 2.0)
+    args = (
+        jax.random.normal(ks[0], (s, m, h, p, n), jnp.float32),
+        jnp.asarray(m - 1, jnp.int32),
+        jax.random.normal(ks[1], (s, h, p)).astype(jnp.bfloat16),
+        jnp.where(active[:, None], dt, 0.0).at[:, ::7].set(0.0),
+        -jax.random.uniform(ks[3], (h,), minval=1.0, maxval=16.0),
+        jax.random.normal(ks[4], (s, n)).astype(jnp.bfloat16),
+        jax.random.normal(ks[5], (s, n)).astype(jnp.bfloat16),
+        active,
+    )
+
+    def compiled(flag):  # a fresh function a flag: jit's cache is the function's
+        with pallas_flag(flag):
+            return jax.jit(lambda *a: HybridMoELM._ssm_decode(*a)).lower(*args).compile()
+
+    kernel, oracle = compiled(kernel_flag), compiled("0")
+    assert ("tpu_custom_call" in kernel.as_text()) == on_chip, (
+        "ssm_decode: a Mosaic custom call is expected on the chip, and only there")
+    (y_got, got), (y_want, want) = kernel(*args), oracle(*args)
+    t_kernel, t_oracle = ms_per_call(kernel, *args), ms_per_call(oracle, *args)
+    differ = int(np.sum(np.asarray(got) != np.asarray(want)))
+    y_err = float(jnp.max(jnp.abs(y_got - y_want)) / jnp.max(jnp.abs(y_want)))
+    say(f"  ssm_decode S{s} M{m} H{h}x{p} N{n}: state elements not bit for bit "
+        f"the oracle's {differ}, y err {y_err:.2e} (tol {SSM_Y_TOL:.0e}); info: "
+        f"{t_kernel:.2f} ms kernel, {t_oracle:.2f} ms oracle, a call with its "
+        "copy of the stack")
+    assert differ == 0 and y_err <= SSM_Y_TOL, (differ, y_err)
 
 
 # -- phase: serve -------------------------------------------------------------

@@ -303,6 +303,18 @@ def observe_fused_projection_xent(path: str) -> None:
     ).inc(path=path)
 
 
+def observe_ssm_decode(path: str) -> None:
+    """A Mamba-2 layer's one-token recurrence was TRACED into a decode step
+    (once per call each time the step is traced, never per step;
+    serving/hybrid_moe_lm.HybridMoELM._ssm_decode): path is 'kernel' where
+    the Pallas kernel runs it (ops/pallas/ssm_decode.py), 'oracle' where
+    `mamba2.ssm_step` does."""
+    REGISTRY.counter(
+        "paddle_tpu_ssm_decode_total",
+        "Mamba-2 one-token recurrences traced, by path (kernel|oracle)",
+    ).inc(path=path)
+
+
 # -- serving resilience (ISSUE 10) -------------------------------------------
 #
 # One naming authority for the serving failure-path counters, so the

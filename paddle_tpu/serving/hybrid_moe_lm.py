@@ -335,11 +335,12 @@ class HybridMoELM(PagedLM):
     def _residual(self, x: Array, h: Array) -> Array:
         return (x.astype(F32) + self.cfg.residual_multiplier * h.astype(F32)).astype(self.dtype)
 
-    def _mamba(self, w, u: Array, tail: Array, state: Array, valid: Array, step: bool):
-        """The Mamba-2 mixer over u [B, T, D] from (tail [B, K-1, C], state
-        [B, H, P, N]); valid [B, T] marks the tokens, which lead each row.
-        `step`: T == 1, the one-token recurrence. Returns (out [B, T, D],
-        new tail, new state)."""
+    def _mamba(self, w, u: Array, tail: Array, valid: Array, recur):
+        """The Mamba-2 mixer over u [B, T, D] from the convolution's tail
+        [B, K-1, C]; valid [B, T] marks the tokens, which lead each row.
+        `recur(x, dt, a_neg, b, c)` runs the recurrence over x [B, T, H, P]
+        from the state the caller holds and returns (y [B, T, H, P] float32,
+        the state after it). Returns (out [B, T, D], new tail, that state)."""
         c = self.cfg
         bsz, t, _ = u.shape
         heads, p, n = c.mamba_heads, c.mamba_head_dim, c.mamba_state
@@ -352,18 +353,36 @@ class HybridMoELM(PagedLM):
         x = x.reshape(bsz, t, heads, p)
         dt = jax.nn.softplus(dt.astype(F32) + w["m_dt_bias"])
         dt = jnp.where(valid[..., None], dt, 0.0)                 # no token: decay 1, input 0
-        a_neg = -jnp.exp(w["m_a_log"])
-        if step:
-            y, new = mamba2.ssm_step(state, x[:, 0], dt[:, 0], a_neg, b[:, 0], cc[:, 0])
-            y = y[:, None]
-        else:
-            y, new = mamba2.ssd_chunked(x, dt, a_neg, b, cc, state, c.mamba_chunk)
-        # rows with no token keep their state bit for bit
-        state = jnp.where(valid[:, :1, None, None], new, state)
+        y, state = recur(x, dt, -jnp.exp(w["m_a_log"]), b, cc)
         y = y + w["m_d"][:, None] * x.astype(F32)
         g = y.reshape(bsz, t, -1) * jax.nn.silu(z.astype(F32))    # the gate BEFORE the norm
         g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + c.rms_eps)
         return self._mm((g * w["m_norm"].astype(F32)).astype(self.dtype), w["m_out"]), tail, state
+
+    @staticmethod
+    def _ssm_decode(ssm: Array, m, x: Array, dt: Array, a_neg: Array, b: Array,
+                    c: Array, active: Array):
+        """One token a slot through Mamba layer m of the WHOLE stacked state
+        ssm [S, M, H, P, N]: (y [S, H, P] float32, the stack with layer m
+        advanced where `active`; a lane with no request keeps its state bit
+        for bit). The Pallas kernel (ops/pallas/ssm_decode.py: one pass over
+        the layer's state, written in place) when `pallas.enabled()`, else
+        `mamba2.ssm_step` on the layer's slice, which is also the kernel's
+        CPU ORACLE (tests/test_ssm_decode_kernel.py). Runs where a decode
+        step is traced, so the counter moves once a call a trace."""
+        from paddle_tpu.obs import metrics as obs_metrics
+        from paddle_tpu.ops import pallas as _pallas
+
+        if _pallas.enabled():
+            from paddle_tpu.ops.pallas.ssm_decode import ssm_decode
+
+            obs_metrics.observe_ssm_decode("kernel")
+            return ssm_decode(ssm, m, x, dt, a_neg, b, c, active)
+        obs_metrics.observe_ssm_decode("oracle")
+        held = jax.lax.dynamic_index_in_dim(ssm, m, 1, keepdims=False)
+        y, new = mamba2.ssm_step(held, x, dt, a_neg, b, c)
+        new = jnp.where(active[:, None, None, None], new, held)
+        return y, jax.lax.dynamic_update_index_in_dim(ssm, new, m, 1)
 
     def _split_heads(self, q: Array, k: Array, v: Array):
         c = self.cfg
@@ -438,8 +457,13 @@ class HybridMoELM(PagedLM):
         causal = jnp.tril(jnp.ones((t, t), bool))
 
         def mamba_fn(w, h, carry, m):
-            out, new_tail, new_state = self._mamba(
-                w, h, tail[:, m], state[:, m], valid, step=False)
+            def recur(x, dt, a_neg, b, cc):
+                held = state[:, m]
+                y, new = mamba2.ssd_chunked(x, dt, a_neg, b, cc, held, c.mamba_chunk)
+                # rows with no token keep their state bit for bit
+                return y, jnp.where(valid[:, :1, None, None], new, held)
+
+            out, new_tail, new_state = self._mamba(w, h, tail[:, m], valid, recur)
             return out, carry, (new_state, new_tail)
 
         def attn_fn(w, h, carry, a):
@@ -560,12 +584,14 @@ class HybridMoELM(PagedLM):
 
         def mamba_fn(w, h, carry, m):
             kp, vp, ssm, conv = carry
-            out, tail, new = self._mamba(
-                w, h,
-                jax.lax.dynamic_index_in_dim(conv, m, 1, keepdims=False),
-                jax.lax.dynamic_index_in_dim(ssm, m, 1, keepdims=False),
-                valid, step=True)
-            ssm = jax.lax.dynamic_update_index_in_dim(ssm, new, m, 1)
+
+            def recur(x, dt, a_neg, b, cc):
+                y, stack = self._ssm_decode(
+                    ssm, m, x[:, 0], dt[:, 0], a_neg, b[:, 0], cc[:, 0], active)
+                return y[:, None], stack
+
+            out, tail, ssm = self._mamba(
+                w, h, jax.lax.dynamic_index_in_dim(conv, m, 1, keepdims=False), valid, recur)
             conv = jax.lax.dynamic_update_index_in_dim(conv, tail, m, 1)
             return out, (kp, vp, ssm, conv), ()
 

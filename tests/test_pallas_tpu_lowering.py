@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.pallas import paged_attention, rnn_kernels
+from paddle_tpu.ops.pallas import paged_attention, rnn_kernels, ssm_decode
 from paddle_tpu.serving.session import decode_step_in_flight
 
 
@@ -30,6 +30,7 @@ from paddle_tpu.serving.session import decode_step_in_flight
 def _mosaic_not_interpret(monkeypatch):
     monkeypatch.setattr(rnn_kernels, "interpret_mode", lambda: False)
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
+    monkeypatch.setattr(ssm_decode, "interpret_mode", lambda: False)
 
 
 def _lstm_fwd(proj, mask, w, b, h0, c0):
@@ -70,6 +71,12 @@ def _paged(n_heads):
     return f
 
 
+def _ssm_shapes(s, m, h, p, n):
+    bf16 = jnp.bfloat16
+    return [(s, m, h, p, n), ((), jnp.int32), ((s, h, p), bf16), (s, h), (h,),
+            ((s, n), bf16), ((s, n), bf16), ((s,), jnp.bool_)]
+
+
 def _lstm_shapes(t, b, h):
     return [(t, b, 4 * h), (t, b, 1), (h, 4 * h), (4 * h,), (b, h), (b, h)]
 
@@ -106,6 +113,11 @@ CASES = [
      _paged_shapes(32, 16, 128, 16, 128, layers=24, n_pages=833), 1),
     ("paged_tp_shard", _paged(4),
      _paged_shapes(32, 4, 128, 16, 128, layers=24, n_pages=833), 1),
+    # the Mamba-2 decode step at granite_4_0_h_small's cell (64 slots, a stack
+    # of nine layers of 128 heads of 64 x 128, the layer traced) and at
+    # chip_smoke's small hybrid model
+    ("ssm_decode_cell", ssm_decode.ssm_decode, _ssm_shapes(64, 9, 128, 64, 128), 1),
+    ("ssm_decode_small", ssm_decode.ssm_decode, _ssm_shapes(2, 3, 8, 64, 128), 1),
 ]
 
 
@@ -302,7 +314,9 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
     """HybridMoELM.decode_step at granite_4_0_h_small's shapes (nine Mamba-2
     layers in two scanned runs round one attention layer, 36 of 72 experts a
     layer, bfloat16, 64 slots of float32 state) compiled for the described
-    v5e: the grouped-head paged-attention kernel passes Mosaic, the grouped
+    v5e: the grouped-head paged-attention kernel passes Mosaic, each scanned
+    Mamba run's recurrence is the `ssm_decode` kernel and no fusion reads
+    the stacked state a second time for y, the grouped
     expert products take the WHOLE expert stacks (no instruction of a
     layer's experts' shape or of the state's is a copy or a slice cut for
     them: either is a third of the step), pools and state are the outputs'
@@ -340,6 +354,19 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
     ).compile()
     text = compiled.as_text()
     assert pool == (1, 8193, 16, 1024) and 'paged_attention_decode' in text
+    # the Mamba-2 recurrence is the kernel's, once a scanned run, and no
+    # fusion reads the stacked state to reduce it to y: the second read of
+    # 268 MB a layer and step that the kernel removes (PERF.md, PR 38)
+    assert len(re.findall(r"= \(f32\[64,4,1,2048\]\S*, f32\[64,9,128,64,128\]\S*\) custom-call\(",
+                          text)) == 2, "ssm_decode kernel expected once a Mamba run"
+    shapes = dict(re.findall(r"%(\S+) = (\S+?)\{", text))
+    readouts = [
+        line.strip()[:140] for line in text.splitlines()
+        if re.search(r"= f32\[64,128,64\]\S* fusion\(", line)
+        and any(shapes.get(op) == "f32[64,9,128,64,128]"
+                for op in re.findall(r"%([\w.-]+)", line.split("fusion(", 1)[1]))
+    ]
+    assert not readouts, readouts
     cut = [
         line.strip()[:140] for line in text.splitlines()
         if re.search(r"= (bf16\[36,(4096,1536|768,4096)\]|f32\[64,(9,)?128,64,128\])\S* "
