@@ -303,6 +303,17 @@ def observe_fused_projection_xent(path: str) -> None:
     ).inc(path=path)
 
 
+def observe_attention_decoder_scan() -> None:
+    """An attention decoder's teacher-forced scan was TRACED in a forward
+    other than init's (once per decoder per trace, never per step;
+    nn/attention_layers.AttentionDecoder.forward): the scan whose backward
+    forms the encoder's gradient in one contraction after the loop."""
+    REGISTRY.counter(
+        "paddle_tpu_attention_decoder_scan_total",
+        "attention decoder training scans traced",
+    ).inc()
+
+
 def observe_ssm_decode(path: str) -> None:
     """A Mamba-2 layer's one-token recurrence was TRACED into a decode step
     (once per call each time the step is traced, never per step;

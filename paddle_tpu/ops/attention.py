@@ -49,11 +49,27 @@ def additive_attention(
     """→ (context [B, D], weights [B, T] f32); masked sequence softmax runs
     f32 (seq_softmax pin), the context contraction is a dot boundary in the
     ambient compute dtype."""
+    weights = additive_weights(enc_proj, dec_state, w_dec, v, lengths)
+    return attention_context(enc, weights), weights
+
+
+def additive_weights(
+    enc_proj: Array,  # [B, T, A]
+    dec_state: Array,  # [B, H]
+    w_dec: Array,
+    v: Array,
+    lengths: Array,
+) -> Array:
+    """The masked sequence softmax of the Bahdanau scores → weights [B, T]
+    f32; padded source positions read 0."""
+    return seq_ops.seq_softmax(additive_scores(enc_proj, dec_state, w_dec, v), lengths)
+
+
+def attention_context(enc: Array, weights: Array) -> Array:
+    """Σ_t weights[b, t] · enc[b, t] → [B, D]: a dot boundary in the ambient
+    compute dtype."""
     p = dtypes.current()
-    scores = additive_scores(enc_proj, dec_state, w_dec, v)
-    weights = seq_ops.seq_softmax(scores, lengths)
-    context = jnp.einsum("btd,bt->bd", p.cast(enc), p.cast(weights))
-    return context, weights
+    return jnp.einsum("btd,bt->bd", p.cast(enc), p.cast(weights))
 
 
 def _attn_fuse_ok(q: Array, k: Array, v: Array, scale) -> bool:

@@ -380,3 +380,70 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
     expected = 2 * 2 * int(np.prod(pool)) + held
     assert expected <= memory.alias_size_in_bytes < 1.1 * expected
     assert memory.argument_size_in_bytes < 12.6e9 and memory.temp_size_in_bytes < 64e6
+
+
+# -- the NMT decoder's backward, at seq2seq_nmt.train's shapes -----------------
+
+def _while_bodies(text):
+    """{body name: its instruction lines} of every while loop in the text."""
+    names = set(re.findall(r" while\(.*?body=%?([\w.-]+)", text))
+    bodies, cur = {}, None
+    for line in text.splitlines():
+        if cur is None:
+            m = re.match(r"%?([\w.-]+) \(", line)
+            if m and m.group(1) in names:
+                cur = bodies.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        else:
+            cur.append(line)
+    return bodies
+
+
+def test_the_nmt_decoders_backward_loop_carries_no_encoder_gradient(on_chip):
+    """jax.grad of the attention decoder's teacher-forced scan at the cell's
+    shapes (batch 512, 50 source and 50 target steps, encoder states 1024
+    wide, hidden and attention 512, bfloat16) compiled for the described
+    v5e: no loop body has a fusion of the encoder's [512, 50, 1024] shape
+    (the float32 gradient carried through the reverse loop, 210 MB read and
+    written a step, is formed once after it), and no array anywhere has the
+    source steps minor: [512, 1024, 50] is the encoder's bfloat16 copy laid
+    out with 50 steps padded to 128 lanes, which slows both context
+    contractions 2.3 times, and [512, 512, 50] the scores' tanh recomputed
+    transposed for v's gradient alone (PERF.md, PR 42)."""
+    from paddle_tpu.core import dtypes
+    from paddle_tpu.nn import layers as L
+    from paddle_tpu.nn.attention_layers import AttentionDecoder, DecoderParams
+    from paddle_tpu.ops import linalg, rnn
+
+    b, t, d_enc, d_emb, h = 512, 50, 1024, 512, 512
+
+    def aval(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    params = DecoderParams(
+        w_enc=aval(d_enc, h), w_dec=aval(h, h), v=aval(h), w_in=aval(d_emb + d_enc, 3 * h),
+        gru=rnn.GruParams(w_hzr=aval(h, 2 * h), w_hc=aval(h, h), bias=aval(3 * h)),
+        w_init=aval(d_enc, h),
+    )
+    dec = AttentionDecoder(L.Data("enc", shape=(d_enc,), is_seq=True),
+                           L.Data("emb", shape=(d_emb,), is_seq=True), h)
+
+    def loss(p, enc, emb, lengths):
+        with dtypes.policy_scope(dtypes.bf16_policy()):
+            proj_emb = linalg.matmul(emb, p.w_in[:d_emb])
+            hs = dec.teacher_forced(p, enc, lengths, proj_emb, lengths)
+        return jnp.sum(hs.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        params, aval(b, t, d_enc), aval(b, t, d_emb), aval(b, dtype=jnp.int32)
+    ).compile().as_text()
+    bodies = _while_bodies(text)
+    assert len(bodies) == 2, sorted(bodies)  # the forward scan and the reverse one
+    carried = [
+        line.strip()[:140] for lines in bodies.values() for line in lines
+        if re.search(rf"= \(?\w+\[{b},{t},{d_enc}\]\S* fusion\(", line)
+    ]
+    assert not carried, carried
+    steps_minor = sorted(set(re.findall(rf"\w+\[{b},(?:{d_enc}|{h}),{t}\]", text)))
+    assert not steps_minor, steps_minor
