@@ -326,6 +326,19 @@ def observe_ssm_decode(path: str) -> None:
     ).inc(path=path)
 
 
+def observe_paged_attention_decode(path: str, window: int) -> None:
+    """A layer's decode attention over the page pool was TRACED into a step
+    (once per call each time the step is traced, never per step;
+    serving/model.PagedLM._paged_attention_local): path is 'kernel' where
+    the Pallas kernel runs it (ops/pallas/paged_attention.py), 'oracle'
+    where the jnp gather does; window is the layer's (0: the whole
+    context)."""
+    REGISTRY.counter(
+        "paddle_tpu_paged_attention_decode_total",
+        "decode attention calls traced, by path (kernel|oracle) and window",
+    ).inc(path=path, window=str(int(window)))
+
+
 # -- serving resilience (ISSUE 10) -------------------------------------------
 #
 # One naming authority for the serving failure-path counters, so the

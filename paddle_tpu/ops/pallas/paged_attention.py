@@ -44,6 +44,14 @@ block left, or the zeros both V tiles start a call with — finite either way,
 so 0 x row is 0 (K's rows need no such care: their scores are replaced, not
 multiplied).
 
+A window (`window` W > 0, a layer that attends to its last W positions
+alone) changes three things and nothing else: the table is the slot's RING
+of R pages (serving/kv_cache.py), logical page p read from entry p % R; a
+slot's walk starts at the first page its window touches, (pos - W + 1) //
+PS, so it fetches at most R pages whatever its position; and a token before
+pos - W + 1 scores NEG_INF as a token past pos does. With no window the
+kernel is what it was, op for op.
+
 The jnp gather path remains the CPU oracle: `paged_attention_decode` must
 match it to float tolerance (argmax-equal under greedy decode) for every
 mixed length / block-table layout — asserted in interpret mode on CPU by
@@ -91,13 +99,21 @@ BLOCK_TOKENS = 512
 
 
 @functools.lru_cache(maxsize=None)
-def _pages_per_block(page_size: int, kd: int, pmax: int, itemsize: int = 4) -> int:
+def _pages_per_block(page_size: int, kd: int, pmax: int, itemsize: int = 4,
+                     ring: bool = False) -> int:
     """B, the pages one block gathers: as many as the budget, the token cap
-    and the table's width allow. Logged here, once a geometry."""
+    and the table's width allow; for a ring (a window), the most of the
+    budget that divides the ring's R pages, the token cap left out: a ring
+    block is ONE copy, and the fewer the copies the less each page costs.
+    Logged here, once a geometry."""
     page_bytes = page_size * kd * itemsize
-    b = min(
-        TILE_BUDGET // (4 * page_bytes), BLOCK_TOKENS // page_size, pmax
-    )
+    if ring:
+        fit = max(1, TILE_BUDGET // (4 * page_bytes))
+        b = max(d for d in range(1, pmax + 1) if pmax % d == 0 and d <= fit)
+    else:
+        b = min(
+            TILE_BUDGET // (4 * page_bytes), BLOCK_TOKENS // page_size, pmax
+        )
     b = max(1, b)
     log.info(
         "paged_attention_decode: %d pages a block (%d tokens of %d lanes, "
@@ -132,6 +148,7 @@ def _paged_decode_kernel(
     pages_per_block: int,
     pmax: int,
     group: int,
+    window: int = 0,
 ):
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
@@ -139,40 +156,73 @@ def _paged_decode_kernel(
     ps, b = page_size, pages_per_block
     t = b * ps
 
-    def pages_held(slot):
-        return jnp.minimum(pos_ref[slot] // ps + 1, pmax)
-
-    def each_page(slot, blk, act):
-        """`act(i, p)` for each page p = blk*B + i of the block the slot
-        holds: the same pages when a block is sent for and when it is
-        waited for, since both read the prefetched scalars."""
-        held = pages_held(slot)
-        for i in range(b):
-            pl.when(blk * b + i < held)(
-                functools.partial(act, i, blk * b + i)
-            )
-
     tiles = ((k_hbm, k_buf), (v_hbm, v_buf))
 
-    def copy(kv, buf, i, page):
-        hbm, buf_ref = tiles[kv]
-        return pltpu.make_async_copy(
-            hbm.at[layer, page],
-            buf_ref.at[buf, pl.ds(i * ps, ps)],
-            sems.at[kv, buf],
-        )
+    if window:
+        # a ring of pmax = R pages a slot, consecutive in the pool, walked a
+        # block of B consecutive entries at a time (B divides R): the blocks
+        # that hold a page of the window, from the one holding its first
+        # page, each one copy for K and one for V
+        n_ring = pmax // b
 
-    def start(slot, blk, buf):
-        def act(i, p):
-            page = bt_ref[slot * pmax + p]
-            copy(0, buf, i, page).start()
-            copy(1, buf, i, page).start()
+        def span(slot):
+            """(the window's first page, its pages, its first ring block)."""
+            pos_s = pos_ref[slot]
+            lo = jnp.maximum(pos_s - window + 1, 0) // ps
+            return lo, pos_s // ps - lo + 1, jax.lax.rem(lo, pmax) // b
 
-        each_page(slot, blk, act)
+        def ring_blocks(slot):
+            lo, n, blk0 = span(slot)
+            last = (jax.lax.rem(lo, pmax) + n - 1) // b
+            return jnp.minimum(last - blk0 + 1, n_ring)
 
-    def wait(kv, blk, buf):
-        # a wait takes its size from the descriptor, not its source
-        each_page(s, blk, lambda i, p: copy(kv, buf, i, 0).wait())
+        def ring_copy(kv, buf, page):
+            hbm, buf_ref = tiles[kv]
+            return pltpu.make_async_copy(
+                hbm.at[layer, pl.ds(page, b)], buf_ref.at[buf], sems.at[kv, buf]
+            )
+
+        def start(slot, blk, buf):
+            c = jax.lax.rem(span(slot)[2] + blk, n_ring)
+            page = bt_ref[slot * pmax + c * b]
+            ring_copy(0, buf, page).start()
+            ring_copy(1, buf, page).start()
+
+        def wait(kv, blk, buf):
+            ring_copy(kv, buf, 0).wait()
+    else:
+        def pages_held(slot):
+            return jnp.minimum(pos_ref[slot] // ps + 1, pmax)
+
+        def each_page(slot, blk, act):
+            """`act(i, p)` for each page p = blk*B + i of the block the slot
+            holds: the same pages when a block is sent for and when it is
+            waited for, since both read the prefetched scalars."""
+            held = pages_held(slot)
+            for i in range(b):
+                pl.when(blk * b + i < held)(
+                    functools.partial(act, i, blk * b + i)
+                )
+
+        def copy(kv, buf, i, page):
+            hbm, buf_ref = tiles[kv]
+            return pltpu.make_async_copy(
+                hbm.at[layer, page],
+                buf_ref.at[buf, pl.ds(i * ps, ps)],
+                sems.at[kv, buf],
+            )
+
+        def start(slot, blk, buf):
+            def act(i, p):
+                page = bt_ref[slot * pmax + p]
+                copy(0, buf, i, page).start()
+                copy(1, buf, i, page).start()
+
+            each_page(slot, blk, act)
+
+        def wait(kv, blk, buf):
+            # a wait takes its size from the descriptor, not its source
+            each_page(s, blk, lambda i, p: copy(kv, buf, i, 0).wait())
 
     @pl.when(s == 0)
     def _open_chain():
@@ -181,7 +231,10 @@ def _paged_decode_kernel(
         start(0, 0, 0)
 
     pos = pos_ref[s]
-    n_blk = (pages_held(s) + b - 1) // b
+    if window:
+        n_blk = ring_blocks(s)
+    else:
+        n_blk = (pages_held(s) + b - 1) // b
     first = turn_ref[0]
 
     kd = q_scr.shape[1]
@@ -196,8 +249,10 @@ def _paged_decode_kernel(
         # grouped heads: the caller laid q out block-diagonal already, a row
         # a QUERY head over the lanes of the K/V head it reads
         q_scr[...] = (q_ref[0].astype(jnp.float32) * scale).astype(q_scr.dtype)
-    # the products' operands are of the pool's type (module docstring)
-    precision = _PRECISION if k_buf.dtype == jnp.float32 else None
+    # the products' operands are of the pool's type (module docstring), and
+    # their precision is stated either way: a dot that states none does not
+    # lower under a `matmul_precision` of "high" (PERF.md section 4)
+    precision = _PRECISION if k_buf.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -215,15 +270,25 @@ def _paged_decode_kernel(
 
         wait(0, j, buf)
         # all heads in one MXU call: q is block-diagonal over the lane
-        # segments, so row h of q @ k^T contracts head h's lanes only
+        # segments, so row h of q @ k^T contracts head h's lanes only (a
+        # ring block [B, PS, KD] is the same rows as a gathered [B*PS, KD])
         sc = jax.lax.dot_general(
-            q_scr[...], k_buf[buf],
+            q_scr[...], k_buf[buf].reshape(t, -1) if window else k_buf[buf],
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )  # [H, T]
         # ragged masking: logical token index within THIS slot's sequence
-        idx = j * t + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(idx <= pos, sc, NEG_INF)
+        if window:
+            # ring entry r holds the latest logical page j <= pos // PS
+            # with j % R == r
+            at = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            entry = jax.lax.rem(span(s)[2] + j, n_ring) * b + at // ps
+            top = pos // ps
+            idx = (top - jax.lax.rem(top - entry + pmax, pmax)) * ps + at % ps
+            sc = jnp.where((idx >= 0) & (idx <= pos) & (idx > pos - window), sc, NEG_INF)
+        else:
+            idx = j * t + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(idx <= pos, sc, NEG_INF)
         # online-softmax recurrence (f32 throughout)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -235,7 +300,9 @@ def _paged_decode_kernel(
         # the flush (H-fold redundant MXU work, in exchange for no
         # in-kernel reshape/transpose of the [T, KD] tile)
         pv = jax.lax.dot_general(
-            probs.astype(v_buf.dtype), v_buf[buf], (((1,), (0,)), ((), ())),
+            probs.astype(v_buf.dtype),
+            v_buf[buf].reshape(t, -1) if window else v_buf[buf],
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )  # [H, KD]
         acc_scr[...] = acc_scr[...] * alpha + pv
@@ -246,7 +313,8 @@ def _paged_decode_kernel(
     jax.lax.fori_loop(0, n_blk, block, 0)
     turn_ref[0] = (first + n_blk) % 2
 
-    # l >= exp(0 - m) > 0 always: logical index 0 is <= every position
+    # l >= exp(0 - m) > 0 always: logical index 0 is <= every position, and
+    # in a window the position itself is in it
     ctx = acc_scr[...] / l_scr[:, :1]  # [H, KD]
     if group == 1:
         out_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0, keepdims=True)
@@ -265,6 +333,7 @@ def paged_attention_decode(
     scale: float,
     n_heads: int,
     group: int = 1,
+    window: int = 0,
 ) -> Array:
     """One decode step of ragged paged attention for all slots over layer
     `layer` of the pool: [S, KD] f32 context, numerically equivalent to the
@@ -283,11 +352,15 @@ def paged_attention_decode(
     wide. The kernel's block-diagonal query then has a row a QUERY head over
     the pool's lanes, laid out here (a few MB a call), and each row's own
     K/V lanes are picked out of the kernel's [S, n_heads, KD] here too. With
-    a group of 1 the call is what it was."""
+    a group of 1 the call is what it was.
+
+    `window` W > 0: each slot attends to its last W positions, and
+    `block_table` [S, R] is its ring of pages (module docstring)."""
     if group > 1:
         return _grouped_decode(
             q, k_pages, v_pages, block_table, positions,
             layer=layer, scale=scale, n_heads=n_heads, group=group,
+            window=window,
         )
     s, kd_model = q.shape
     head_dim = kd_model // n_heads
@@ -307,20 +380,20 @@ def paged_attention_decode(
         layer = 0
     b = _pages_per_block(
         k_pages.shape[2], q.shape[1], block_table.shape[1],
-        k_pages.dtype.itemsize,
+        k_pages.dtype.itemsize, ring=bool(window),
     )
     out = _decode_layer(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
         q[:, None, :], k_pages, v_pages,
         scale=scale, n_heads=n_heads, head_dim=head_dim, pages_per_block=b,
-        interpret=interpret_mode(),
+        interpret=interpret_mode(), window=window,
     )
     return out[:, 0, :kd_model]
 
 
 def _grouped_decode(q, k_pages, v_pages, block_table, positions, *, layer,
-                    scale, n_heads, group):
+                    scale, n_heads, group, window=0):
     s = q.shape[0]
     n_kv = n_heads // group
     head_dim = q.shape[1] // n_heads
@@ -339,13 +412,14 @@ def _grouped_decode(q, k_pages, v_pages, block_table, positions, *, layer,
     qd = jnp.pad(qd.reshape(s, n_heads, kd), [(0, 0), (0, 0), (0, kd_pool - kd)])
     b = _pages_per_block(
         k_pages.shape[2], kd_pool, block_table.shape[1], k_pages.dtype.itemsize,
+        ring=bool(window),
     )
     out = _decode_layer(
         jnp.asarray(layer, jnp.int32).reshape(1),
         block_table.astype(jnp.int32).reshape(-1), positions.astype(jnp.int32),
         qd, k_pages, v_pages,
         scale=scale, n_heads=n_heads, head_dim=head_dim, pages_per_block=b,
-        interpret=interpret_mode(), group=group,
+        interpret=interpret_mode(), group=group, window=window,
     )
     own = out[..., :kd].reshape(s, n_kv, group, n_kv, head_dim)
     return jnp.einsum("scged,ce->scgd", own, jnp.eye(n_kv, dtype=out.dtype)).reshape(s, -1)
@@ -354,12 +428,13 @@ def _grouped_decode(q, k_pages, v_pages, block_table, positions, *, layer,
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "n_heads", "head_dim", "pages_per_block", "interpret", "group"
+        "scale", "n_heads", "head_dim", "pages_per_block", "interpret", "group",
+        "window",
     ),
 )
 def _decode_layer(
     layer, table, positions, q, k_pages, v_pages,
-    *, scale, n_heads, head_dim, pages_per_block, interpret, group=1,
+    *, scale, n_heads, head_dim, pages_per_block, interpret, group=1, window=0,
 ):
     """The kernel's call. The layer is DATA (a third prefetched scalar) and
     the call is jitted, so the L calls of one decode step share one trace
@@ -381,8 +456,9 @@ def _decode_layer(
         ],
         out_specs=pl.BlockSpec((1, q_rows, kd), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, b * ps, kd), k_pages.dtype),
-            pltpu.VMEM((2, b * ps, kd), v_pages.dtype),
+            # a ring block is one copy of B whole pages
+            pltpu.VMEM((2, b, ps, kd) if window else (2, b * ps, kd), k_pages.dtype),
+            pltpu.VMEM((2, b, ps, kd) if window else (2, b * ps, kd), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((n_heads, kd), k_pages.dtype),
@@ -395,7 +471,7 @@ def _decode_layer(
         functools.partial(
             _paged_decode_kernel, scale=scale, page_size=ps,
             head_dim=head_dim, pages_per_block=b,
-            pmax=table.shape[0] // s, group=group,
+            pmax=table.shape[0] // s, group=group, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, q_rows, kd), jnp.float32),

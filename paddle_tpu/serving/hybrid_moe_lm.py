@@ -36,10 +36,11 @@ token: padded positions run with dt = 0 and the convolution's tail is read at
 the prompt's length. A recurrence has no page to trim or alias, so the session
 refuses the prefix cache and speculation for a model that declares state.
 
-The expert block is TOLD which experts it holds (`experts_held`). It routes
-over all `num_experts_routed`, sorts the assignments by expert, and runs the
-held experts' two products as grouped products (`jax.lax.ragged_dot`) over
-the assignments that landed here: no capacity, no dropped token. What the
+The expert block (serving/moe.py, shared with WindowMoELM; softmax after
+top-k here) is TOLD which experts it holds (`experts_held`). It routes over
+all `num_experts_routed`, sorts the assignments by expert, and runs the held
+experts' two products as grouped products (`jax.lax.ragged_dot`) over the
+assignments that landed here: no capacity, no dropped token. What the
 absent experts would add is left out, and the shared MLP is counted once;
 the counters of `counter_spec` (assignments by held expert, here and absent)
 accumulate on the device in the carried state.
@@ -63,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.ops import mamba2
+from paddle_tpu.serving import moe
 from paddle_tpu.serving.model import NEG_INF, PagedLM
 
 Array = jax.Array
@@ -281,47 +283,12 @@ class HybridMoELM(PagedLM):
                 for k in names}
 
     def _moe(self, w, h: Array, valid: Array):
-        """h [T, D], valid [T]: (the held experts' part of the routed block
-        [T, D], counts of the valid tokens' assignments by held expert [E],
-        (landed here, went to an absent expert) [2]). `w["moe_wi"]` and
-        `w["moe_wo"]` are the WHOLE stacks [L, E, ...] and `w["layer"]` the
-        layer: the grouped product takes every layer's experts as its groups
-        and this layer's alone have rows, so its weights are read where they
-        lie. A slice of the stack cut for it is a copy of the layer's experts
-        a layer and step (0.68 GB, a third of the step; chip run, PR 35)."""
-        c = self.cfg
-        t, k, e = h.shape[0], c.top_k, len(c.experts_held)
-        n_layers = w["moe_wi"].shape[0]
-        wi = w["moe_wi"].reshape((n_layers * e,) + w["moe_wi"].shape[2:])
-        wo = w["moe_wo"].reshape((n_layers * e,) + w["moe_wo"].shape[2:])
-        logits = jnp.matmul(h, w["router"], preferred_element_type=F32)
-        val, idx = jax.lax.top_k(logits, k)                       # [T, k]
-        gate = jax.nn.softmax(val, -1)                            # over the CHOSEN logits
-        # an assignment's group: its expert's place among the held; group e,
-        # which has no weights and gets no work, for an absent expert and for
-        # what is no token
-        group = jnp.where(valid[:, None], jnp.asarray(self._local_of)[idx], e).reshape(-1)
-        order = jnp.argsort(group, stable=True)                   # [T * k]
-        sizes = jnp.sum(group[:, None] == jnp.arange(e)[None, :], 0, dtype=jnp.int32)
-        rows = h[order // k]                                      # [T * k, D], by expert
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros(n_layers * e, jnp.int32), sizes, (w["layer"] * e,))
-        a, b = jnp.split(
-            jax.lax.ragged_dot(rows, wi, groups, preferred_element_type=F32)
-            .astype(self.dtype), 2, -1)
-        out = jax.lax.ragged_dot(
-            jax.nn.silu(a) * b, wo, groups, preferred_element_type=F32
-        ).astype(self.dtype)
-        # back in (token, choice) order and summed in that order, so a token's
-        # sum does not depend on who shares the batch; rows past the groups
-        # hold nothing that counts
-        back = jnp.argsort(order)
-        here = (group < e).reshape(t, k)
-        out = jnp.where(here[..., None], out[back].reshape(t, k, -1).astype(F32), 0.0)
-        out = jnp.sum(out * gate[..., None], 1).astype(self.dtype)
-        landed = jnp.sum(sizes)
-        absent = jnp.sum(valid) * k - landed
-        return out, sizes.astype(jnp.uint32), jnp.stack([landed, absent]).astype(jnp.uint32)
+        """h [T, D], valid [T]: serving/moe.py's expert block over this
+        layer (`w["layer"]`) of the WHOLE stacks `w["moe_wi"]`,
+        `w["moe_wo"]`, routed softmax after top-k."""
+        return moe.expert_block(
+            h, valid, w["router"], w["moe_wi"], w["moe_wo"], w["layer"],
+            top_k=self.cfg.top_k, local_of=self._local_of, dtype=self.dtype)
 
     def _ffn(self, w, x: Array, valid: Array):
         """The second half of a layer over x [..., D]: (x, expert counts)."""

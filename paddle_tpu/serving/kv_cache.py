@@ -42,7 +42,17 @@ page granularity: only FULL immutable prompt pages are ever shared, and the
 first divergent page is a fresh private page the request's own chunked
 prefill writes. Retirement/cancel recycling is counted in PHYSICAL frees
 (a shared page decrefs without freeing), so the leak-watch counters stay
-exact under aliasing."""
+exact under aliasing.
+
+Window layers (a model whose `cache_windows` give some layers a window of W
+positions) keep their K/V apart, in a RING of pages a slot: W positions span
+at most `ring_pages(W, page_size)` pages, so slot s owns ring pages
+1 + s * R .. (s + 1) * R of a second pool [window_layers, 1 + S * R, PS, KD]
+(its page 0 the dump page), and logical page j lands in ring entry j % R: a
+page that slides out of the window is the one the next page reuses. The ring
+is STATIC: no free list, no allocation and no preemption on its side; every
+table snapshot (`block_table`, `slot_row`) carries the slot's R ring entries
+after its P logical pages, and the pools are the pair (full, ring)."""
 
 from __future__ import annotations
 
@@ -53,6 +63,13 @@ import numpy as np
 
 from paddle_tpu.obs import metrics as obs_metrics
 from paddle_tpu.serving.prefix_cache import PrefixIndex
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages a slot's ring holds for a window of `window` positions: the most
+    pages the window spans (its first position mid-page), so that the page a
+    step writes is never one the same step still reads."""
+    return -(-(int(window) - 1) // int(page_size)) + 1
 
 
 class PagedKVCache:
@@ -75,6 +92,8 @@ class PagedKVCache:
         pool_dtype=None,
         prefix_cache: bool = False,
         prefix_cache_pages: Optional[int] = None,
+        window_layers: int = 0,
+        window: int = 0,
     ):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is the dump page)")
@@ -119,6 +138,13 @@ class PagedKVCache:
         self._slot_hit: List[int] = [0] * max_slots
         self._slot_reg: List[int] = [0] * max_slots
         self._slot_node: List[int] = [0] * max_slots
+        # window layers' static ring (module docstring): R entries a slot
+        self.window_layers = int(window_layers)
+        self.window = int(window) if self.window_layers else 0
+        self.ring = ring_pages(self.window, page_size) if self.window else 0
+        self._ring_table = (
+            1 + np.arange(max_slots * self.ring, dtype=np.int32)
+        ).reshape(max_slots, self.ring)
 
     # -- device pool --------------------------------------------------------
     def make_pools(self):
@@ -129,6 +155,13 @@ class PagedKVCache:
 
         shape = (self.n_layers, self.num_pages, self.page_size, self.kv_dim)
         dtype = self.pool_dtype or jnp.float32
+        if self.ring:
+            # the pair (full, ring): one pool a kind of layer
+            if self.pool_sharding is not None:
+                raise ValueError("window layers' pages are not built under a mesh")
+            rings = (self.window_layers, 1 + self.max_slots * self.ring) + shape[2:]
+            return ((jnp.zeros(shape, dtype), jnp.zeros(rings, dtype)),
+                    (jnp.zeros(shape, dtype), jnp.zeros(rings, dtype)))
         if self.pool_sharding is None:
             return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
         zeros = jax.jit(
@@ -436,6 +469,12 @@ class PagedKVCache:
     def slot_pages(self, slot: int) -> List[int]:
         return list(self._slot_pages[slot])
 
+    def held_pages(self, slots) -> tuple:
+        """(full-pool pages, ring pages) that `slots` hold with K/V in them
+        or about to be: a ring holds at most R of a slot's pages."""
+        full = [len(self._slot_pages[s]) for s in slots]
+        return sum(full), sum(min(n, self.ring) for n in full)
+
     def page_refcount(self, page: int) -> int:
         return self._refcount[page]
 
@@ -470,10 +509,16 @@ class PagedKVCache:
         the SCALAR-PREFETCH operand of the ragged paged-attention kernel
         (ops/pallas/paged_attention.py): its rows name the physical page of
         each copy the kernel issues, a block of pages at a time."""
+        if self.ring:
+            return np.concatenate([self._table, self._ring_table], 1)
         return self._table.copy()
 
     def slot_row(self, slot: int) -> np.ndarray:
         """One slot's [1, max_pages_per_seq] block-table row — the shape the
         per-slot prefill/commit/chunk executables take (a snapshot, as
         `block_table` is)."""
+        if self.ring:
+            return np.concatenate(
+                [self._table[slot : slot + 1], self._ring_table[slot : slot + 1]], 1
+            )
         return self._table[slot : slot + 1].copy()

@@ -117,6 +117,25 @@ def _rms(x: Array, scale: Array) -> Array:
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
 
 
+def _kth_largest(x: Array, k: Array) -> Array:
+    """The k-th largest of float32 x [V] (k traced, 1 <= k <= V), exactly
+    the value `jnp.sort(x)[::-1][k - 1]` holds, found bit by bit over the
+    floats' order as unsigned ints: 32 counts over x, no sort. A sort of a
+    vocabulary of 200k took 32 s of each serving program's compile for v5e
+    (ahead of time, without a chip); this takes a second."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    # order-preserving: negatives reversed below the positives
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where(jnp.sum(key >= cand) >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, bit, jnp.uint32(0))
+    back = jnp.where(t >> 31 == 1, t & jnp.uint32(0x7FFFFFFF), ~t)
+    return jax.lax.bitcast_convert_type(back, jnp.float32)
+
+
 class PagedLM:
     """What every served decoder shares and no architecture owns: placement
     on the TP mesh, on-device sampling, the three programs that are a
@@ -217,6 +236,14 @@ class PagedLM:
         return jnp.float32
 
     @property
+    def cache_windows(self) -> Tuple[int, ...]:
+        """A window a cache layer (0: the layer attends to the whole
+        context): the layers with one keep their pages in a ring a slot
+        (serving/kv_cache.py), the pools and every block-table row then the
+        pair (full, ring). No windows here."""
+        return (0,) * self.cache_layers
+
+    @property
     def kv_group(self) -> int:
         """Query heads that read one K/V head: the pool is `n_heads //
         kv_group` heads wide under a query of `n_heads`."""
@@ -268,7 +295,7 @@ class PagedLM:
                 key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
                 # top-k as a threshold: keep logits >= the k-th largest
                 # (ties keep all — deterministic, no index shuffling)
-                thr = jnp.sort(lg)[::-1][jnp.clip(k, 1, lg.shape[-1]) - 1]
+                thr = _kth_largest(lg, jnp.clip(k, 1, lg.shape[-1]))
                 keep = (k <= 0) | (lg >= thr)
                 safe_t = jnp.where(temp > 0, temp, 1.0)
                 z = jnp.where(keep, lg / safe_t, NEG_INF).astype(jnp.float32)
@@ -427,6 +454,7 @@ class PagedLM:
         lengths: Array,  # [B] — the FULL prompt length
         block_rows: Array,  # [B, max_pages_per_seq] int32
         starts: Array,  # [B] — position of kc[..., 0, :] (0 = whole prompt)
+        ring: int = 0,
     ) -> Tuple[Array, Array]:
         """Write prompt K/V into the slots' pages at offset `starts`
         (whole-prompt prefill passes zeros; chunked prefill and speculation
@@ -438,12 +466,22 @@ class PagedLM:
         operand out with the indexed dims major, so a window that spans the
         layer dim costs two relayouts of each whole pool a call (four
         `copy f32[24,833,16,2048]`, 32-53 ms a prefill and 2.62 GB of
-        temporaries at the served cell's geometry; PERF.md, PR 32)."""
+        temporaries at the served cell's geometry; PERF.md).
+
+        `ring` R > 0: `block_rows` [B, R] are rings of pages (window layers,
+        serving/kv_cache.py), logical page j written to entry j % R, and a
+        position the same write would overwrite R pages later goes to the
+        dump page instead: no two positions of one scatter share a place."""
         ps = k_pages.shape[2]
         l, b, t, kd = kc.shape
         pos = starts[:, None] + jnp.arange(t)[None, :]  # [B, T] absolute
         valid = pos < lengths[:, None]  # [B, T]
-        logical = jnp.minimum(pos // ps, block_rows.shape[1] - 1)
+        if ring:
+            last = jnp.minimum(lengths, starts + t)[:, None] - 1
+            valid = valid & (pos > last - ring * ps)
+            logical = (pos // ps) % ring
+        else:
+            logical = jnp.minimum(pos // ps, block_rows.shape[1] - 1)
         page = jnp.take_along_axis(block_rows, logical, axis=1)
         page = jnp.where(valid, page, 0).reshape(-1)  # [B*T]
         offs = (pos % ps).reshape(-1)
@@ -476,6 +514,7 @@ class PagedLM:
         layer,               # int, or a traced scalar inside a scanned stack
         n_heads: int,
         group: int = 1,
+        window: int = 0,
     ) -> Array:
         """Ragged paged attention over `n_heads` heads of layer `layer` (the
         FULL head count on one chip; the LOCAL slice per shard under TP —
@@ -490,7 +529,13 @@ class PagedLM:
         softmax in VMEM) when `pallas.enabled()`
         (TPU, or PADDLE_TPU_PALLAS=1/interpret), else the dense jnp gather —
         which is also the kernel's CPU ORACLE: interpret-mode equality across
-        mixed lengths/block tables is pinned in tests/test_decode_fastpath."""
+        mixed lengths/block tables is pinned in tests/test_decode_fastpath.
+
+        `window` W > 0: each slot attends to its last W positions through
+        `block_table` [S, R], its ring of pages (serving/kv_cache.py).
+        Either path counts itself where it is traced
+        (`paddle_tpu_paged_attention_decode_total`, by path and window)."""
+        from paddle_tpu.obs import metrics as obs_metrics
         from paddle_tpu.ops import pallas as _pallas
 
         s = q.shape[0]
@@ -500,15 +545,18 @@ class PagedLM:
                 paged_attention_decode,
             )
 
+            obs_metrics.observe_paged_attention_decode("kernel", window)
             return paged_attention_decode(
                 q, k_pages, v_pages, block_table, positions,
                 layer=layer, scale=self.scale, n_heads=h_, group=group,
+                window=window,
             ).astype(q.dtype)
+        obs_metrics.observe_paged_attention_decode("oracle", window)
         ps = k_pages.shape[2]
-        if group > 1:
+        if group > 1 or window:
             return self._grouped_attention_oracle(
                 q, k_pages[layer][block_table], v_pages[layer][block_table],
-                positions, h_ // group, group,
+                positions, h_ // group, group, window,
             )
         qh = q.reshape(s, h_, hd)
         # dense gather: [S, P, PS, KD] -> [S, T_ctx, H, hd]
@@ -526,14 +574,26 @@ class PagedLM:
             "sht,sthd->shd", w, v_seq, preferred_element_type=jnp.float32
         ).astype(q.dtype).reshape(s, -1)
 
-    def _grouped_attention_oracle(self, q, k_seq, v_seq, positions, n_kv, group):
+    def _grouped_attention_oracle(self, q, k_seq, v_seq, positions, n_kv, group,
+                                  window=0):
         """The dense gather path with `group` query heads a K/V head: k_seq,
-        v_seq [S, P, PS, n_kv * hd] the slot's gathered pages."""
+        v_seq [S, P, PS, n_kv * hd] the slot's gathered pages; with a window,
+        [S, R, PS, ...] its ring, entry r holding the latest logical page j
+        <= pos // PS with j % R == r."""
         s, hd = q.shape[0], self.cfg.head_dim
+        ps = k_seq.shape[2]
         qh = q.reshape(s, n_kv, group, hd)
         k_seq = k_seq.reshape(s, -1, n_kv, hd)
         v_seq = v_seq.reshape(s, -1, n_kv, hd)
-        seen = jnp.arange(k_seq.shape[1])[None, :] <= positions[:, None]
+        if window:
+            ring = k_seq.shape[1] // ps
+            top = (positions // ps)[:, None]
+            page = top - (top - jnp.arange(ring)[None, :]) % ring          # [S, R]
+            at = (page[:, :, None] * ps + jnp.arange(ps)).reshape(s, -1)   # [S, R*PS]
+            seen = ((at >= 0) & (at <= positions[:, None])
+                    & (at > positions[:, None] - window))
+        else:
+            seen = jnp.arange(k_seq.shape[1])[None, :] <= positions[:, None]
         sc = jnp.einsum(
             "scgd,stcd->scgt", qh, k_seq, preferred_element_type=jnp.float32
         ) * self.scale
@@ -551,6 +611,7 @@ class PagedLM:
         block_table: Array,  # [S, P]
         positions: Array,    # [S]
         layer: int,
+        window: int = 0,
     ) -> Array:
         """The TP dispatch seam over `_paged_attention_local`.
 
@@ -567,6 +628,7 @@ class PagedLM:
             return self._paged_attention_local(
                 q, k_pages, v_pages, block_table, positions,
                 layer=layer, n_heads=self.cfg.n_heads, group=self.kv_group,
+                window=window,
             )
         local = functools.partial(
             self._paged_attention_local,

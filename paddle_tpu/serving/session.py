@@ -230,6 +230,19 @@ class ServingSession:
                    "speculate_k rolls a rejected draft back by trimming "
                    "pages, and the recurrence cannot be rolled back")
             )
+        # window layers keep a ring of pages a slot (kv_cache.py): nothing
+        # there to alias a prefix into or to trim a rejected draft out of
+        windows = tuple(int(w) for w in model.cache_windows)
+        if any(windows) and (self.prefix_cache or self.speculate_k):
+            raise ValueError(
+                f"{type(model).__name__} has window layers, whose pages are a "
+                "ring a slot that the session neither aliases nor trims: "
+                + ("prefix_cache would alias pages the ring has already "
+                   "reused" if self.prefix_cache else
+                   "speculate_k rolls a rejected draft back by trimming pages")
+            )
+        if len(set(w for w in windows if w)) > 1:
+            raise ValueError(f"one window for every window layer, not {windows}")
         # per-seq page budget covers the verify chunk's K-token overshoot
         pages_per_seq = -(-(max_ctx + self.speculate_k) // page_size)
         if num_pages is None:
@@ -239,7 +252,9 @@ class ServingSession:
         # leaves (a looped stack leaves one a pass and layer), how wide, and
         # in what type
         self.cache = PagedKVCache(
-            n_layers=model.cache_layers,
+            n_layers=windows.count(0),
+            window_layers=len(windows) - windows.count(0),
+            window=max(windows, default=0),
             kv_dim=model.cache_width,
             pool_dtype=model.cache_dtype,
             num_pages=num_pages,
@@ -268,7 +283,7 @@ class ServingSession:
         self.layer_passes = int(model.layer_passes)
         obs_metrics.set_kv_bytes_per_token(
             2 * int(model.cache_layers) * model.cache_width
-            * self.k_pages.dtype.itemsize
+            * jax.tree.leaves(self.k_pages)[0].dtype.itemsize
         )
         obs_metrics.set_recurrent_state_bytes_per_slot(sum(
             int(np.prod(shape)) * np.dtype(dtype).itemsize
@@ -679,12 +694,15 @@ class ServingSession:
             seeds, temps, top_ks = self._sampling_row(h)
             rows = self.cache.slot_row(slot)
             waited = self._wait_ns
+            # the first position the chunk's window layers read
+            window_from = (max(0, start - self.cache.window + 1)
+                           if self.cache.window else 0)
             # span-ok: the flight recorder's one ring write a CHUNK, int
             # attrs: the chunk's dispatch and, behind the prompt's last, the
             # first token's fetch; under the request's trace where it has one
             with trace.activate(h.trace_ctx), trace.flight(
                 "serve.chunk", request_id=h.request_id, start=start,
-                tokens=len(piece),
+                tokens=len(piece), window_from=window_from,
             ) as sp:
                 # ONE dispatch per chunk: forward + commit fused, pages
                 # donated through (see model.prefill_chunk docstring)
@@ -1050,6 +1068,12 @@ class ServingSession:
             top_ks[slot] = act.handle.top_k
         bt = self.cache.block_table()
         prev_tok = self._prev_tok
+        # what the lanes hold: pages of the full pool and of the rings, and
+        # the positions their attention reads (perfbench's
+        # kv_bytes_per_context_token)
+        pages_full, pages_window = self.cache.held_pages(
+            [slot for slot, _ in active])
+        context = int(positions.sum()) + len(active)
         # zero-recompile assertion data: the decode signature must be the
         # same every step no matter the request mix (fixed [max_slots] shape)
         self.recompiles.record(
@@ -1067,7 +1091,8 @@ class ServingSession:
         # (perfbench: `slots`) and how much of it only rebuilds K/V
         with trace.flight(
             "serve.decode", slots=len(active), layer_passes=self.layer_passes,
-            replaying=replaying,
+            replaying=replaying, pages_full=pages_full,
+            pages_window=pages_window, context_tokens=context,
         ) as sp:
             next_tok = self._dispatch_decode(
                 tokens, prev_tok, from_prev, positions, act_mask, bt, seeds,
@@ -1508,8 +1533,8 @@ class ServingSession:
         lane = np.zeros(s, bool)
         held = () if self.state is None else (jax.tree.map(aval, self.state),)
         return self._decode.lower(
-            jax.tree.map(aval, self.params), aval(self.k_pages),
-            aval(self.v_pages), *held, i32, aval(self._prev_tok), lane, i32,
+            jax.tree.map(aval, self.params), jax.tree.map(aval, self.k_pages),
+            jax.tree.map(aval, self.v_pages), *held, i32, aval(self._prev_tok), lane, i32,
             lane, self.cache.block_table(), np.zeros(s, np.uint32), i32, f32,
             i32,
         ).compile().as_text()

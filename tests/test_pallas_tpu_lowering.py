@@ -71,6 +71,16 @@ def _paged(n_heads):
     return f
 
 
+def _paged_window(n_heads, group, window):
+    def f(q, k_pages, v_pages, table, positions):
+        return paged_attention.paged_attention_decode(
+            q, k_pages, v_pages, table, positions,
+            layer=1, scale=0.088, n_heads=n_heads, group=group, window=window,
+        )
+
+    return f
+
+
 def _ssm_shapes(s, m, h, p, n):
     bf16 = jnp.bfloat16
     return [(s, m, h, p, n), ((), jnp.int32), ((s, h, p), bf16), (s, h), (h,),
@@ -113,6 +123,15 @@ CASES = [
      _paged_shapes(32, 16, 128, 16, 128, layers=24, n_pages=833), 1),
     ("paged_tp_shard", _paged(4),
      _paged_shapes(32, 4, 128, 16, 128, layers=24, n_pages=833), 1),
+    # trinity_mini's cell: 64 slots, 32 query heads over 4 K/V heads of 128,
+    # bfloat16; a window layer's ring of 129 pages a slot over 5 layers, and
+    # the full layer's 1120 pages a slot
+    ("paged_window_cell", _paged_window(32, 8, 2048),
+     [((64, 4096), jnp.bfloat16), ((5, 1 + 64 * 129, 16, 512), jnp.bfloat16),
+      ((5, 1 + 64 * 129, 16, 512), jnp.bfloat16), ((64, 129), jnp.int32), ((64,), jnp.int32)], 1),
+    ("paged_full_trinity", _paged_window(32, 8, 0),
+     [((64, 4096), jnp.bfloat16), ((2, 71681, 16, 512), jnp.bfloat16),
+      ((2, 71681, 16, 512), jnp.bfloat16), ((64, 1120), jnp.int32), ((64,), jnp.int32)], 1),
     # the Mamba-2 decode step at granite_4_0_h_small's cell (64 slots, a stack
     # of nine layers of 128 heads of 64 x 128, the layer traced) and at
     # chip_smoke's small hybrid model

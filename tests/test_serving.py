@@ -410,7 +410,7 @@ def test_generation_session_builds_once(monkeypatch):
 # -- one decode step in flight (ISSUE 36) --------------------------------------------
 #
 # The engine dispatches decode step N before it fetches step N-1's tokens.
-# What that may never change is a token: over the three served models, a
+# What that may never change is a token: over the four served models, a
 # session driven by step() serves what the full-context reference gives
 # across everything that is learned one step late (an EOS, a cancel, an
 # expiry), a dry pool that preempts, the shortest budgets, and both ways an
@@ -439,12 +439,14 @@ def _tiny_model(kind):
         return model, model.init_params(jax.random.PRNGKey(0))
     if kind == "looped":
         from test_looped_lm import tiny
-    else:
+    elif kind == "hybrid":
         from test_hybrid_moe_lm import tiny
+    else:
+        from test_window_moe_lm import tiny
     return tiny()
 
 
-@pytest.fixture(scope="module", params=["servable", "looped", "hybrid"])
+@pytest.fixture(scope="module", params=["servable", "looped", "hybrid", "window"])
 def flight_model(request):
     """The model with its EOS set to a token that ONE prompt's greedy answer
     reaches at its third draw or later (and, where the tiny weights allow,
