@@ -65,6 +65,10 @@ HYBRID_TIE_TOL = 0.05
 # the same float32 products summed in another order (the new state must be
 # the oracle's bit for bit)
 SSM_Y_TOL = 1e-5
+# The expert block with the grouped-matmul kernel against ragged-dot's, over
+# its largest output: the same bfloat16 products accumulated in float32 (the
+# interpreter's dot on the CPU sums in another order)
+MOE_TOL = 1e-2
 
 FULL = dict(
     gru=[(50, 128, 512)],
@@ -74,6 +78,10 @@ FULL = dict(
     paged=[(8, 2, 16, 16, 8), (16, 16, 128, 16, 8)],
     # (slots, Mamba layers, heads, head_dim, state): granite's cell
     ssm=[(64, 9, 128, 64, 128)],
+    # (tokens, top_k, expert layers, held, routed, d_model, expert width):
+    # the decode step's expert block at trinity_mini's and granite's cells
+    # (two expert layers of the stack, the second run)
+    moe=[(64, 8, 2, 128, 128, 2048, 1024), (64, 10, 2, 36, 72, 4096, 768)],
     serve=[
         ["--demo"],
         ["--demo", "--d_model=2048", "--n_heads=16", "--n_layers=2",
@@ -88,6 +96,7 @@ REHEARSAL = dict(
     attn=[(2, 16, 16, 32)],
     paged=[(4, 2, 16, 8, 3), (4, 2, 128, 8, 3)],
     ssm=[(2, 3, 8, 64, 128)],
+    moe=[(8, 2, 2, 4, 8, 128, 64)],
     serve=[
         ["--demo", "--prefill_buckets=16,32", "--max_new_limit=16"],
         ["--demo", "--d_model=512", "--n_heads=4", "--n_layers=1",
@@ -268,6 +277,9 @@ def phase_kernels(size, on_chip: bool) -> None:
     for shape in size["ssm"]:
         check_ssm_decode(shape, flag, on_chip)
 
+    for shape in size["moe"]:
+        check_expert_block(shape, flag, on_chip)
+
 
 def check_ssm_decode(shape, kernel_flag, on_chip):
     """The Mamba-2 decode kernel against `mamba2.ssm_step` on the layer's
@@ -311,6 +323,46 @@ def check_ssm_decode(shape, kernel_flag, on_chip):
         f"{t_kernel:.2f} ms kernel, {t_oracle:.2f} ms oracle, a call with its "
         "copy of the stack")
     assert differ == 0 and y_err <= SSM_Y_TOL, (differ, y_err)
+
+
+def check_expert_block(shape, kernel_flag, on_chip):
+    """The routed expert block (serving/moe.py) with its grouped products
+    the Pallas kernel's (ops/pallas/grouped_matmul.py) against ragged-dot's,
+    the layer traced: the block's output within MOE_TOL of its largest
+    entry (bit for bit on the chip: PERF.md, section 6)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.serving import moe
+
+    t, k, layers, held, routed, d, f = shape
+    ks = jax.random.split(jax.random.PRNGKey(44), 4)
+    args = (
+        jax.random.normal(ks[0], (t, d), jnp.bfloat16), jnp.arange(t) % 7 != 3,
+        jax.random.normal(ks[1], (d, routed), jnp.bfloat16),
+        jax.random.normal(ks[2], (layers, held, d, 2 * f), jnp.bfloat16),
+        jax.random.normal(ks[3], (layers, held, f, d), jnp.bfloat16),
+        jnp.asarray(layers - 1, jnp.int32),
+    )
+    local_of = np.minimum(np.arange(routed), held).astype(np.int32)
+
+    def compiled(flag):  # a fresh function a flag: jit's cache is the function's
+        with pallas_flag(flag):
+            return jax.jit(lambda *a: moe.expert_block(
+                *a, top_k=k, local_of=local_of, dtype=jnp.bfloat16)[0]).lower(*args).compile()
+
+    kernel, oracle = compiled(kernel_flag), compiled("0")
+    assert ("tpu_custom_call" in kernel.as_text()) == on_chip, (
+        "grouped_matmul: a Mosaic custom call is expected on the chip, and only there")
+    got, want = kernel(*args).astype(jnp.float32), oracle(*args).astype(jnp.float32)
+    t_kernel, t_oracle = ms_per_call(kernel, *args), ms_per_call(oracle, *args)
+    differ = int(np.sum(np.asarray(got) != np.asarray(want)))
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    say(f"  expert block T{t} k{k} E{held}/{routed} D{d} F{f}: outputs not bit for bit "
+        f"ragged-dot's {differ}, err {err:.2e} (tol {MOE_TOL:.0e}); info: "
+        f"{t_kernel:.2f} ms kernel, {t_oracle:.2f} ms ragged-dot, a block")
+    assert err <= MOE_TOL, (differ, err)
 
 
 # -- phase: serve -------------------------------------------------------------

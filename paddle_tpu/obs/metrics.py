@@ -326,6 +326,18 @@ def observe_ssm_decode(path: str) -> None:
     ).inc(path=path)
 
 
+def observe_grouped_matmul(path: str) -> None:
+    """One of the routed expert block's two grouped products was TRACED
+    (once per product each time a step is traced, never per step;
+    serving/moe.expert_block): path is 'kernel' where the Pallas kernel runs
+    it (ops/pallas/grouped_matmul.py), 'ragged_dot' where XLA's ragged-dot
+    does."""
+    REGISTRY.counter(
+        "paddle_tpu_grouped_matmul_total",
+        "grouped expert products traced, by path (kernel|ragged_dot)",
+    ).inc(path=path)
+
+
 def observe_paged_attention_decode(path: str, window: int) -> None:
     """A layer's decode attention over the page pool was TRACED into a step
     (once per call each time the step is traced, never per step;

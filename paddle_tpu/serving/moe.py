@@ -1,8 +1,8 @@
 """The routed expert block two served decoders share (HybridMoELM and
 WindowMoELM): route every token to `top_k` experts, sort the assignments by
-expert, and run the experts' two products as grouped products
-(`jax.lax.ragged_dot`) over the assignments that landed on an expert this
-chip holds. No capacity and no dropped token.
+expert, and run the experts' two products as grouped products over the
+assignments that landed on an expert this chip holds. No capacity and no
+dropped token.
 
 Two routings, the model's to declare (`choose`):
 
@@ -14,11 +14,21 @@ with r = h W_r in float32; b moves which experts are chosen and never how
 much each one weighs.
 
 The expert stacks are taken WHOLE, [L, E, ...] over the model's L expert
-layers, and the layer is an argument: the grouped product takes every
-layer's experts as its groups and this layer's alone have rows, so the
-weights are read where they lie. A slice of the stack cut for the custom
-call is a copy of the layer's experts a layer and step (0.68 GB, a third of
-granite's step on the chip; PERF.md)."""
+layers, and the layer is an argument: ragged-dot takes every layer's experts
+as its groups and this layer's alone have rows, the kernel takes the layer
+as an index of its blocks, so the weights are read where they lie. A slice
+of the stack cut for the custom call is a copy of the layer's experts a
+layer and step (0.68 GB, a third of granite's step on the chip; PERF.md).
+
+Which grouped product runs is one rule on a shape the trace sees, the mean
+rows a held expert gets, T k / E held: at or under `KERNEL_ROWS_PER_EXPERT`,
+where the kernel's blocks fit VMEM, the Pallas kernel streams each held
+expert's weights back to back (ops/pallas/grouped_matmul.py: Trinity's decode
+step, 64 slots x 8 over 128 experts, 4 an expert; granite's decode step and
+its prefills to 512 tokens, 17.8 to 142.2 an expert); elsewhere XLA's
+`jax.lax.ragged_dot` (Trinity's 2048-token chunk, whose 16,384 rows do not
+fit: 128 an expert). On CPU `ops.pallas.enabled()` is off and ragged-dot,
+the kernel's oracle, runs everywhere."""
 
 from __future__ import annotations
 
@@ -28,8 +38,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddle_tpu.obs import metrics as obs_metrics
+from paddle_tpu.ops import pallas
+from paddle_tpu.ops.pallas import grouped_matmul as gm
+
 Array = jax.Array
 F32 = jnp.float32
+
+# the most rows a held expert gets on average, T k / E held, at which the
+# grouped products are the Pallas kernel's: it beat ragged-dot at every mean
+# timed on the chip where its blocks fit, 3.6 to 142.2 (granite's 512-token
+# prefill), and nothing past that was timed (benchmarks/grouped_matmul_bench.py;
+# PERF.md)
+KERNEL_ROWS_PER_EXPERT = 144
 
 
 def choose(logits: Array, top_k: int, bias: Optional[Array] = None,
@@ -55,9 +76,6 @@ def expert_block(h: Array, valid: Array, router: Array, wi: Array, wo: Array, la
     in `dtype`, counts of the valid tokens' assignments by held expert [E
     held], (landed here, went to an absent expert) [2])."""
     t, k, e = h.shape[0], top_k, wi.shape[1]
-    n_layers = wi.shape[0]
-    wi = wi.reshape((n_layers * e,) + wi.shape[2:])
-    wo = wo.reshape((n_layers * e,) + wo.shape[2:])
     logits = jnp.matmul(h, router, preferred_element_type=F32)
     gate, idx = choose(logits, k, bias, route_scale)          # [T, k]
     # an assignment's group: its expert's place among the held; group e,
@@ -67,14 +85,8 @@ def expert_block(h: Array, valid: Array, router: Array, wi: Array, wo: Array, la
     order = jnp.argsort(group, stable=True)                   # [T * k]
     sizes = jnp.sum(group[:, None] == jnp.arange(e)[None, :], 0, dtype=jnp.int32)
     rows = h[order // k]                                      # [T * k, D], by expert
-    groups = jax.lax.dynamic_update_slice(
-        jnp.zeros(n_layers * e, jnp.int32), sizes, (layer * e,))
-    a, b = jnp.split(
-        jax.lax.ragged_dot(rows, wi, groups, preferred_element_type=F32)
-        .astype(dtype), 2, -1)
-    out = jax.lax.ragged_dot(
-        jax.nn.silu(a) * b, wo, groups, preferred_element_type=F32
-    ).astype(dtype)
+    a, b = jnp.split(_grouped(rows, wi, sizes, layer).astype(dtype), 2, -1)
+    out = _grouped(jax.nn.silu(a) * b, wo, sizes, layer).astype(dtype)
     # back in (token, choice) order and summed in that order, so a token's
     # sum does not depend on who shares the batch; rows past the groups
     # hold nothing that counts
@@ -85,3 +97,20 @@ def expert_block(h: Array, valid: Array, router: Array, wi: Array, wo: Array, la
     landed = jnp.sum(sizes)
     absent = jnp.sum(valid) * k - landed
     return out, sizes.astype(jnp.uint32), jnp.stack([landed, absent]).astype(jnp.uint32)
+
+
+def _grouped(rows: Array, w: Array, sizes: Array, layer) -> Array:
+    """rows [M, K] sorted by expert times layer `layer` of the whole stack w
+    [L, E, K, N], sizes [E] each held expert's rows: float32 [M, N], rows
+    past the groups 0. The kernel or ragged-dot by the module's rule; runs
+    where a step is traced, so the counter moves once a product a trace."""
+    (m, kd), (n_layers, e, _, n) = rows.shape, w.shape
+    if (pallas.enabled() and m <= KERNEL_ROWS_PER_EXPERT * e
+            and gm.fits(m, kd, n, rows.dtype, w.dtype)):
+        obs_metrics.observe_grouped_matmul("kernel")
+        return gm.grouped_matmul(rows, w, sizes, layer)
+    obs_metrics.observe_grouped_matmul("ragged_dot")
+    # every layer's experts are groups, and only this layer's have rows
+    groups = jax.lax.dynamic_update_slice(jnp.zeros(n_layers * e, jnp.int32), sizes, (layer * e,))
+    return jax.lax.ragged_dot(rows, w.reshape((n_layers * e,) + w.shape[2:]), groups,
+                              preferred_element_type=F32)

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.ops.pallas import paged_attention, rnn_kernels, ssm_decode
+from paddle_tpu.ops.pallas import grouped_matmul, paged_attention, rnn_kernels, ssm_decode
 from paddle_tpu.serving.session import decode_step_in_flight
 
 
@@ -31,6 +31,7 @@ def _mosaic_not_interpret(monkeypatch):
     monkeypatch.setattr(rnn_kernels, "interpret_mode", lambda: False)
     monkeypatch.setattr(paged_attention, "interpret_mode", lambda: False)
     monkeypatch.setattr(ssm_decode, "interpret_mode", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
 
 
 def _lstm_fwd(proj, mask, w, b, h0, c0):
@@ -87,6 +88,12 @@ def _ssm_shapes(s, m, h, p, n):
             ((s, n), bf16), ((s, n), bf16), ((s,), jnp.bool_)]
 
 
+def _gmm_shapes(m, layers, experts, k, n):
+    bf16 = jnp.bfloat16
+    return [((m, k), bf16), ((layers, experts, k, n), bf16), ((experts,), jnp.int32),
+            ((), jnp.int32)]
+
+
 def _lstm_shapes(t, b, h):
     return [(t, b, 4 * h), (t, b, 1), (h, 4 * h), (4 * h,), (b, h), (b, h)]
 
@@ -137,6 +144,14 @@ CASES = [
     # chip_smoke's small hybrid model
     ("ssm_decode_cell", ssm_decode.ssm_decode, _ssm_shapes(64, 9, 128, 64, 128), 1),
     ("ssm_decode_small", ssm_decode.ssm_decode, _ssm_shapes(2, 3, 8, 64, 128), 1),
+    # the decode step's grouped expert products: trinity_mini's (64 slots x
+    # top-8 over 128 experts, 4 expert layers of 2048 -> 2 x 1024 -> 2048)
+    # and granite_4_0_h_small's (64 x top-10, 36 held of 72, 10 layers of
+    # 4096 -> 2 x 768 -> 4096), the layer traced
+    ("gmm_trinity_wi", grouped_matmul.grouped_matmul, _gmm_shapes(512, 4, 128, 2048, 2048), 1),
+    ("gmm_trinity_wo", grouped_matmul.grouped_matmul, _gmm_shapes(512, 4, 128, 1024, 2048), 1),
+    ("gmm_granite_wi", grouped_matmul.grouped_matmul, _gmm_shapes(640, 10, 36, 4096, 1536), 1),
+    ("gmm_granite_wo", grouped_matmul.grouped_matmul, _gmm_shapes(640, 10, 36, 768, 4096), 1),
 ]
 
 
@@ -338,8 +353,9 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
     the stacked state a second time for y, the grouped
     expert products take the WHOLE expert stacks (no instruction of a
     layer's experts' shape or of the state's is a copy or a slice cut for
-    them: either is a third of the step), pools and state are the outputs'
-    buffers, and the temporaries are megabytes."""
+    them: either is a third of the step) and are the grouped-matmul
+    kernel, pools and state are the outputs' buffers, and the temporaries
+    are megabytes."""
     from paddle_tpu.serving.hybrid_moe_lm import HybridMoEConfig, HybridMoELM
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
@@ -393,12 +409,80 @@ def test_the_hybrid_decode_step_reads_experts_and_state_where_they_lie(on_chip, 
         and "dynamic-update-slice" not in line.split("=")[0]
     ]
     assert not cut, cut
+    # the expert products, 640 rows over 36 held experts (17.8 an expert,
+    # under moe.KERNEL_ROWS_PER_EXPERT), are the grouped-matmul kernel: twice
+    # in each scanned Mamba run's body and twice for the attention layer
+    assert sorted(re.findall(r"%grouped_matmul\S* = f32\[640,(\d+)\]\S* custom-call\(", text)) \
+        == ["1536"] * 3 + ["4096"] * 3
+    assert "ragged-dot" not in text
     memory = compiled.memory_analysis()
     held = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in state.values())
     # (the tail's three rows are laid out padded: at least, and within a tenth)
     expected = 2 * 2 * int(np.prod(pool)) + held
     assert expected <= memory.alias_size_in_bytes < 1.1 * expected
     assert memory.argument_size_in_bytes < 12.6e9 and memory.temp_size_in_bytes < 64e6
+
+
+# -- the window-and-experts decoder's decode step, at Trinity-Mini's size ------
+
+def test_the_window_moe_decode_step_streams_its_experts_through_the_kernel(on_chip, monkeypatch):
+    """WindowMoELM.decode_step at trinity_mini's shapes (64 slots, four
+    expert layers of 128 experts top-8, bfloat16, one full layer's pool and
+    five window layers' rings) compiled for the described v5e: the eight
+    grouped expert products (two a layer) are the grouped-matmul kernel and
+    no ragged-dot is left in the program; no instruction of a layer's
+    experts' shape is a copy or a slice (the kernel reads the whole stack
+    where it lies); the 2048-token chunk's products, 128 rows an expert,
+    stay ragged-dots."""
+    from paddle_tpu.serving.window_moe_lm import FULL, WINDOW, WindowMoEConfig, WindowMoELM
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
+    model = WindowMoELM(WindowMoEConfig(
+        vocab=200192, layer_types=(WINDOW,) * 3 + (FULL,) + (WINDOW,) * 2, d_model=2048,
+        n_heads=32, n_kv_heads=4, head_dim=128, window=2048, n_dense=2, dense_width=6144,
+        num_experts=128, top_k=8, expert_width=1024, shared_width=1024, route_scale=2.826,
+        embedding_scale=2048 ** 0.5, max_len=17920, dtype="bfloat16",
+    ))
+    slots, pages, ring = 64, 1120, 129
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on_chip)
+
+    params = jax.tree.map(
+        lambda a: aval(a.shape, a.dtype),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+    )
+    pools = tuple((aval((1, 71681, 16, 512), jnp.bfloat16),
+                   aval((5, 1 + slots * ring, 16, 512), jnp.bfloat16)) for _ in range(2))
+    state = {k: aval(s, d) for k, (s, d) in model.counter_spec().items()}
+    s = (slots,)
+    # the session's decode step, as the looped test above
+    compiled = jax.jit(decode_step_in_flight(model), donate_argnums=(1, 2, 3)).lower(
+        params, *pools, state,
+        aval(s, jnp.int32), aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval(s, jnp.int32), aval(s, jnp.bool_),
+        aval((slots, pages + ring), jnp.int32), aval(s, jnp.uint32), aval(s, jnp.int32),
+        aval(s, jnp.float32), aval(s, jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert params["moe_wi"].shape == (4, 128, 2048, 2048)
+    assert len(re.findall(r"%grouped_matmul\S* = f32\[512,2048\]\S* custom-call\(", text)) == 8, \
+        "the grouped-matmul kernel expected twice an expert layer"
+    assert "ragged-dot" not in text and "ragged_dot" not in text
+    cut = [
+        line.strip()[:140] for line in text.splitlines()
+        if re.search(r"= bf16\[(4,)?128,(2048|1024),2048\]\S* (copy|fusion|dynamic-slice|slice)\(",
+                     line)
+    ]
+    assert not cut, cut
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    # the chunk program, lowered: its products are XLA's
+    chunk = jax.jit(model.prefill_chunk).trace(
+        params, *pools, state, aval((1, 2048), jnp.int32), aval((1,), jnp.int32),
+        aval((1,), jnp.int32), aval((1, pages + ring), jnp.int32), aval((1,), jnp.int32),
+        aval((1,), jnp.uint32), aval((1,), jnp.float32), aval((1,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert chunk.count("ragged_dot") >= 8 and "grouped_matmul" not in chunk
 
 
 # -- the NMT decoder's backward, at seq2seq_nmt.train's shapes -----------------

@@ -358,3 +358,48 @@ def test_the_counters_and_the_spans_record_both_kinds_of_page():
         a["context_tokens"] for a in decodes) > 16
     # the long prompt's four chunks of 8 and the short one's two
     assert sorted(a["window_from"] for a in chunks) == [0, 0, 0, 0, 1, 9]
+
+
+def test_the_decode_steps_expert_products_are_the_kernels_and_a_long_chunks_are_not(monkeypatch):
+    """Traced under PADDLE_TPU_PALLAS=interpret, a decode step of 4 slots
+    (16 rows over 16 experts: 1 an expert) counts one `kernel` a product a
+    MoE layer, and its tokens are the ragged-dot program's; a block whose
+    rows a held expert gets on average pass the cut counts `ragged_dot`,
+    flag or not."""
+    model, params = tiny()
+    calls = REGISTRY.counter("paddle_tpu_grouped_matmul_total",
+                             "grouped expert products traced, by path (kernel|ragged_dot)")
+
+    def step():
+        s = session(model, params, max_slots=4)
+        h = s.submit(PROMPT, 6)
+        s.run_until_idle()
+        return [int(t) for t in h.tokens]
+
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "0")
+    want = step()
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    before = {p: calls.value(path=p) for p in ("kernel", "ragged_dot")}
+
+    def aval(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    s = (4,)
+    pools = tuple((aval((1, 9, PS, model.cache_width), jnp.float32),
+                   aval((5, 9, PS, model.cache_width), jnp.float32)) for _ in range(2))
+    state = {k: aval(shape, d) for k, (shape, d) in model.counter_spec().items()}
+    jax.eval_shape(model.decode_step, params, *pools, state, aval(s), aval(s), aval(s, bool),
+                   aval(s + (4 + RING,)), aval(s, jnp.uint32), aval(s), aval(s, jnp.float32),
+                   aval(s))
+    assert calls.value(path="kernel") - before["kernel"] == 2 * model.n_moe
+    assert calls.value(path="ragged_dot") == before["ragged_dot"]
+    assert step() == want
+    # past the cut: T k / E one row an expert over KERNEL_ROWS_PER_EXPERT
+    t = moe.KERNEL_ROWS_PER_EXPERT * 16 // 4 + 4
+    before = calls.value(path="ragged_dot")
+    jax.eval_shape(
+        lambda h: moe.expert_block(h, jnp.ones(t, bool), params["router"][0], params["moe_wi"],
+                                   params["moe_wo"], 0, top_k=4, local_of=model._local_of,
+                                   dtype=jnp.float32, bias=params["expert_bias"][0]),
+        jax.ShapeDtypeStruct((t, 64), jnp.float32))
+    assert calls.value(path="ragged_dot") - before == 2
