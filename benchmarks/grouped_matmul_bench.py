@@ -1,8 +1,11 @@
 """The routed expert block's grouped products, timed on the chip: the Pallas
-kernel (ops/pallas/grouped_matmul.py) against XLA's ragged-dot as
-serving/moe.py calls it (the whole stack reshaped [L * E, K, N], this
-layer's groups the only ones with rows), at both served MoE geometries and
-at several mean rows a held expert gets (T k / E held).
+kernel (ops/pallas/grouped_matmul.py), with its rows resident where they
+fit and streamed in row tiles where an expert gets many, against XLA's
+ragged-dot as serving/moe.py calls it (the whole stack reshaped [L * E, K,
+N], this layer's groups the only ones with rows), at both served MoE
+geometries and at several mean rows a held expert gets (T k / E held).
+The streamed form is timed at each of `STREAM_TILES` row tiles from 36 rows
+an expert up, its own choice marked `chosen`.
 
 Each timing is a jitted loop of calls over the stack's layers in turn (the
 layer a traced index, as a scanned decode step has it), the host's clock
@@ -41,7 +44,8 @@ GEOMETRIES = {
     "trinity_mini": (4, 128, 128, 8, 2048, 1024),
     "granite_4_0_h_small": (10, 36, 72, 10, 4096, 768),
 }
-RATIOS = (4, 8, 17.8, 36, 71, 128, 142)
+RATIOS = (4, 8, 17.8, 36, 71, 128, 142, 284)
+STREAM_TILES = (16, 64, 128, 256)
 
 
 def _sizes(rng, tokens, top_k, held, routed):
@@ -126,17 +130,25 @@ def main(argv=None):
                 emit(impl="ragged_dot", ms=t_ref * 1e3, gb_s=moved / t_ref / 1e9,
                      peak_share=moved / t_ref / PEAK_BYTES_S, **base)
                 tiling = gm._tiling(m, k, n, 2, 2)
+                variants = []
                 if tiling is None:
                     emit(impl="kernel", fits=False, **base)
-                    continue
-                variants = [tiling]
-                if args.variants and r in RATIOS[:3:2]:
-                    tm0, tn0 = tiling
-                    variants += [(tm, tn0) for tm in (32, 64, 128)]
-                    variants += [(tm0, tn) for tn in (512, 1024) if tn < tn0 and n % tn == 0]
-                for tm, tn in variants:
-                    def product(rows_, w_, sizes_, layer, tm=tm, tn=tn):
-                        return gm._grouped_matmul(rows_, w_, sizes_, layer, tm, tn)
+                else:
+                    variants.append(("kernel", gm._grouped_matmul) + tiling)
+                    if args.variants and r in RATIOS[:3:2]:
+                        tm0, tn0 = tiling
+                        variants += [("kernel", gm._grouped_matmul, tm, tn0) for tm in (32, 64, 128)]
+                        variants += [("kernel", gm._grouped_matmul, tm0, tn)
+                                     for tn in (512, 1024) if tn < tn0 and n % tn == 0]
+                if r >= 36:
+                    chosen = gm._stream_tiling(m, k, n, held, 2, 2)
+                    for tm in sorted(set(STREAM_TILES) | {chosen[0]}):
+                        variants.append(("streamed", gm._grouped_matmul_streamed, tm,
+                                         gm._stream_width(tm, k, n, 2, 2)))
+                for impl, fn, tm, tn in variants:
+                    def product(rows_, w_, sizes_, layer, fn=fn, tm=tm, tn=tn):
+                        return fn(rows_, w_, sizes_, layer, tm, tn)
+                    extra = {"chosen": (tm, tn) == chosen} if impl == "streamed" else {}
                     try:
                         got = jax.jit(_cast(product))(rows, w, sz, layer).astype(F32)
                         # the rows that landed: ragged-dot leaves the rows past the
@@ -145,12 +157,12 @@ def main(argv=None):
                         err = float(jnp.max(jnp.abs(mine - ref)) / jnp.max(jnp.abs(ref)))
                         t, _ = _timed(_looped(product, layers, calls), (rows, w, sz), calls, args.repeats)
                     except Exception as e:  # a tiling Mosaic refuses: recorded, not fatal
-                        emit(impl="kernel", tm=tm, tn=tn,
-                             error=f"{type(e).__name__}: {str(e)[:200]}", **base)
+                        emit(impl=impl, tm=tm, tn=tn,
+                             error=f"{type(e).__name__}: {str(e)[:200]}", **extra, **base)
                         continue
-                    emit(impl="kernel", tm=tm, tn=tn, ms=t * 1e3,
+                    emit(impl=impl, tm=tm, tn=tn, ms=t * 1e3,
                          gb_s=moved / t / 1e9, peak_share=moved / t / PEAK_BYTES_S,
-                         speedup=t_ref / t, rel_err=err, **base)
+                         speedup=t_ref / t, rel_err=err, **extra, **base)
                 del rows, want
             del w
     if args.out:

@@ -79,9 +79,11 @@ FULL = dict(
     # (slots, Mamba layers, heads, head_dim, state): granite's cell
     ssm=[(64, 9, 128, 64, 128)],
     # (tokens, top_k, expert layers, held, routed, d_model, expert width):
-    # the decode step's expert block at trinity_mini's and granite's cells
-    # (two expert layers of the stack, the second run)
-    moe=[(64, 8, 2, 128, 128, 2048, 1024), (64, 10, 2, 36, 72, 4096, 768)],
+    # the decode step's expert block at trinity_mini's and granite's cells,
+    # and trinity_mini's 2048-token chunk, whose rows stream in tiles (two
+    # expert layers of the stack, the second run)
+    moe=[(64, 8, 2, 128, 128, 2048, 1024), (64, 10, 2, 36, 72, 4096, 768),
+         (2048, 8, 2, 128, 128, 2048, 1024)],
     serve=[
         ["--demo"],
         ["--demo", "--d_model=2048", "--n_heads=16", "--n_layers=2",
@@ -96,7 +98,7 @@ REHEARSAL = dict(
     attn=[(2, 16, 16, 32)],
     paged=[(4, 2, 16, 8, 3), (4, 2, 128, 8, 3)],
     ssm=[(2, 3, 8, 64, 128)],
-    moe=[(8, 2, 2, 4, 8, 128, 64)],
+    moe=[(8, 2, 2, 4, 8, 128, 64), (600, 2, 2, 4, 8, 128, 64)],
     serve=[
         ["--demo", "--prefill_buckets=16,32", "--max_new_limit=16"],
         ["--demo", "--d_model=512", "--n_heads=4", "--n_layers=1",

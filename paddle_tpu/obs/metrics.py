@@ -330,11 +330,12 @@ def observe_grouped_matmul(path: str) -> None:
     """One of the routed expert block's two grouped products was TRACED
     (once per product each time a step is traced, never per step;
     serving/moe.expert_block): path is 'kernel' where the Pallas kernel runs
-    it (ops/pallas/grouped_matmul.py), 'ragged_dot' where XLA's ragged-dot
-    does."""
+    it with every row in VMEM (ops/pallas/grouped_matmul.py), 'streamed'
+    where the kernel streams the rows in tiles, 'ragged_dot' where XLA's
+    ragged-dot does."""
     REGISTRY.counter(
         "paddle_tpu_grouped_matmul_total",
-        "grouped expert products traced, by path (kernel|ragged_dot)",
+        "grouped expert products traced, by path (kernel|streamed|ragged_dot)",
     ).inc(path=path)
 
 

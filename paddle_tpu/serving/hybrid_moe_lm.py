@@ -39,9 +39,9 @@ refuses the prefix cache and speculation for a model that declares state.
 The expert block (serving/moe.py, shared with WindowMoELM; softmax after
 top-k here) is TOLD which experts it holds (`experts_held`). It routes over
 all `num_experts_routed`, sorts the assignments by expert, and runs the held
-experts' two products as grouped products (the Pallas grouped matmul, or
-`jax.lax.ragged_dot` past serving/moe.py's cut) over the assignments that
-landed here: no capacity, no dropped token. What the
+experts' two products as grouped products (the Pallas grouped matmul, its
+rows in VMEM whole or, past serving/moe.py's cut, in tiles) over the
+assignments that landed here: no capacity, no dropped token. What the
 absent experts would add is left out, and the shared MLP is counted once;
 the counters of `counter_spec` (assignments by held expert, here and absent)
 accumulate on the device in the carried state.

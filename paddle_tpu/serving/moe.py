@@ -20,15 +20,17 @@ as an index of its blocks, so the weights are read where they lie. A slice
 of the stack cut for the custom call is a copy of the layer's experts a
 layer and step (0.68 GB, a third of granite's step on the chip; PERF.md).
 
-Which grouped product runs is one rule on a shape the trace sees, the mean
-rows a held expert gets, T k / E held: at or under `KERNEL_ROWS_PER_EXPERT`,
-where the kernel's blocks fit VMEM, the Pallas kernel streams each held
-expert's weights back to back (ops/pallas/grouped_matmul.py: Trinity's decode
-step, 64 slots x 8 over 128 experts, 4 an expert; granite's decode step and
-its prefills to 512 tokens, 17.8 to 142.2 an expert); elsewhere XLA's
-`jax.lax.ragged_dot` (Trinity's 2048-token chunk, whose 16,384 rows do not
-fit: 128 an expert). On CPU `ops.pallas.enabled()` is off and ragged-dot,
-the kernel's oracle, runs everywhere."""
+Which grouped product runs is one rule on shapes the trace sees, the mean
+rows a held expert gets, T k / E held, and what fits VMEM. Both forms are
+the Pallas kernel's (ops/pallas/grouped_matmul.py), which streams each held
+expert's weights back to back: at or under `KERNEL_ROWS_PER_EXPERT`, where
+all the rows fit VMEM at once, they stay there while the experts go by
+(Trinity's decode step, 64 slots x 8 over 128 experts, 4 an expert;
+granite's decode step and its prefills to 512 tokens, 17.8 to 142.2 an
+expert); elsewhere the rows and the output stream through VMEM in tiles
+(Trinity's 2048-token chunk, 16,384 rows, 128 an expert; granite's
+1024-token prefill, 284). On CPU `ops.pallas.enabled()` is off and
+`jax.lax.ragged_dot`, the kernel's oracle, runs everywhere."""
 
 from __future__ import annotations
 
@@ -46,10 +48,10 @@ Array = jax.Array
 F32 = jnp.float32
 
 # the most rows a held expert gets on average, T k / E held, at which the
-# grouped products are the Pallas kernel's: it beat ragged-dot at every mean
-# timed on the chip where its blocks fit, 3.6 to 142.2 (granite's 512-token
-# prefill), and nothing past that was timed (benchmarks/grouped_matmul_bench.py;
-# PERF.md)
+# grouped products keep every row in VMEM where they fit: that form beat
+# ragged-dot at every mean timed on the chip where its blocks fit, 3.6 to
+# 142.2 (granite's 512-token prefill); past it the rows stream in tiles
+# (benchmarks/grouped_matmul_bench.py; PERF.md)
 KERNEL_ROWS_PER_EXPERT = 144
 
 
@@ -102,13 +104,17 @@ def expert_block(h: Array, valid: Array, router: Array, wi: Array, wo: Array, la
 def _grouped(rows: Array, w: Array, sizes: Array, layer) -> Array:
     """rows [M, K] sorted by expert times layer `layer` of the whole stack w
     [L, E, K, N], sizes [E] each held expert's rows: float32 [M, N], rows
-    past the groups 0. The kernel or ragged-dot by the module's rule; runs
-    where a step is traced, so the counter moves once a product a trace."""
+    past the groups 0. The kernel's two forms or ragged-dot by the module's
+    rule; runs where a step is traced, so the counter moves once a product a
+    trace."""
     (m, kd), (n_layers, e, _, n) = rows.shape, w.shape
-    if (pallas.enabled() and m <= KERNEL_ROWS_PER_EXPERT * e
-            and gm.fits(m, kd, n, rows.dtype, w.dtype)):
-        obs_metrics.observe_grouped_matmul("kernel")
-        return gm.grouped_matmul(rows, w, sizes, layer)
+    if pallas.enabled():
+        if m <= KERNEL_ROWS_PER_EXPERT * e and gm.fits(m, kd, n, rows.dtype, w.dtype):
+            obs_metrics.observe_grouped_matmul("kernel")
+            return gm.grouped_matmul(rows, w, sizes, layer)
+        if gm.streams(m, kd, n, e, rows.dtype, w.dtype):
+            obs_metrics.observe_grouped_matmul("streamed")
+            return gm.grouped_matmul_streamed(rows, w, sizes, layer)
     obs_metrics.observe_grouped_matmul("ragged_dot")
     # every layer's experts are groups, and only this layer's have rows
     groups = jax.lax.dynamic_update_slice(jnp.zeros(n_layers * e, jnp.int32), sizes, (layer * e,))

@@ -152,6 +152,17 @@ CASES = [
     ("gmm_trinity_wo", grouped_matmul.grouped_matmul, _gmm_shapes(512, 4, 128, 1024, 2048), 1),
     ("gmm_granite_wi", grouped_matmul.grouped_matmul, _gmm_shapes(640, 10, 36, 4096, 1536), 1),
     ("gmm_granite_wo", grouped_matmul.grouped_matmul, _gmm_shapes(640, 10, 36, 768, 4096), 1),
+    # the same products with their rows streamed in tiles: trinity_mini's
+    # 2048-token chunk (16,384 rows, 128 an expert) and granite's 1024-token
+    # prefill (10,240 rows)
+    ("gmm_trinity_chunk_wi", grouped_matmul.grouped_matmul_streamed,
+     _gmm_shapes(16384, 4, 128, 2048, 2048), 1),
+    ("gmm_trinity_chunk_wo", grouped_matmul.grouped_matmul_streamed,
+     _gmm_shapes(16384, 4, 128, 1024, 2048), 1),
+    ("gmm_granite_prefill_wi", grouped_matmul.grouped_matmul_streamed,
+     _gmm_shapes(10240, 10, 36, 4096, 1536), 1),
+    ("gmm_granite_prefill_wo", grouped_matmul.grouped_matmul_streamed,
+     _gmm_shapes(10240, 10, 36, 768, 4096), 1),
 ]
 
 
@@ -433,7 +444,8 @@ def test_the_window_moe_decode_step_streams_its_experts_through_the_kernel(on_ch
     no ragged-dot is left in the program; no instruction of a layer's
     experts' shape is a copy or a slice (the kernel reads the whole stack
     where it lies); the 2048-token chunk's products, 128 rows an expert,
-    stay ragged-dots."""
+    are the kernel's too, its rows streamed in tiles, and the chunk program
+    cuts no copy or slice of the stack either."""
     from paddle_tpu.serving.window_moe_lm import FULL, WINDOW, WindowMoEConfig, WindowMoELM
 
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "1")
@@ -476,13 +488,23 @@ def test_the_window_moe_decode_step_streams_its_experts_through_the_kernel(on_ch
     ]
     assert not cut, cut
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
-    # the chunk program, lowered: its products are XLA's
-    chunk = jax.jit(model.prefill_chunk).trace(
+    # the chunk program, compiled: its products, 16,384 rows at 128 an
+    # expert, are the kernel's with the rows streamed in tiles, and it cuts
+    # no copy or slice of an expert stack either
+    chunk = jax.jit(model.prefill_chunk).lower(
         params, *pools, state, aval((1, 2048), jnp.int32), aval((1,), jnp.int32),
         aval((1,), jnp.int32), aval((1, pages + ring), jnp.int32), aval((1,), jnp.int32),
         aval((1,), jnp.uint32), aval((1,), jnp.float32), aval((1,), jnp.int32),
-    ).lower(lowering_platforms=("tpu",)).as_text()
-    assert chunk.count("ragged_dot") >= 8 and "grouped_matmul" not in chunk
+    ).compile().as_text()
+    assert len(re.findall(r"%grouped_matmul\S* = f32\[16384,2048\]\S* custom-call\(", chunk)) \
+        == 8, "the streamed grouped-matmul kernel expected twice an expert layer"
+    assert "ragged-dot" not in chunk and "ragged_dot" not in chunk
+    cut = [
+        line.strip()[:140] for line in chunk.splitlines()
+        if re.search(r"= bf16\[(4,)?128,(2048|1024),2048\]\S* (copy|fusion|dynamic-slice|slice)\(",
+                     line)
+    ]
+    assert not cut, cut
 
 
 # -- the NMT decoder's backward, at seq2seq_nmt.train's shapes -----------------

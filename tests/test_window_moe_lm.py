@@ -364,11 +364,12 @@ def test_the_decode_steps_expert_products_are_the_kernels_and_a_long_chunks_are_
     """Traced under PADDLE_TPU_PALLAS=interpret, a decode step of 4 slots
     (16 rows over 16 experts: 1 an expert) counts one `kernel` a product a
     MoE layer, and its tokens are the ragged-dot program's; a block whose
-    rows a held expert gets on average pass the cut counts `ragged_dot`,
-    flag or not."""
+    rows a held expert gets on average pass the cut counts `streamed`, the
+    kernel with its rows in tiles, and `ragged_dot` with the flag off."""
     model, params = tiny()
-    calls = REGISTRY.counter("paddle_tpu_grouped_matmul_total",
-                             "grouped expert products traced, by path (kernel|ragged_dot)")
+    calls = REGISTRY.counter(
+        "paddle_tpu_grouped_matmul_total",
+        "grouped expert products traced, by path (kernel|streamed|ragged_dot)")
 
     def step():
         s = session(model, params, max_slots=4)
@@ -379,7 +380,7 @@ def test_the_decode_steps_expert_products_are_the_kernels_and_a_long_chunks_are_
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "0")
     want = step()
     monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
-    before = {p: calls.value(path=p) for p in ("kernel", "ragged_dot")}
+    before = {p: calls.value(path=p) for p in ("kernel", "streamed", "ragged_dot")}
 
     def aval(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -396,10 +397,20 @@ def test_the_decode_steps_expert_products_are_the_kernels_and_a_long_chunks_are_
     assert step() == want
     # past the cut: T k / E one row an expert over KERNEL_ROWS_PER_EXPERT
     t = moe.KERNEL_ROWS_PER_EXPERT * 16 // 4 + 4
-    before = calls.value(path="ragged_dot")
-    jax.eval_shape(
-        lambda h: moe.expert_block(h, jnp.ones(t, bool), params["router"][0], params["moe_wi"],
-                                   params["moe_wo"], 0, top_k=4, local_of=model._local_of,
-                                   dtype=jnp.float32, bias=params["expert_bias"][0]),
-        jax.ShapeDtypeStruct((t, 64), jnp.float32))
-    assert calls.value(path="ragged_dot") - before == 2
+
+    def past_the_cut():
+        jax.eval_shape(
+            lambda h: moe.expert_block(h, jnp.ones(t, bool), params["router"][0],
+                                       params["moe_wi"], params["moe_wo"], 0, top_k=4,
+                                       local_of=model._local_of, dtype=jnp.float32,
+                                       bias=params["expert_bias"][0]),
+            jax.ShapeDtypeStruct((t, 64), jnp.float32))
+
+    before = {p: calls.value(path=p) for p in ("kernel", "streamed", "ragged_dot")}
+    past_the_cut()
+    assert calls.value(path="streamed") - before["streamed"] == 2
+    assert calls.value(path="kernel") == before["kernel"]
+    assert calls.value(path="ragged_dot") == before["ragged_dot"]
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "0")
+    past_the_cut()
+    assert calls.value(path="ragged_dot") - before["ragged_dot"] == 2
